@@ -18,9 +18,10 @@ _jax.config.update("jax_enable_x64", True)
 # MXU speed comes from explicit bf16 dtypes via AMP, not degraded fp32.
 _jax.config.update("jax_default_matmul_precision", "highest")
 
-# Persistent compilation cache (FLAGS_compile_cache_dir, default
-# ~/.cache/paddle_tpu): compiled eager-op plans and TrainStep programs
-# survive process restarts (core/compile_cache.py).
+# Persistent compilation cache (JAX_COMPILATION_CACHE_DIR where set, else
+# FLAGS_compile_cache_dir, default <checkout>/.jax_cache): compiled
+# eager-op plans and TrainStep programs survive process restarts
+# (core/compile_cache.py).
 from .core import compile_cache as _compile_cache  # noqa: E402
 
 _compile_cache.setup()

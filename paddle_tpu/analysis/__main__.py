@@ -30,7 +30,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m paddle_tpu.analysis",
         description="framework-aware invariant lints (see "
-                    "PERF.md 'Static analysis & lock checking')")
+                    "DESIGN.md 'Static analysis & lock checking')")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to scan (default: paddle_tpu/ and "
                          "tools/ under the repo root)")

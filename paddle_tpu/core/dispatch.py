@@ -139,7 +139,7 @@ _NOT_VJP_JITTABLE: set = set()
 
 
 # ------------------------------------------------------- dispatch fast path
-# Per-op call-plan cache (the ~110 µs/op lever, PERF.md "Dispatch fast
+# Per-op call-plan cache (the ~110 µs/op lever, DESIGN.md "Dispatch fast
 # path"): keyed by (op, input avals, stop_gradient bits, static kwargs,
 # grad mode, flags epoch), a hit skips pytree flattening, dtype-promotion
 # re-derivation and jit re-dispatch entirely — the stored plan carries the

@@ -134,30 +134,29 @@ define_flag("flash_block_k", 128,
             "flash-attention key/value tile size")
 define_flag("flash_dot_impl", "auto",
             "matmul strategy inside the flash kernels: 'bf16' feeds "
-            "storage-dtype operands straight into the MXU dots (fastest; "
-            "needs a Mosaic with mixed-precision NT/TN tpu.matmul), 'nn' "
+            "storage-dtype operands straight into NT/TN MXU dots, 'nn' "
             "restructures every dot into canonical NN form with "
-            "pre-transposed K/V and in-kernel f32 transposes (bf16 MXU "
-            "rate on Mosaics that reject transposed mixed dots), 'nn2' "
-            "is nn with zero in-kernel transposes (Q^T/dO^T in, "
-            "dK^T/dV^T out; survives Mosaics lacking f32 vector "
-            "transposes), 'f32' casts blocks to f32 before the dots "
-            "(always compiles, ~4x slower MXU rate), 'auto' probes the "
-            "real backend once and caches the verdict "
-            "(tools/flash_caps.json), picking bf16 > nn > nn2 > f32")
+            "pre-transposed K/V and in-kernel f32 transposes, 'nn2' is "
+            "nn with zero in-kernel transposes (Q^T/dO^T in, dK^T/dV^T "
+            "out), 'f32' casts blocks to f32 before the dots (~4x "
+            "slower MXU rate), 'auto' is bf16")
 define_flag("dataloader_fork_workers", False,
             "DataLoader num_workers>0 uses forked worker PROCESSES (numpy-"
             "only datasets; forking after jax backend init is unsafe for "
             "datasets that touch device arrays) instead of threads")
 define_flag("eager_op_jit", True, "jit-compile eager per-op executions")
 define_flag("eager_jit_cache_size", 8192, "max cached compiled op programs")
-define_flag("compile_cache_dir", os.path.join("~", ".cache", "paddle_tpu"),
+define_flag("compile_cache_dir",
+            os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), ".jax_cache"),
             "persistent XLA compilation-cache directory (jax "
             "jax_compilation_cache_dir): compiled per-op plan executables "
-            "and TrainStep programs survive process restarts; empty "
-            "string disables. DONATED programs are kept off the cache on "
-            "the CPU backend (jaxlib serialization corrupts their "
-            "aliasing — core/compile_cache.suspend_if)")
+            "and TrainStep programs survive process restarts; default is "
+            "one fixed path inside the checkout; empty string disables; "
+            "JAX_COMPILATION_CACHE_DIR, where set, wins "
+            "(core/compile_cache.py). DONATED programs are kept off the "
+            "cache on the CPU backend (jaxlib serialization corrupts "
+            "their aliasing — core/compile_cache.suspend_if)")
 define_flag("compile_cache_min_compile_secs", 0.0,
             "only persist programs whose compile took at least this many "
             "seconds (0.0 persists everything, including the "

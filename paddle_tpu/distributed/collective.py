@@ -23,26 +23,13 @@ import numpy as np
 from ..core.tensor import Tensor
 from .env import get_mesh
 
-try:  # jax>=0.5 moved shard_map to the top level
-    from jax import shard_map as _shard_map_fn
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-
 def shard_map(f, mesh, in_specs, out_specs, check=True):
-    kw = {}
-    if not check:
-        # the static replication checker can't always prove collectives'
-        # outputs replicated (e.g. all_gather); disable per-program
-        import inspect
+    # check=False: the static replication checker can't always prove
+    # collectives' outputs replicated (e.g. all_gather); disable
+    # per-program
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=bool(check))
 
-        params = inspect.signature(_shard_map_fn).parameters
-        if "check_vma" in params:
-            kw["check_vma"] = False
-        elif "check_rep" in params:
-            kw["check_rep"] = False
-    return _shard_map_fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         **kw)
 
 P = jax.sharding.PartitionSpec
 

@@ -690,7 +690,7 @@ class QuorumStore:
         # and monotonic clocks start near zero on a fresh host, so a
         # zeroed stamp still read as fresh and a fence rejection looped
         # forever on the deposed epoch instead of re-validating —
-        # found by schedcheck's bounded exploration (PERF.md catch
+        # found by schedcheck's bounded exploration (DESIGN.md catch
         # table, ISSUE 15).
         self._validated_at = None
         self._resync_thread: Optional[threading.Thread] = None

@@ -321,7 +321,7 @@ class TelemetryCallback(Callback):
             recs = getattr(prof, "step_records", [])
             if len(recs) > self._seen_records:
                 rec = recs[-1]
-                row["mfu"] = round(rec["mfu"], 6)
+                row["mfu"] = rec["mfu"]
                 row["flops"] = rec["flops"]
                 row["step_time_ms"] = round(rec["time_ms"], 3)
             self._seen_records = len(recs)

@@ -45,7 +45,7 @@ from .shard import StaleEpochError, epoch_key
 
 def _key_bytes(k: int) -> bytes:
     """A key's ring point. Decimal-string hashing (not raw int bytes)
-    so the shard map is reproducible from the PERF.md walkthrough by
+    so the shard map is reproducible from the DESIGN.md walkthrough by
     hand: sha1(b"embed:12345")."""
     return f"embed:{int(k)}".encode()
 

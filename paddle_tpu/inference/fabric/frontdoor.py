@@ -1,7 +1,7 @@
 """The fleet's HTTP front door: one address, N serving hosts.
 
 Extends the serving tier's stdlib HTTP front (serving/server._Handler
-— same helpers, same error taxonomy) with the router behind it instead
+— same helpers, same error classes) with the router behind it instead
 of a local engine:
 
   POST /predict    forwarded verbatim (JSON or raw-binary — the body

@@ -164,6 +164,30 @@ def run_worker(prefix: str, runner=None, predictor=None) -> int:
             proto_out.flush()
 
 
+def build_generator(preset: str, state_dict: str | None = None,
+                    draft: str | None = None, **engine_kw):
+    """The GenerativeEngine `--generate PRESET` serves: a models.gpt
+    preset with seeded demo weights (or `state_dict` loaded into it),
+    optionally a `draft` preset for speculative decode; `engine_kw` goes
+    to the engine. Returned warmed and started."""
+    import paddle_tpu as paddle
+    from ..models.gpt import PRESETS, GPTForCausalLM
+    from .serving import GenerativeEngine
+
+    def preset_model(name):
+        paddle.seed(0)
+        model = GPTForCausalLM(PRESETS[name])
+        model.eval()
+        return model
+
+    model = preset_model(preset)
+    if state_dict:
+        model.set_state_dict(paddle.load(state_dict))
+    return GenerativeEngine(
+        model, draft=None if draft is None else preset_model(draft),
+        **engine_kw)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m paddle_tpu.inference.serve",
@@ -208,7 +232,7 @@ def main(argv=None) -> int:
                     help="--generate KV-cache pool precision: int8 "
                          "stores quantized rows with per-(row, layer) "
                          "absmax scales — half the pool bytes, double "
-                         "the slots per byte (PERF.md Quantized serving)")
+                         "the slots per byte (DESIGN.md Quantized serving)")
     ap.add_argument("--quantize-weights", action="store_true",
                     help="weight-only int8 for the --generate model "
                          "(and draft): absmax per layer at warmup, "
@@ -241,31 +265,19 @@ def main(argv=None) -> int:
 
     generator = None
     if args.generate is not None:
-        import paddle_tpu as paddle
-        from ..models.gpt import PRESETS, GPTForCausalLM
-        from .serving import GenerativeEngine
+        from ..models.gpt import PRESETS
 
-        if args.generate not in PRESETS:
-            ap.error(f"unknown preset {args.generate!r}; have "
-                     f"{sorted(PRESETS)}")
-        paddle.seed(0)
-        model = GPTForCausalLM(PRESETS[args.generate])
-        if args.state_dict:
-            model.set_state_dict(paddle.load(args.state_dict))
-        model.eval()
-        draft_model = None
-        if args.draft is not None:
-            if args.draft not in PRESETS:
-                ap.error(f"unknown draft preset {args.draft!r}; have "
+        for what, name in (("preset", args.generate),
+                           ("draft preset", args.draft)):
+            if name is not None and name not in PRESETS:
+                ap.error(f"unknown {what} {name!r}; have "
                          f"{sorted(PRESETS)}")
-            paddle.seed(0)
-            draft_model = GPTForCausalLM(PRESETS[args.draft])
-            draft_model.eval()
-        generator = GenerativeEngine(
-            model, slots=args.slots,
+        generator = build_generator(
+            args.generate, state_dict=args.state_dict, draft=args.draft,
+            slots=args.slots,
             replicas=args.replicas if args.replicas else 1,
             max_queue_depth=args.max_queue_depth,
-            draft=draft_model, spec_tokens=args.spec_tokens,
+            spec_tokens=args.spec_tokens,
             prefix_cache_slots=args.prefix_cache,
             kv_dtype=args.kv_dtype,
             quantize_weights=args.quantize_weights)

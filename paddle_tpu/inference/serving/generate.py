@@ -477,7 +477,7 @@ def _extend_body(p, buf_k, buf_v, slot, ids, start, length, temp, topk,
     the logits at absolute position length-1. ids [1, T] int32. An int8
     pool KEEPS the row's scale (set by the cached prefix's original
     prefill): tail positions quantize with it, clip semantics — the
-    scale-granularity error source PERF.md documents."""
+    scale-granularity error source DESIGN.md documents."""
     import jax
     import jax.numpy as jnp
 

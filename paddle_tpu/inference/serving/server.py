@@ -214,7 +214,7 @@ class _Handler(BaseHTTPRequestHandler):
             else:
                 self._predict_json(body)
         except Exception as e:  # noqa: BLE001
-            # _send_error_obj keeps the status taxonomy honest:
+            # _send_error_obj keeps the status mapping honest:
             # ServingError carries its own 4xx/5xx, TimeoutError is a
             # server-side 504, anything unexpected a 500 — never a 400
             self._send_error_obj(e)

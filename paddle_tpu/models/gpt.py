@@ -12,6 +12,7 @@ TPU-first details:
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -250,15 +251,24 @@ class GPTForCausalLM(nn.Layer):
 @defop("gpt_scan_blocks")
 def _gpt_scan_blocks_p(x, ln1_w, ln1_b, qkv_w, qkv_b, out_w, out_b,
                        ln2_w, ln2_b, fc1_w, fc1_b, fc2_w, fc2_b,
-                       num_heads=8, eps=1e-5, remat=False):
+                       num_heads=8, eps=1e-5, remat=False,
+                       attn_shard=None):
     """The whole transformer stack as ONE lax.scan over stacked per-layer
     params ([L, ...] leading axis) — XLA sees one block body instead of L
     unrolled copies, so compile time drops ~L-fold (same math as the
     unrolled GPTBlock list; dropout-free path). remat=True checkpoints
-    each scan iteration (activation memory ~1 block)."""
+    each scan iteration (activation memory ~1 block). attn_shard =
+    (mesh, spec of the [B, L, H, hd] q/k/v) runs attention per shard
+    (GPTForCausalLMScan.shard_attention)."""
     from ..nn.functional import _sdpa_p
 
-    sdpa = _sdpa_p._pure_fn
+    sdpa = functools.partial(_sdpa_p._pure_fn, is_causal=True)
+    if attn_shard is not None:
+        from ..distributed.collective import shard_map
+
+        mesh, spec = attn_shard
+        sdpa = shard_map(sdpa, mesh, in_specs=(spec,) * 3, out_specs=spec,
+                         check=False)
     H = int(num_heads)
     D = x.shape[-1]
     hd = D // H
@@ -274,8 +284,7 @@ def _gpt_scan_blocks_p(x, ln1_w, ln1_b, qkv_w, qkv_b, out_w, out_b,
         qkv = y @ qw + qb                       # [B, L, 3D]
         b_, l_, _ = qkv.shape
         qkv = qkv.reshape(b_, l_, 3, H, hd)
-        att = sdpa(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
-                   is_causal=True)
+        att = sdpa(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
         h = h + att.reshape(b_, l_, D) @ ow + ob
         y = ln(h, l2w, l2b)
         y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
@@ -294,7 +303,7 @@ class GPTForCausalLMScan(nn.Layer):
     weight, the stack executed by `gpt_scan_blocks`. Same math as
     GPTForCausalLM with dropout=0 (build via `from_unrolled` for
     bit-matching weights); the win is compile time — one block body
-    traced instead of num_layers copies (PERF.md lever; reference role:
+    traced instead of num_layers copies (reference role:
     the fused-multi-transformer static op,
     paddle/fluid/operators/fused/fused_multi_transformer_op.cu)."""
 
@@ -328,6 +337,17 @@ class GPTForCausalLMScan(nn.Layer):
             self.lm_head_w = mk([D, cfg.vocab_size],
                                 default_initializer=xav)
         self.remat = False
+        self._attn_shard = None
+
+    def shard_attention(self, mesh, mesh_axes=("dp", "tp")):
+        """Run attention per (batch, head) shard of `mesh` (shard_map)
+        — the companion of gpt_scan_shard_fn(mesh_axes). GSPMD cannot
+        partition a Pallas call: with sharded q/k/v the bare flash
+        kernel does not lower ("wrap the call in a shard_map")."""
+        from jax.sharding import PartitionSpec as P
+
+        dp, tp = mesh_axes
+        self._attn_shard = (mesh, P(dp, None, tp, None))
 
     @classmethod
     def from_unrolled(cls, model: "GPTForCausalLM") -> "GPTForCausalLMScan":
@@ -388,7 +408,7 @@ class GPTForCausalLMScan(nn.Layer):
             self.out_w, self.out_b, self.ln2_w, self.ln2_b,
             self.fc1_w, self.fc1_b, self.fc2_w, self.fc2_b,
             num_heads=self.cfg.num_heads, eps=self.cfg.layer_norm_eps,
-            remat=bool(self.remat))
+            remat=bool(self.remat), attn_shard=self._attn_shard)
         return self.ln_f(h)
 
     def forward(self, input_ids):

@@ -1100,7 +1100,7 @@ from ..ops.creation import diag_embed  # noqa: E402,F401 (paddle parity)
 @defop("fused_linear_cross_entropy")
 def _fused_linear_ce_p(h, weight, labels, transpose_y=True, chunk=2048,
                        ignore_index=-100):
-    """Chunked fused LM-head + softmax-CE (the bench PERF.md lever:
+    """Chunked fused LM-head + softmax-CE (the bench lever:
     'fused CE-from-bf16-logits').
 
     Never materializes the [T, vocab] logits: a lax.scan walks token

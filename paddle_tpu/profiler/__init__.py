@@ -235,12 +235,13 @@ class Profiler:
         dt_s = max((now - t0) / 1e9, 1e-12)
         flops = self._session.step_flops - self._step_flops_mark
         self._step_flops_mark = self._session.step_flops
+        peak = _stats.device_peak_flops()
         rec = {
             "step": self._step,
             "time_ms": (now - t0) / 1e6,
             "flops": int(flops),
             "flops_per_sec": flops / dt_s,
-            "mfu": flops / dt_s / _stats.device_peak_flops(),
+            "mfu": flops / dt_s / peak if peak else None,
         }
         if num_samples is not None:
             rec["num_samples"] = num_samples

@@ -27,12 +27,13 @@ from . import aggregator, memory
 from .aggregator import (OpStat, build_table, fmt_bytes, fmt_flops,
                          layer_stats, load_device_trace, merge_device_totals,
                          op_stats)
-from .flops import device_peak_flops
+from .flops import DEVICE_PEAKS, device_peak_flops, device_peaks
 from .memory import MemoryTracer
 
 __all__ = [
     "install", "uninstall", "active", "add_flops", "note_donation",
-    "device_peak_flops", "build_summary", "build_summary_dict",
+    "DEVICE_PEAKS", "device_peaks", "device_peak_flops", "build_summary",
+    "build_summary_dict",
     "op_stats", "layer_stats", "load_device_trace", "merge_device_totals",
     "OpStat", "MemoryTracer", "build_table", "fmt_flops", "fmt_bytes",
     "register_summary_provider", "unregister_summary_provider",
@@ -185,8 +186,8 @@ def _ms(us: float) -> str:
     return f"{us / 1000.0:.3f}"
 
 
-def _mfu_str(flops: int, seconds: float, peak: float) -> str:
-    if not flops or seconds <= 0:
+def _mfu_str(flops: int, seconds: float, peak) -> str:
+    if not flops or seconds <= 0 or not peak:
         return "-"
     return f"{flops / seconds / peak * 100:.2f}%"
 
@@ -200,7 +201,8 @@ def build_summary(prof, sorted_by=None, time_unit="ms") -> str:
     peak = device_peak_flops()
     sections = [
         f"Profiler statistics (time unit: ms; FLOPs are analytic forward "
-        f"counts; MFU basis {fmt_flops(peak)}FLOP/s)"
+        f"counts; MFU basis "
+        f"{fmt_flops(peak) + 'FLOP/s' if peak else 'none (CPU run)'})"
     ]
 
     rows = []
@@ -259,7 +261,7 @@ def build_summary(prof, sorted_by=None, time_unit="ms") -> str:
         srows.append([
             r["step"], f"{r['time_ms']:.3f}", fmt_flops(r["flops"]),
             fmt_flops(r["flops_per_sec"]) + "/s",
-            f"{r['mfu'] * 100:.2f}%",
+            "-" if r["mfu"] is None else f"{r['mfu'] * 100:.2f}%",
         ])
     sections.append(build_table(
         "Step Summary",
@@ -305,7 +307,9 @@ def build_summary_dict(prof, top_ops: int = 8) -> dict:
         out["avg_step_time_ms"] = round(
             sum(r["time_ms"] for r in steps) / len(steps), 3)
         out["flops_per_step"] = int(max(r["flops"] for r in steps))
-        out["avg_mfu"] = round(sum(r["mfu"] for r in steps) / len(steps), 4)
+        if peak:
+            out["avg_mfu"] = round(
+                sum(r["mfu"] for r in steps) / len(steps), 4)
     out["top_ops"] = [
         {"name": st.name, "calls": st.calls,
          "total_ms": round(st.total / 1000.0, 3), "flops": int(st.flops)}

@@ -5,7 +5,7 @@ Analog of the reference's `python/paddle/profiler/profiler_statistic.py`
 RecordEvent stream (chrome-trace dicts) plus an optional jax.profiler
 device trace into per-op and per-layer statistic tables.
 
-Event taxonomy (the `cat` field):
+Event categories (the `cat` field):
 - ``Operator``     — one dispatch through core/dispatch.apply; carries
   ``args.flops`` (analytic) and ``args.layer`` (name-stack path).
 - ``Forward``      — one nn.Layer.__call__ span, named with the dotted

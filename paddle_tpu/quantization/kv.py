@@ -21,7 +21,7 @@ Scale scheme (per (pool row, layer), symmetric, no zero point):
   with the row's EXISTING scale — clip semantics: a late outlier
   saturates at +-127 rather than rescaling (and thus requantizing) the
   whole row. This is the documented long-context error source
-  (PERF.md "Quantized serving").
+  (DESIGN.md "Quantized serving").
 - ``fake_quant`` is the in-scan write helper: the round trip it applies
   is bitwise what a scatter-then-gather through the pool produces, so
   a verify program attending freshly-written block positions sees the

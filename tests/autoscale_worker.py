@@ -117,7 +117,7 @@ def main():
 
 if __name__ == "__main__":
     main()
-    # hard exit: backend/relay threads must not abort interpreter
+    # hard exit: backend threads must not abort interpreter
     # teardown after the work is done (same pattern as launch.hard_exit)
     sys.stdout.flush()
     sys.stderr.flush()

@@ -176,4 +176,4 @@ if __name__ == "__main__":
     main()
     sys.stdout.flush()
     sys.stderr.flush()
-    os._exit(0)  # backend/relay threads must not block exit
+    os._exit(0)  # backend threads must not block exit

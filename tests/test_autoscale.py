@@ -147,23 +147,29 @@ class TestDynamicReplicas:
         traffic that follows records only bucket HITS (zero new
         compiles) — the executables were all pre-built."""
         eng = make_engine(saved_model)
-        base = eng.metrics.snapshot()
-        compiles_before = sum(st["compiles"]
-                              for st in base["buckets"].values())
-        rep = eng.add_replica()
-        assert rep["admitted_after_warmup"]
-        assert rep["warmed_executables"] == len(eng._boundaries)
-        assert rep["persistent_misses"] == 0  # never an XLA re-compile
-        assert eng.health()["replicas"] == 2
-        futs = [eng.submit(req(i)) for i in range(12)]
-        for f in futs:
-            f.result(60)
-        snap = eng.metrics.snapshot()
-        compiles_after = sum(st["compiles"]
-                             for st in snap["buckets"].values())
-        assert compiles_after == compiles_before
-        assert sum(st["hits"] for st in snap["buckets"].values()) > 0
-        eng.shutdown()
+        # shut down whatever the verdict: a failed assertion that left the
+        # engine's threads running made every later test of this xdist
+        # worker share its process with them (schedcheck then reports
+        # nondeterminism, the compile counters count their compiles)
+        try:
+            base = eng.metrics.snapshot()
+            compiles_before = sum(st["compiles"]
+                                  for st in base["buckets"].values())
+            rep = eng.add_replica()
+            assert rep["admitted_after_warmup"]
+            assert rep["warmed_executables"] == len(eng._boundaries)
+            assert rep["persistent_misses"] == 0  # never an XLA re-compile
+            assert eng.health()["replicas"] == 2
+            futs = [eng.submit(req(i)) for i in range(12)]
+            for f in futs:
+                f.result(60)
+            snap = eng.metrics.snapshot()
+            compiles_after = sum(st["compiles"]
+                                 for st in snap["buckets"].values())
+            assert compiles_after == compiles_before
+            assert sum(st["hits"] for st in snap["buckets"].values()) > 0
+        finally:
+            eng.shutdown()
 
     def test_remove_replica_drains_without_losing_requests(self,
                                                            saved_model):

@@ -1,10 +1,7 @@
 """The BASELINE.md single-chip rows, verbatim (the driver's north-star
 table): ResNet-50 on CIFAR-shaped data trains end-to-end in DYGRAPH
 mode, and BERT-base-style MLM trains under bf16 AMP O2. On the CI host
-these run at CPU-tractable sizes; the SAME code paths run on a real
-chip via PADDLE_TPU_TEST_REAL=1 (tests/conftest.py)."""
-import os
-
+these run at CPU-tractable sizes."""
 import numpy as np
 import pytest
 
@@ -33,18 +30,14 @@ class TestResNetCifarDygraph:
             losses.append(float(loss.numpy()))
         return losses
 
-    # (the always-on resnet18 dygraph train already lives in
-    # tests/test_amp_io_jit.py::TestModels::test_resnet_trains_one_batch —
-    # this module only adds the literal resnet50 row, slow tier)
-    @pytest.mark.skipif(os.environ.get("PADDLE_TPU_SLOW_TESTS") != "1",
-                        reason="resnet50 dygraph on CPU: slow tier")
     def test_resnet50_cifar_dygraph_loss_decreases(self):
-        """The literal baseline row (Bottleneck resnet50)."""
+        """The literal baseline row (Bottleneck resnet50), cut to what the
+        assertion needs: two eager steps on one tiny batch."""
         from paddle_tpu.models import resnet50
 
         paddle.seed(0)
         losses = self._train(resnet50(num_classes=10, small_input=True),
-                             steps=4, batch=4, lr=0.003)
+                             steps=2, batch=2, lr=0.003)
         assert all(np.isfinite(losses)), losses
         assert losses[-1] < losses[0], losses
 

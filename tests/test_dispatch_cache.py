@@ -1,7 +1,7 @@
 """Dispatch fast path (core/dispatch plan cache) + persistent
 compilation cache (core/compile_cache).
 
-The plan cache is the ~110 µs/op lever (PERF.md "Dispatch fast path"): a
+The plan cache is the ~110 µs/op lever (DESIGN.md "Dispatch fast path"): a
 hit must skip flattening/jit re-dispatch yet stay bit-identical with the
 general path; keys must split on everything that changes the compiled
 program (shapes, dtypes, stop_gradient, scalar statics AND their types,
@@ -161,6 +161,39 @@ class TestPersistentCompileCache:
             "cold process against a warm compile-cache dir recompiled "
             f"{r2['persistent']['misses']} programs")
         assert r2["grad0"] == r1["grad0"]
+
+    @pytest.mark.parametrize("placed", [True, False],
+                             ids=["env-placed", "in-checkout-default"])
+    def test_cache_dir_placement(self, tmp_path, placed):
+        """JAX_COMPILATION_CACHE_DIR places the cache from outside: after
+        `import paddle_tpu` jax's directory is still the env's (even with
+        FLAGS_compile_cache_dir pointing elsewhere) and stats() reports
+        it. Unset, the directory is the one fixed path inside the
+        checkout — never under ~ or a temp name."""
+        from _cpu_env import cpu_subprocess_env
+
+        env = cpu_subprocess_env()
+        env.pop("FLAGS_compile_cache_dir", None)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if placed:
+            want = str(tmp_path / "placed")
+            env["JAX_COMPILATION_CACHE_DIR"] = want
+            env["FLAGS_compile_cache_dir"] = str(tmp_path / "flag")
+        else:
+            want = os.path.join(REPO, ".jax_cache")
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import json, jax, paddle_tpu\n"
+             "from paddle_tpu.core import compile_cache as cc\n"
+             "print(json.dumps([jax.config.jax_compilation_cache_dir, "
+             "cc.stats()['dir'], cc.stats()['enabled']]))"],
+            capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
+        assert out.returncode == 0, out.stdout + out.stderr
+        jax_dir, stats_dir, enabled = json.loads(
+            out.stdout.strip().splitlines()[-1])
+        assert jax_dir == want and stats_dir == want and enabled
+        if placed:
+            assert not (tmp_path / "flag").exists()
 
     def test_disabled_by_empty_flag(self, tmp_path):
         from paddle_tpu.core import compile_cache

@@ -44,7 +44,9 @@ class TestMultiProcessHybrid:
     and the loss curves must match. Covers _mp_put's non-addressable
     sharding path for params, opt state and batch."""
 
-    def _run_serial(self, mode, n_devices=4, runner=RUNNER, timeout=300):
+    # Subprocess waits are bounded so that serial + two cluster attempts
+    # stay inside conftest's wedge window; alone each takes 10-30 s.
+    def _run_serial(self, mode, n_devices=4, runner=RUNNER, timeout=150):
         out = subprocess.run(
             [sys.executable, runner], capture_output=True, text=True,
             timeout=timeout, cwd=REPO,
@@ -54,33 +56,23 @@ class TestMultiProcessHybrid:
         return _parse_losses(out.stdout)
 
     def _run_cluster(self, mode, nproc=2, runner=RUNNER, losses_rank=0,
-                     timeout=300):
+                     timeout=150):
         """Reference _run_cluster_gloo (test_dist_base.py:1467): N real
         processes, CPU collectives, launch env contract. One retry with a
         fresh port absorbs jax.distributed coordination-service startup
         crashes under heavy CI load (a task starved through the connect
-        window kills the whole world)."""
+        window kills the whole world). Ranks write to files, not pipes
+        (testing.multihost.RankProc says why)."""
+        from paddle_tpu.testing.multihost import RankProc, wait_ranks
+
         for attempt in range(2):
             port = _free_port()
-            procs = []
-            for r in range(nproc):
-                env = _clean_env(
-                    DIST_MODE=mode,
-                    PADDLE_TRAINER_ID=str(r),
-                    PADDLE_TRAINERS_NUM=str(nproc),
-                    PADDLE_MASTER=f"127.0.0.1:{port}")
-                procs.append(subprocess.Popen(
-                    [sys.executable, runner], stdout=subprocess.PIPE,
-                    stderr=subprocess.PIPE, text=True, cwd=REPO, env=env))
-            outs = []
-            for p in procs:
-                try:
-                    stdout, stderr = p.communicate(timeout=timeout)
-                except subprocess.TimeoutExpired:
-                    for q in procs:
-                        q.kill()
-                    stdout, stderr = p.communicate()
-                outs.append((p.returncode, stdout, stderr))
+            procs = [RankProc([sys.executable, runner], _clean_env(
+                DIST_MODE=mode,
+                PADDLE_TRAINER_ID=str(r),
+                PADDLE_TRAINERS_NUM=str(nproc),
+                PADDLE_MASTER=f"127.0.0.1:{port}")) for r in range(nproc)]
+            outs = wait_ranks(procs, timeout)
             if all(rc == 0 for rc, _, _ in outs):
                 return _parse_losses(outs[losses_rank][1])
             if attempt == 1:
@@ -182,6 +174,7 @@ class TestMultiProcessGPTPipeline:
         assert all(np.isfinite(serial)), serial
         np.testing.assert_allclose(serial, cluster, rtol=1e-4, atol=1e-6)
 
+    @pytest.mark.slow  # ~40 s alone: real-ish shapes in five processes
     def test_pp4_gpt_big_shapes_cross_process_parity(self):
         """Round-4 verdict weak #4: the cross-process pipeline must
         EXECUTE real-ish shapes, not just toy ones. pp=4 stage processes,
@@ -189,14 +182,12 @@ class TestMultiProcessGPTPipeline:
         stages + multi-precision AdamW, 2 steps — loss-trajectory parity
         with the O2-decorated compiled TrainStep at bf16 tolerance
         (rtol 5e-2: bf16 has ~3 decimal digits; the two executions
-        reduce in different orders). Slow tier: ~minutes of CPU math."""
-        if not os.environ.get("PADDLE_TPU_SLOW_TESTS"):
-            pytest.skip("slow tier (PADDLE_TPU_SLOW_TESTS=1)")
+        reduce in different orders)."""
         serial = self._h._run_serial(self, "pp_gpt_big", n_devices=2,
-                                     runner=self.GPT_RUNNER, timeout=1200)
+                                     runner=self.GPT_RUNNER, timeout=280)
         cluster = self._h._run_cluster(self, "pp_gpt_big", nproc=4,
                                        runner=self.GPT_RUNNER,
-                                       losses_rank=3, timeout=1200)
+                                       losses_rank=3, timeout=280)
         # no strict-decrease assert: the O2 loss is read at bf16
         # resolution (~0.06 near ln(50304)=10.8), so 2 steps of lr 1e-3
         # need not change the REPRESENTABLE value; the claim under test

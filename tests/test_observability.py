@@ -555,10 +555,14 @@ class TestTrainingTrace:
         for r in rows:
             assert need <= set(r)
         assert all(r["step_time_ms"] > 0 for r in rows)
+        # a CPU run has no MFU (no peak is assumed for a host): the
+        # series carries the field as null and the textfile omits it
+        assert all(r["mfu"] is None for r in rows)
         text = open(os.path.join(metrics_dir, "metrics.prom")).read()
-        for field in ("step_time_ms", "mfu", "queue_depth",
+        for field in ("step_time_ms", "queue_depth",
                       "starvation_fraction", "ckpt_stall_s"):
             assert f"paddle_train_{field} " in text
+        assert "paddle_train_mfu" not in text
 
     def test_resume_fast_forward_prefix_records_no_spans(self, tracing,
                                                          tmp_path):

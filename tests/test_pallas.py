@@ -1,5 +1,6 @@
 """Pallas flash-attention kernel vs the XLA einsum reference (interpret mode
-on CPU — the fake-TPU CI pattern; the real-TPU path is exercised by bench.py).
+on CPU — the fake-TPU CI pattern; tests/test_chip_compile.py asks the TPU
+compiler, chip_smoke.py runs the kernel).
 Reference role: paddle/phi/kernels/gpu/flash_attn_kernel.cu (+grad).
 """
 import math
@@ -53,6 +54,25 @@ def test_flash_matches_reference_fwd_bwd(causal, impl):
         assert float(jnp.abs(a - b).max()) / scale < 2e-4
 
 
+def test_dot_impl_resolves_in_process():
+    """FLAGS_flash_dot_impl: 'auto' is 'bf16' — decided in this process,
+    no probe child, no file — a named strategy is itself, anything else
+    raises."""
+    from paddle_tpu.core.flags import flag, set_flags
+    from paddle_tpu.ops.pallas.flash_attention import _resolve_dot_impl
+
+    assert flag("flash_dot_impl") == "auto" and _resolve_dot_impl() == "bf16"
+    try:
+        for impl in ("bf16", "nn", "nn2", "f32"):
+            set_flags({"FLAGS_flash_dot_impl": impl})
+            assert _resolve_dot_impl() == impl
+        set_flags({"FLAGS_flash_dot_impl": "fp8"})
+        with pytest.raises(ValueError, match="auto|bf16|nn|nn2|f32"):
+            _resolve_dot_impl()
+    finally:
+        set_flags({"FLAGS_flash_dot_impl": "auto"})
+
+
 def test_supported_gate():
     assert flash_attention_supported((2, 256, 4, 64), 64, True)
     assert not flash_attention_supported((2, 200, 4, 64), 64, True)
@@ -63,9 +83,11 @@ def test_supported_gate():
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("impl", ["bf16", "nn", "nn2", "f32"])
 def test_mosaic_tpu_lowering(causal, dtype, impl):
-    """Cross-lower the kernels for the TPU target on the CPU host
-    (jax.export runs the full Mosaic pass) — catches Mosaic lowering
-    regressions without a chip. Guards the x64 pitfall: the package enables
+    """Cross-lower the kernels for the TPU target on the CPU host.
+    jax.export only LOWERS (jaxpr -> Mosaic MLIR in a custom call); it
+    never asks the TPU compiler, which is what refused these kernels for
+    their dot precision — tests/test_chip_compile.py does that. What this
+    guards is the lowering itself, e.g. the x64 pitfall: the package enables
     jax_enable_x64, so stray Python int/float literals in kernel bodies
     become 64-bit constants Mosaic cannot lower (infinite recursion in
     convert_element_type)."""

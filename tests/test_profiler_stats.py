@@ -294,7 +294,8 @@ class TestProfiledGPT:
         assert len({r["flops"] for r in recs}) == 1
         assert all(r["flops"] > 0 for r in recs)
         assert all(r["time_ms"] > 0 for r in recs)
-        assert all(0 <= r["mfu"] for r in recs)
+        # a CPU run has no MFU: no peak is assumed for a host
+        assert all(r["mfu"] is None for r in recs)
         # forward analytic count must cover at least the block matmuls:
         # qkv + out + fc1 + fc2 per layer, tokens = 2*8
         cfg_h, tokens, layers_n = 32, 16, 2
@@ -368,7 +369,36 @@ class TestProfiledGPT:
         assert d["steps"] == 3
         assert d["avg_step_time_ms"] > 0
         assert d["flops_per_step"] > 0
-        assert 0 <= d["avg_mfu"]
+        assert d["device_peak_flops"] is None and "avg_mfu" not in d
         assert len(d["top_ops"]) == 5
         assert d["memory"]["peak_bytes"] > 0
         assert d["donation"]["params_bytes"] > 0
+
+
+class TestDevicePeaks:
+    """One table keyed by device_kind; no platform-keyed or CPU default."""
+
+    def test_v5e_row(self):
+        row = pstats.device_peaks("TPU v5 lite")
+        assert row["bf16_flops"] == 197e12 and row["int8_ops"] == 393e12
+        assert row["hbm_bytes_per_s"] == 819e9 and row["hbm_bytes"] == 16e9
+
+    def test_unknown_kind_raises(self):
+        with pytest.raises(LookupError, match="no published peaks"):
+            pstats.device_peaks("TPU v99")
+        # this host's own device kind ("cpu") is not in the table either
+        with pytest.raises(LookupError):
+            pstats.device_peaks()
+
+    def test_cpu_has_no_peak_and_flag_overrides(self):
+        assert pstats.device_peak_flops() is None
+        paddle.set_flags({"FLAGS_device_peak_flops": 2e12})
+        try:
+            assert pstats.device_peak_flops() == 2e12
+            p = prof.Profiler(timer_only=True)
+            p.start()
+            p.step()
+            p.stop()
+            assert p.step_records[0]["mfu"] == 0.0
+        finally:
+            paddle.set_flags({"FLAGS_device_peak_flops": 0.0})

@@ -271,7 +271,7 @@ def _prefix_gate(vocab, retries=2):
 
 
 def _quant_gate(vocab):
-    """Quantized-serving gate (PERF.md "Quantized serving"). Three
+    """Quantized-serving gate (DESIGN.md "Quantized serving"). Three
     engines on the same seeded weights: the f32 reference at S slots
     sets the byte budget, an int8-pool engine at 2S slots must FIT that
     budget (allocator-exact ``kv_pool_bytes``, which mirrors ``alloc``
