@@ -2427,8 +2427,14 @@ class GenerativeEngine:
                 self._finish(w, gen, cs, slot, req, "handoff",
                              extra={"handoff": _ho.to_b64(raw)})
 
-    def _decode_step(self, w: ReplicaSlot, gen: int,
-                     cs: _ClassState) -> None:
+    def _decode_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                     phase: Optional[dict] = None) -> None:
+        """One batched decode step of class `cs`. `phase` is the worker
+        loop's {"iter", "rid"} (None with tracing off): the args of the
+        spans that partition this thread's time — `.stage` (the host
+        arrays' device_puts), `.launch` (the program call until it
+        returns), `.wait` (the blocking read of the results), all three
+        inside `generate.decode_step`, then `generate.emit`."""
         import jax
 
         with self._cv:
@@ -2442,6 +2448,9 @@ class GenerativeEngine:
         scratch = cs.n_slots    # the +1 row: padding lands there
         spec = self._spec
         k = self._spec_k
+
+        # the rows are read here, before the hang-injection point below: a
+        # worker that unwedges must not read rows its replacement owns
         slots = np.full((bucket,), scratch, np.int32)
         toks = np.zeros((bucket,), np.int32)
         lens = np.zeros((bucket,), np.int32)
@@ -2457,6 +2466,10 @@ class GenerativeEngine:
             topks[i] = row.req.top_k
             topps[i] = row.req.top_p
             keys[i] = row.key
+
+        def put(a):
+            return jax.device_put(a, w.device)
+
         devk = self._device_key(w.device)
         if spec:
             prog_keys = [(devk, "dpropose", cs.cap, bucket),
@@ -2486,48 +2499,55 @@ class GenerativeEngine:
             _chaos.hit("serving.decode_step", replica=w.rid,
                        generation=gen)
             with _tr.span("generate.decode_step", "serving", args,
-                          parent=rows[0].req.ctx):
-                with _cc.donated_cpu_guard(self._donate):
-                    if spec:
-                        # ONE fused k-step draft burst; the draft pool
-                        # advances through all k inputs so a full
-                        # accept finds every cached position next round
+                          parent=rows[0].req.ctx), \
+                    _cc.donated_cpu_guard(self._donate):
+                with _tr.span("generate.decode_step.stage", "serving",
+                              phase):
+                    staged = [put(slots), put(toks), put(lens),
+                              put(temps), put(topks), put(topps),
+                              put(keys)]
+                # `staged` is dropped inside the last launch: its device
+                # buffers are freed while the program runs, not between
+                # two steps
+                if spec:
+                    # ONE fused k-step draft burst; the draft pool
+                    # advances through all k inputs so a full accept
+                    # finds every cached position next round
+                    with _tr.span("generate.decode_step.launch",
+                                  "serving", phase):
                         props, cs.dbuf_k, cs.dbuf_v = self._program(
                             "dpropose", cs.cap, bucket, k)(
                                 self._draft_params_for(w.device),
-                                cs.dbuf_k, cs.dbuf_v,
-                                jax.device_put(slots, w.device),
-                                jax.device_put(toks, w.device),
-                                jax.device_put(lens, w.device))
+                                cs.dbuf_k, cs.dbuf_v, *staged[:3])
+                    with _tr.span("generate.decode_step.wait",
+                                  "serving", phase):
                         props = np.asarray(props)      # [bucket, k]
-                        tok_mat = np.concatenate(
+                    with _tr.span("generate.decode_step.stage",
+                                  "serving", phase):
+                        staged[1] = put(np.concatenate(
                             [toks[:, None], props[:, :k - 1]],
-                            axis=1).astype(np.int32)
+                            axis=1).astype(np.int32))
+                    with _tr.span("generate.decode_step.launch",
+                                  "serving", phase):
                         ys, khist, cs.buf_k, cs.buf_v = self._program(
                             "verify", cs.cap, bucket, k)(
                                 self._params_for(w.device),
-                                cs.buf_k, cs.buf_v,
-                                jax.device_put(slots, w.device),
-                                jax.device_put(tok_mat, w.device),
-                                jax.device_put(lens, w.device),
-                                jax.device_put(temps, w.device),
-                                jax.device_put(topks, w.device),
-                                jax.device_put(topps, w.device),
-                                jax.device_put(keys, w.device))
+                                cs.buf_k, cs.buf_v, *staged)
+                        del staged
+                    with _tr.span("generate.decode_step.wait",
+                                  "serving", phase):
                         ys = np.asarray(ys)            # [bucket, k]
                         khist = np.asarray(khist)      # [bucket, k, 2]
-                    else:
+                else:
+                    with _tr.span("generate.decode_step.launch",
+                                  "serving", phase):
                         nxt, nkeys, cs.buf_k, cs.buf_v = self._program(
                             "decode", cs.cap, bucket)(
                                 self._params_for(w.device),
-                                cs.buf_k, cs.buf_v,
-                                jax.device_put(slots, w.device),
-                                jax.device_put(toks, w.device),
-                                jax.device_put(lens, w.device),
-                                jax.device_put(temps, w.device),
-                                jax.device_put(topks, w.device),
-                                jax.device_put(topps, w.device),
-                                jax.device_put(keys, w.device))
+                                cs.buf_k, cs.buf_v, *staged)
+                        del staged
+                    with _tr.span("generate.decode_step.wait",
+                                  "serving", phase):
                         nxt = np.asarray(nxt)
                         nkeys = np.asarray(nkeys)
         finally:
@@ -2536,12 +2556,26 @@ class GenerativeEngine:
                     w.busy_since = None
                     w.compiling = False
                 w.batches += 1
+        with _tr.span("generate.emit", "serving", phase):
+            self._emit_step(w, gen, cs, rows, prog_keys, bucket,
+                            (props, ys, khist) if spec else (nxt, nkeys))
+
+    def _emit_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                   rows: list, prog_keys: list, bucket: int,
+                   results: tuple) -> None:
+        """What follows a decode step's read on the worker thread: the
+        rows' bookkeeping under the lock, every accepted token to its
+        stream, finished rows out of their slots."""
+        n = len(rows)
+        spec = self._spec
+        k = self._spec_k
         with self._cv:
             for pk in prog_keys:
                 self._warmed.add(pk)
         self.metrics.on_step(n, bucket)
         finished = []
         if spec:
+            props, ys, khist = results
             # accept the longest agreed prefix per row: ys[i, j] is
             # the target's OWN token at position j (same key chain as
             # plain decode), valid while every earlier draft proposal
@@ -2575,6 +2609,7 @@ class GenerativeEngine:
                 if done_row:
                     finished.append(row)
         else:
+            nxt, nkeys = results
             with self._cv:
                 if w.generation != gen:
                     return
@@ -2979,14 +3014,30 @@ class GenerativeEngine:
         # fresh zeroed pools; the zombie's buffers die with its frame
         state: Dict[int, _ClassState] = {
             cap: self._alloc_class(cap, w.device) for cap in self._caps}
+        # with tracing on, this thread's time is a partition of spans
+        # (admit | prefill | decode_step{stage, launch, wait} | emit |
+        # idle); those of one pass carry the same `iter`, so a reader
+        # adds up a pass's phases without guessing from times
+        it = 0
         while True:
-            with self._cv:
+            it += 1
+            phase = {"iter": it, "rid": w.rid} if _tr.enabled() else None
+            with _tr.span("generate.admit", "serving", phase), self._cv:
                 if w.generation != gen:
                     return
                 w.last_beat = time.monotonic()
                 admit_ok = w.state == "active" and not self._abort
                 admitted = self._admit_locked(w, gen, state) \
                     if admit_ok else []
+                depth = len(self._queue)
+            if phase is not None:
+                now_ns = time.perf_counter_ns()
+                for req, _cs, _slot in admitted:
+                    _tr.emit_span(
+                        "generate.queue_wait", req.t_enq_ns, now_ns,
+                        parent=req.ctx, cat="serving",
+                        args={"prompt_tokens": int(req.prompt.size),
+                              "queue_depth": depth, **phase})
             try:
                 for req, cs, slot in admitted:
                     if req.handoff is not None:
@@ -3010,7 +3061,9 @@ class GenerativeEngine:
                             self._cv.notify_all()
                             return
                         if not queue_live:
-                            self._cv.wait(0.05)
+                            with _tr.span("generate.idle", "serving",
+                                          phase):
+                                self._cv.wait(0.05)
                     continue
                 with self._cv:
                     aborting = self._abort
@@ -3021,7 +3074,7 @@ class GenerativeEngine:
                     continue
                 for cs in state.values():
                     if cs.rows:
-                        self._decode_step(w, gen, cs)
+                        self._decode_step(w, gen, cs, phase)
             except Exception as e:  # noqa: BLE001 — last line of
                 # defense: the worker thread must NEVER die (its slots
                 # would leak and the queue would starve); requeue the

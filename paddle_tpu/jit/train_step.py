@@ -247,7 +247,10 @@ class TrainStep:
         def grads_of(params, buffers, key, batch):
             def compute_loss(p):
                 full = {**p, **frozen}
-                with _st.functional_trace(), \
+                # the scope is what the device trace's split into forward,
+                # backward and recompute is read by (PERF.md §3)
+                with jax.named_scope("train.loss"), \
+                        _st.functional_trace(), \
                         swap_state(model, full, buffers) as (_, nb):
                     targs = [Tensor(a) for a in batch]
                     with _rng.rng_key_scope(key):
@@ -283,8 +286,9 @@ class TrainStep:
                 grads = {n: jax.lax.with_sharding_constraint(
                     g, NamedSharding(mesh, param_specs[n]))
                     for n, g in grads.items()}
-            new_params, new_opt_state = optimizer.functional_update(
-                params, grads, opt_state, lr=lr, step=step_idx)
+            with jax.named_scope("train.optimizer"):
+                new_params, new_opt_state = optimizer.functional_update(
+                    params, grads, opt_state, lr=lr, step=step_idx)
             if param_specs is not None:
                 new_params = {n: jax.lax.with_sharding_constraint(
                     p, NamedSharding(mesh, param_specs[n]))
@@ -362,8 +366,10 @@ class TrainStep:
 
             def apply_step(params, acc, opt_state, lr, step_idx):
                 grads = {n: g / k for n, g in acc.items()}
-                new_params, new_opt_state = optimizer.functional_update(
-                    params, grads, opt_state, lr=lr, step=step_idx)
+                with jax.named_scope("train.optimizer"):
+                    new_params, new_opt_state = \
+                        optimizer.functional_update(
+                            params, grads, opt_state, lr=lr, step=step_idx)
                 if param_specs is not None:
                     new_params = {n: jax.lax.with_sharding_constraint(
                         p, NamedSharding(mesh, param_specs[n]))
@@ -495,6 +501,10 @@ class TrainStep:
                 compiled = self.lowered(*batch).compile()
         except Exception as e:  # noqa: BLE001
             return {"error": repr(e)}
+        if _tracer.enabled():
+            # with the compiled program in hand: which scope each of its
+            # instructions was traced under, for readers of a device trace
+            _tracer.note_op_scopes(compiled.as_text())
         try:
             cost = compiled.cost_analysis()
             if isinstance(cost, (list, tuple)):

@@ -19,16 +19,27 @@ correlation:
   one Perfetto-loadable file (stable tids + thread-name metadata via
   observability.exporter).
 
+One clock with the device: while tracing is on, a live ``Span`` also
+holds a ``jax.profiler.TraceAnnotation`` of its own name (ids and scalar
+args as metadata). Under a running ``jax.profiler`` session the span is
+then an event of the ``/host:CPU`` plane of the same ``.xplane.pb`` as
+the device's operations, on the thread that ran it — which is how an
+idle gap of the device is attributed to what the host was doing
+(benchmarks/harness/host_spans.py). With no session running the
+annotation costs one check. ``emit_span`` (already measured, possibly on
+another thread's behalf) stays host-clock only.
+
 Overhead contract: tracing is off unless ``FLAGS_trace_dir`` is set.
 When off, ``span()`` returns a shared no-op handle and every hook site
-costs one module-attribute check — nothing allocates, nothing locks
-(tools/trace_smoke.py asserts the disabled-path cost stays in the
-noise).
+costs one module-attribute check — nothing allocates, nothing locks,
+nothing of jax is imported (tools/trace_smoke.py asserts the
+disabled-path cost stays in the noise).
 """
 from __future__ import annotations
 
 import itertools
 import os
+import re
 import threading
 import time
 from collections import deque
@@ -160,13 +171,38 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, on the first live span
+_SCALARS = (bool, int, float, str)
+
+
+def _annotate(name: str, ctx: TraceContext,
+              parent: Optional[TraceContext], args: Optional[dict]):
+    """An entered TraceAnnotation called `name` carrying the span's ids
+    and the scalars of `args`. Importing jax.profiler initialises no
+    backend (the rule _process_index states)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    meta = {"trace": ctx.trace_id, "span": ctx.span_id}
+    if parent is not None:
+        meta["parent"] = parent.span_id
+    if args:
+        # `name` is the annotation's own positional argument
+        meta.update((k, v) for k, v in args.items()
+                    if isinstance(v, _SCALARS) and k != "name")
+    ann = _ANNOTATION(name, **meta)
+    ann.__enter__()
+    return ann
+
 
 class Span:
     """Live span: opens on ``__enter__`` (becoming the thread's current
     context), emits its chrome-trace event on ``__exit__``."""
 
     __slots__ = ("name", "cat", "args", "ctx", "_parent", "_prev",
-                 "_begin_ns")
+                 "_begin_ns", "_ann")
 
     def __init__(self, name: str, cat: str = "span",
                  args: Optional[dict] = None,
@@ -178,6 +214,7 @@ class Span:
         self.ctx: Optional[TraceContext] = None
         self._prev = None
         self._begin_ns = 0
+        self._ann = None
 
     def set(self, **kwargs):
         """Attach/override args on a live span."""
@@ -194,11 +231,13 @@ class Span:
         self._parent = parent
         self._prev = getattr(_TLS, "ctx", None)
         _TLS.ctx = self.ctx
+        self._ann = _annotate(self.name, self.ctx, parent, self.args)
         self._begin_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         end_ns = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         _TLS.ctx = self._prev
         a = {"trace": self.ctx.trace_id, "span": self.ctx.span_id}
         if self._parent is not None:
@@ -272,6 +311,9 @@ def step_iter(it, name: str = "train.step", cat: str = "train",
             if got_item:
                 root.__exit__(None, None, None)
             else:
+                # (its annotation can only be closed, not withdrawn: a
+                # profiler capture shows the probe as a short train.step)
+                root._ann.__exit__(None, None, None)
                 _TLS.ctx = root._prev
 
 
@@ -341,14 +383,39 @@ def export(path: Optional[str] = None, profiler_events=None,
     return _exporter.write_chrome_trace(path, events, process_name=pname)
 
 
+# ------------------------------------------------------ device op scopes --
+_OP_NAME_LINE = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?\bop_name="([^"]*)"', re.M)
+_OP_SCOPES: dict = {}
+
+
+def note_op_scopes(compiled_text: str) -> None:
+    """Keep the map instruction name -> op_name of one compiled program's
+    text (`Compiled.as_text()`). A device trace names an operation by its
+    instruction but says nothing of the `jax.named_scope` it was traced
+    under; a reader of the trace that runs in this process joins the two
+    through `op_scopes()`. No-op with tracing off."""
+    if _ENABLED:
+        found = dict(_OP_NAME_LINE.findall(compiled_text))
+        with _LOCK:
+            _OP_SCOPES.update(found)
+
+
+def op_scopes() -> dict:
+    with _LOCK:
+        return dict(_OP_SCOPES)
+
+
 def reset() -> None:
-    """Drop recorded spans (tests; the ring keeps its capacity)."""
+    """Drop recorded spans and op scopes (tests; the ring keeps its
+    capacity)."""
     global _DROPPED
     with _LOCK:
         _SPANS.clear()
+        _OP_SCOPES.clear()
         _DROPPED = 0
 
 
 __all__ = ["TraceContext", "Span", "span", "emit_span", "current_context",
            "use_context", "enabled", "reconfigure", "step_iter", "spans",
-           "stats", "export", "reset"]
+           "stats", "export", "reset", "note_op_scopes", "op_scopes"]

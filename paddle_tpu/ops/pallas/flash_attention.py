@@ -143,6 +143,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, impl):
         k_spec = pl.BlockSpec((1, L, d), _im(lambda b, i: (b, 0, 0)))
     return pl.pallas_call(
         kern,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), _im(lambda b, i: (b, i, 0))),
@@ -359,6 +360,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, impl, res, g):
 
     dq = pl.pallas_call(
         dq_kern,
+        name="flash_bwd_dq",
         grid=(bh, L // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), _im(lambda b, i: (b, i, 0))),
@@ -386,6 +388,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, impl, res, g):
             functools.partial(_dkv_kernel_nn2, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k, seq_len=L),
+            name="flash_bwd_dkv",
             grid=(bh, L // block_k),
             in_specs=[full_ld, full_dl, dkv_k_spec, dkv_k_spec,
                       full_ld, full_dl, row_l, row_l],
@@ -407,6 +410,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, impl, res, g):
         functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=L,
                           impl=impl),
+        name="flash_bwd_dkv",
         grid=(bh, L // block_k),
         in_specs=[
             full_ld,

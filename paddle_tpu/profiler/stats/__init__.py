@@ -2,10 +2,10 @@
 
 The subsystem the reference implements in
 `python/paddle/profiler/profiler_statistic.py` (+ mem_tracing.h): consumes
-the host RecordEvent stream and the jax.profiler device trace and produces
+the host RecordEvent stream and produces
 
-- a per-op summary (calls, total/avg/max/min host time, device time,
-  analytic FLOPs, MFU),
+- a per-op summary (calls, total/avg/max/min host time, analytic FLOPs,
+  MFU),
 - a per-layer roll-up keyed on the nn.Layer name stack,
 - a per-step time/FLOPs/MFU series,
 - a per-step HBM live/peak memory report with allocation events and
@@ -25,8 +25,7 @@ from ...core import dispatch as _dispatch
 from ...core import state as _st
 from . import aggregator, memory
 from .aggregator import (OpStat, build_table, fmt_bytes, fmt_flops,
-                         layer_stats, load_device_trace, merge_device_totals,
-                         op_stats)
+                         layer_stats, op_stats)
 from .flops import DEVICE_PEAKS, device_peak_flops, device_peaks
 from .memory import MemoryTracer
 
@@ -34,7 +33,7 @@ __all__ = [
     "install", "uninstall", "active", "add_flops", "note_donation",
     "DEVICE_PEAKS", "device_peaks", "device_peak_flops", "build_summary",
     "build_summary_dict",
-    "op_stats", "layer_stats", "load_device_trace", "merge_device_totals",
+    "op_stats", "layer_stats",
     "OpStat", "MemoryTracer", "build_table", "fmt_flops", "fmt_bytes",
     "register_summary_provider", "unregister_summary_provider",
 ]
@@ -196,8 +195,6 @@ def build_summary(prof, sorted_by=None, time_unit="ms") -> str:
     """Render every summary section from a (stopped or live) Profiler."""
     events = prof.events()
     ops = op_stats(events)
-    kernels = load_device_trace(getattr(prof, "_jax_dir", None))
-    merge_device_totals(ops, kernels)
     peak = device_peak_flops()
     sections = [
         f"Profiler statistics (time unit: ms; FLOPs are analytic forward "
@@ -207,18 +204,16 @@ def build_summary(prof, sorted_by=None, time_unit="ms") -> str:
 
     rows = []
     for st in sorted(ops.values(), key=lambda s: -s.total):
-        host_s = st.total / 1e6
-        dev_s = st.device_total / 1e6
         rows.append([
             st.name, st.calls, _ms(st.total), _ms(st.avg), _ms(st.max),
-            _ms(st.min if st.calls else 0.0), _ms(st.device_total),
+            _ms(st.min if st.calls else 0.0),
             fmt_flops(st.flops) if st.flops else "-",
-            _mfu_str(st.flops, dev_s or host_s, peak),
+            _mfu_str(st.flops, st.total / 1e6, peak),
         ])
     sections.append(build_table(
         "Operator Summary",
-        ["Name", "Calls", "Total", "Avg", "Max", "Min", "Device", "FLOPs",
-         "MFU"], rows))
+        ["Name", "Calls", "Total", "Avg", "Max", "Min", "FLOPs", "MFU"],
+        rows))
 
     # dispatch-cache health rides with the Operator Summary: a cold or
     # thrashing plan cache is itself the top "operator" on eager traces
@@ -286,12 +281,6 @@ def build_summary(prof, sorted_by=None, time_unit="ms") -> str:
                     parts.append(f"{k}={v}")
             sections.append("buffer donation: " + ", ".join(parts))
 
-    if kernels:
-        krows = [[k, f"{v / 1000.0:.3f}"] for k, v in sorted(
-            kernels.items(), key=lambda kv: -kv[1])[:15]]
-        sections.append(build_table(
-            "Kernel Summary (device trace)", ["Kernel", "Total(ms)"],
-            krows))
     return "\n\n".join(sections)
 
 

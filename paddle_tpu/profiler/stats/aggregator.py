@@ -2,8 +2,9 @@
 
 Analog of the reference's `python/paddle/profiler/profiler_statistic.py`
 (`_build_table`, EventSummary/StatisticData at :291): turns the raw host
-RecordEvent stream (chrome-trace dicts) plus an optional jax.profiler
-device trace into per-op and per-layer statistic tables.
+RecordEvent stream (chrome-trace dicts) into per-op and per-layer
+statistic tables. Device time is not here: this jax's profiler leaves an
+`.xplane.pb`, which benchmarks/harness/trace_reduce.py reads.
 
 Event categories (the `cat` field):
 - ``Operator``     — one dispatch through core/dispatch.apply; carries
@@ -16,10 +17,6 @@ Event categories (the `cat` field):
 """
 from __future__ import annotations
 
-import glob
-import gzip
-import json
-import os
 from typing import Dict, Iterable, List, Optional
 
 _OP_CATS = ("Operator", "PythonOp", "UserDefined", "ProfileStep",
@@ -27,11 +24,10 @@ _OP_CATS = ("Operator", "PythonOp", "UserDefined", "ProfileStep",
 
 
 class OpStat:
-    """Per-key accumulator: calls, host total/max/min (us), device total
-    (us, when a device trace was merged), analytic FLOPs."""
+    """Per-key accumulator: calls, host total/max/min (us), analytic
+    FLOPs."""
 
-    __slots__ = ("name", "cat", "calls", "total", "max", "min",
-                 "device_total", "flops")
+    __slots__ = ("name", "cat", "calls", "total", "max", "min", "flops")
 
     def __init__(self, name: str, cat: str = "Operator"):
         self.name = name
@@ -40,7 +36,6 @@ class OpStat:
         self.total = 0.0
         self.max = 0.0
         self.min = float("inf")
-        self.device_total = 0.0
         self.flops = 0
 
     def add(self, dur_us: float, flops: int = 0):
@@ -96,56 +91,6 @@ def layer_stats(events: Iterable[dict]) -> Dict[str, OpStat]:
             if layer == path or layer.startswith(path + "."):
                 st.flops += flops
     return out
-
-
-# ------------------------------------------------------- device trace ----
-def load_device_trace(trace_dir: Optional[str]) -> Dict[str, float]:
-    """Best-effort parse of the jax.profiler (XLA/TensorBoard) chrome
-    trace dump: kernel name -> total device-time us. Returns {} when no
-    trace exists (CPU runs, timer_only)."""
-    if not trace_dir or not os.path.isdir(trace_dir):
-        return {}
-    paths = sorted(
-        glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                  recursive=True) +
-        glob.glob(os.path.join(trace_dir, "**", "*.trace.json"),
-                  recursive=True),
-        key=os.path.getmtime)
-    if not paths:
-        return {}
-    try:
-        p = paths[-1]
-        if p.endswith(".gz"):
-            with gzip.open(p, "rt") as f:
-                data = json.load(f)
-        else:
-            with open(p) as f:
-                data = json.load(f)
-    except Exception:  # noqa: BLE001 — a corrupt trace must not sink summary
-        return {}
-    totals: Dict[str, float] = {}
-    for e in data.get("traceEvents", []):
-        if e.get("ph") != "X":
-            continue
-        name = e.get("name", "")
-        totals[name] = totals.get(name, 0.0) + float(e.get("dur", 0.0))
-    return totals
-
-
-def merge_device_totals(ops: Dict[str, OpStat],
-                        kernels: Dict[str, float]) -> None:
-    """Fill OpStat.device_total by name containment (XLA kernel names
-    embed the originating op name when metadata survives fusion; unmatched
-    kernels stay visible in the Kernel table). Each kernel credits exactly
-    ONE op — the longest matching name — so overlapping op names (conv2d
-    vs conv2d_transpose, dot vs scaled_dot_product_attention) don't
-    double-count device time."""
-    names = sorted((n for n in ops if n), key=len, reverse=True)
-    for kname, dur in kernels.items():
-        for name in names:
-            if name in kname:
-                ops[name].device_total += dur
-                break
 
 
 # ------------------------------------------------------- table builder --

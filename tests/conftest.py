@@ -13,7 +13,9 @@ if "host_platform_device_count" not in flags:
 
 # No pytest-timeout in the image: a watchdog dumps all stacks and aborts
 # the process if ONE TEST outlasts the window (re-armed at every test
-# start — not a limit on the whole suite). The window sits well below the
+# start and cancelled at every test end — not a limit on the whole suite,
+# nor on a worker that has run out of tests and waits for the others).
+# The window sits well below the
 # tier-1 command's own time limit (1,470 s as the driver runs it), so a
 # hang dumps stacks and frees its worker instead of eating the run; every
 # subprocess wait inside a test is bounded below it. Under xdist only the
@@ -40,6 +42,13 @@ def pytest_runtest_logstart(nodeid, location):
     # a single call
     if _armed:
         _fh.dump_traceback_later(_WEDGE_WINDOW_S, exit=True)
+
+
+def pytest_runtest_logfinish(nodeid, location):
+    # between tests nothing can wedge: an xdist worker with no test left
+    # must not be aborted 600 s after its last one began (ROADMAP D0(b))
+    if _armed:
+        _fh.cancel_dump_traceback_later()
 
 
 # ----------------------------------------------------------- native libs --

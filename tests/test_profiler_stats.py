@@ -99,22 +99,6 @@ class TestKnownTrace:
         assert d["top_ops"][0]["calls"] == 2
 
 
-class TestDeviceMerge:
-    def test_kernel_credits_longest_match_only(self):
-        ops = {"conv2d": aggregator.OpStat("conv2d"),
-               "conv2d_transpose": aggregator.OpStat("conv2d_transpose"),
-               "dot": aggregator.OpStat("dot")}
-        aggregator.merge_device_totals(ops, {
-            "fusion.conv2d_transpose.42": 100.0,
-            "conv2d.7": 30.0,
-            "scaled_dot_product_attention_kernel": 5.0,
-        })
-        # each kernel credits exactly one op (longest matching name)
-        assert ops["conv2d_transpose"].device_total == 100.0
-        assert ops["conv2d"].device_total == 30.0
-        assert ops["dot"].device_total == 5.0
-
-
 class TestNameStack:
     def test_layerlist_setitem_and_insert_requalify(self):
         net = nn.Layer()
