@@ -126,20 +126,11 @@ define_flag("attention_chunk", 256,
             "and seq >= 1024): lax.scan over query blocks with per-chunk "
             "remat bounds attention HBM traffic at [B,H,chunk,L] instead "
             "of the full [L,L] score tensor; 0 disables (plain einsum)")
-define_flag("flash_block_q", 128,
-            "flash-attention query tile size (rows per MXU pass); tune "
-            "with the chip profile — larger tiles amortize HBM traffic "
-            "until VMEM pressure wins")
-define_flag("flash_block_k", 128,
-            "flash-attention key/value tile size")
 define_flag("flash_dot_impl", "auto",
-            "matmul strategy inside the flash kernels: 'bf16' feeds "
-            "storage-dtype operands straight into NT/TN MXU dots, 'nn' "
-            "restructures every dot into canonical NN form with "
-            "pre-transposed K/V and in-kernel f32 transposes, 'nn2' is "
-            "nn with zero in-kernel transposes (Q^T/dO^T in, dK^T/dV^T "
-            "out), 'f32' casts blocks to f32 before the dots (~4x "
-            "slower MXU rate), 'auto' is bf16")
+            "operands of the matmuls inside the flash kernels: 'bf16' "
+            "feeds storage-dtype operands straight into the MXU, 'f32' "
+            "casts blocks to f32 before the dots (~4x slower MXU rate), "
+            "'auto' is bf16")
 define_flag("dataloader_fork_workers", False,
             "DataLoader num_workers>0 uses forked worker PROCESSES (numpy-"
             "only datasets; forking after jax backend init is unsafe for "

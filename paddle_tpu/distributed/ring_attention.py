@@ -61,8 +61,7 @@ def _flash_block(qh, kh, vh, scale, causal, interpret):
     q2 = qh.reshape(B * H, L, D)
     k2 = kh.reshape(B * H, L, D)
     v2 = vh.reshape(B * H, L, D)
-    bq = min(128, L) if L % min(128, L) == 0 else L
-    out, lse = _fwd(q2, k2, v2, scale, causal, bq, bq, interpret,
+    out, lse = _fwd(q2, k2, v2, scale, causal, interpret,
                     _resolve_dot_impl())
     return (out.reshape(B, H, L, D),
             lse.reshape(B, H, L))

@@ -35,8 +35,7 @@ def _local_attention(q, k, v, scale, causal, use_flash=False,
         q2 = jnp.swapaxes(q, 1, 2).reshape(B * h, L, D)
         k2 = jnp.swapaxes(k, 1, 2).reshape(B * h, L, D)
         v2 = jnp.swapaxes(v, 1, 2).reshape(B * h, L, D)
-        bq = min(128, L) if L % min(128, L) == 0 else L
-        out, _ = _fwd(q2, k2, v2, scale, causal, bq, bq, flash_interpret,
+        out, _ = _fwd(q2, k2, v2, scale, causal, flash_interpret,
                       _resolve_dot_impl())
         return jnp.swapaxes(out.reshape(B, h, L, D), 1, 2)
     qh = jnp.swapaxes(q, 1, 2)

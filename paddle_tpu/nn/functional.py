@@ -1063,11 +1063,8 @@ def _sdpa_p(q, k, v, mask=None, dropout_p=0.0, is_causal=False, scale=None):
         from ..ops.pallas import (
             flash_attention as _flash, flash_attention_supported)
 
-        bq, bk = int(flag("flash_block_q")), int(flag("flash_block_k"))
-        if flash_attention_supported(q.shape, q.shape[-1], bool(is_causal),
-                                     block_q=bq, block_k=bk):
-            return _flash(q, k, v, causal=bool(is_causal), sm_scale=scale,
-                          block_q=bq, block_k=bk)
+        if flash_attention_supported(q.shape, q.dtype, bool(is_causal)):
+            return _flash(q, k, v, causal=bool(is_causal), sm_scale=scale)
     # pure-XLA chunked path (no Pallas) for shapes and backends the
     # gate does not admit: the einsum path materializes [B,H,L,L] scores
     # in HBM. Scanning query chunks with per-chunk remat bounds live

@@ -27,14 +27,19 @@ def _ref_attn(q, k, v, causal):
     return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vh), 1, 2)
 
 
+def _grad_errors(f1, f2, args):
+    """Max error of each gradient over that gradient's max."""
+    g1 = jax.grad(f1, (0, 1, 2))(*args)
+    g2 = jax.grad(f2, (0, 1, 2))(*args)
+    return [float(jnp.abs(a.astype(jnp.float32) - b).max())
+            / (float(jnp.abs(b).max()) + 1e-9) for a, b in zip(g1, g2)]
+
+
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("impl", ["bf16", "nn", "nn2", "f32"])
+@pytest.mark.parametrize("impl", ["bf16", "f32"])
 def test_flash_matches_reference_fwd_bwd(causal, impl):
-    """Every dot strategy (FLAGS_flash_dot_impl) must be exact against
-    the einsum reference — 'nn' restructures every dot into canonical NN
-    form (pre-transposed K/V + in-kernel transposes), 'nn2' additionally
-    avoids in-kernel transposes (Q^T/dO^T in, dK^T/dV^T out), 'f32'
-    casts blocks; same math all four ways."""
+    """Both operand choices (FLAGS_flash_dot_impl) must be exact against
+    the einsum reference on f32 inputs — 'f32' only casts blocks."""
     rng = np.random.RandomState(0)
     B, L, H, D = 2, 256, 2, 64
     q, k, v = [jnp.asarray(rng.randn(B, L, H, D), jnp.float32)
@@ -47,41 +52,158 @@ def test_flash_matches_reference_fwd_bwd(causal, impl):
     f1 = lambda q, k, v: (flash_attention(  # noqa: E731
         q, k, v, causal=causal, interpret=True, impl=impl) ** 2).sum()
     f2 = lambda q, k, v: (_ref_attn(q, k, v, causal) ** 2).sum()  # noqa: E731
-    g1 = jax.grad(f1, (0, 1, 2))(q, k, v)
-    g2 = jax.grad(f2, (0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        scale = float(jnp.abs(b).max()) + 1e-9
-        assert float(jnp.abs(a - b).max()) / scale < 2e-4
+    assert max(_grad_errors(f1, f2, (q, k, v))) < 2e-4
+
+
+# (name, B, L, H, D, explicit plan or None for the shape's own)
+_SCHEDULES = [
+    # one square of 1,024 in 256 x 256 tiles: what both train cells get
+    ("cell", 1, 1024, 2, 64, None),
+    # 2 x 2 squares on the grid, scratch accumulators: a square below the
+    # diagonal (unmasked), two on it, one above it (skipped)
+    ("squares", 1, 2048, 1, 64, None),
+    # the same at a small size, 2 batch-heads a step, and a scale that is
+    # no power of two (head_dim 128: the score tile is scaled, not q)
+    ("grid-small", 1, 512, 4, 128, (256, 128, 128, 2)),
+    # tile_q != tile_k: a masked tile holds rows with no column left
+    ("uneven", 1, 384, 2, 64, (384, 128, 384, 1)),
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("case", _SCHEDULES, ids=[c[0] for c in _SCHEDULES])
+def test_flash_schedule_matches_reference(case, causal):
+    """Forward, log-sum-exp and gradients of the kernels under a tile
+    plan against the einsum reference, f32 inputs."""
+    from paddle_tpu.ops.pallas.flash_attention import (
+        TilePlan, _bwd, _fwd, tile_plan)
+
+    _, B, L, H, D, plan = case
+    plan = TilePlan(*plan) if plan else tile_plan(L, D, 4, causal)
+    if case[0] == "cell":       # the cells run bf16: their plan, f32 data
+        plan = tile_plan(L, D, 2, causal)
+        assert (plan.block, plan.tile_q, plan.tile_k) == (1024, 256, 256)
+    rng = np.random.RandomState(0)
+    q, k, v, g = [jnp.asarray(rng.randn(B, L, H, D), jnp.float32)
+                  for _ in range(4)]
+    scale = 1.0 / math.sqrt(D)
+
+    def to_bh(x):
+        return jnp.swapaxes(x, 1, 2).reshape(B * H, L, D)
+
+    def from_bh(x):
+        return jnp.swapaxes(x.reshape(B, H, L, D), 1, 2)
+
+    out, lse = _fwd(to_bh(q), to_bh(k), to_bh(v), scale, causal, True,
+                    "bf16", plan)
+    np.testing.assert_allclose(np.asarray(from_bh(out)),
+                               np.asarray(_ref_attn(q, k, v, causal)),
+                               atol=2e-5)
+    logits = jnp.einsum("bqd,bkd->bqk", to_bh(q), to_bh(k)) * scale
+    if causal:
+        logits = jnp.where(jnp.tril(jnp.ones((L, L), bool)), logits,
+                           -jnp.inf)
+    np.testing.assert_allclose(np.asarray(lse[:, 0]),
+                               np.asarray(jax.nn.logsumexp(logits, -1)),
+                               atol=2e-5)
+    grads = _bwd(scale, causal, True, "bf16",
+                 (to_bh(q), to_bh(k), to_bh(v), out, lse), to_bh(g), plan)
+    want = jax.grad(lambda *a: (_ref_attn(*a, causal) * g).sum(),
+                    (0, 1, 2))(q, k, v)
+    for got, ref in zip(grads, want):
+        err = float(jnp.abs(from_bh(got) - ref).max())
+        assert err / float(jnp.abs(ref).max()) < 2e-4
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_bf16_against_f32_reference(causal):
+    """bf16 operands, as the cells run them, against the f32 reference on
+    the same (bf16-rounded) values. The kernel rounds p and dS to bf16 for
+    the MXU (2^-9 relative) and its output and gradients to bf16: 2e-2 of
+    the output's max and 3e-2 of each gradient's max hold with room (read
+    here: 4e-3 and 1e-2)."""
+    rng = np.random.RandomState(0)
+    q, k, v = [jnp.asarray(rng.randn(1, 512, 2, 64), jnp.bfloat16)
+               for _ in range(3)]
+    q32, k32, v32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    ref = _ref_attn(q32, k32, v32, causal)
+    assert out.dtype == jnp.bfloat16
+    assert float(jnp.abs(out.astype(jnp.float32) - ref).max()) \
+        < 2e-2 * float(jnp.abs(ref).max())
+
+    f1 = lambda q, k, v: (flash_attention(  # noqa: E731
+        q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+        v.astype(jnp.bfloat16), causal=causal,
+        interpret=True).astype(jnp.float32) ** 2).sum()
+    f2 = lambda q, k, v: (_ref_attn(q, k, v, causal) ** 2).sum()  # noqa: E731
+    assert max(_grad_errors(f1, f2, (q32, k32, v32))) < 3e-2
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("seq", [128, 256, 1024, 2048])
+def test_tile_plan(seq, head_dim, itemsize):
+    """The plan divides the sequence, fits the VMEM budget it states and
+    takes fewer grid steps than the 128 x 128 schedule it replaced (one
+    step per batch-head and 128 rows) at both cells' per-shard
+    batch-heads: 64 x 16 and 16 x 16."""
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    plan = fa.tile_plan(seq, head_dim, itemsize, True)
+    assert plan == fa.tile_plan(seq, head_dim, itemsize, False)
+    assert seq % plan.block == 0 and plan.block % plan.tile_q == 0 \
+        and plan.block % plan.tile_k == 0
+    assert plan.tile_q % 128 == 0 and plan.tile_k % 128 == 0
+    assert plan.block * itemsize <= fa._MAX_BLOCK_BYTES
+    assert 1 <= plan.bh <= fa._MAX_BH
+    assert fa._step_vmem_bytes(seq, head_dim, itemsize, plan.block,
+                               plan.bh) <= fa._VMEM_BUDGET < fa._VMEM_LIMIT
+    for batch_heads in (1024, 256):
+        assert batch_heads % plan.bh_per_step(batch_heads) == 0
+        steps = (batch_heads // plan.bh_per_step(batch_heads)
+                 * (seq // plan.block) ** 2)
+        assert steps < batch_heads * (seq // 128)
+    if seq * itemsize <= fa._MAX_TILE_BYTES:    # short: one tile, no loop
+        assert plan.block == plan.tile_q == plan.tile_k == seq
 
 
 def test_dot_impl_resolves_in_process():
     """FLAGS_flash_dot_impl: 'auto' is 'bf16' — decided in this process,
     no probe child, no file — a named strategy is itself, anything else
-    raises."""
+    raises (the retired 'nn'/'nn2' too)."""
     from paddle_tpu.core.flags import flag, set_flags
     from paddle_tpu.ops.pallas.flash_attention import _resolve_dot_impl
 
     assert flag("flash_dot_impl") == "auto" and _resolve_dot_impl() == "bf16"
     try:
-        for impl in ("bf16", "nn", "nn2", "f32"):
+        for impl in ("bf16", "f32"):
             set_flags({"FLAGS_flash_dot_impl": impl})
             assert _resolve_dot_impl() == impl
-        set_flags({"FLAGS_flash_dot_impl": "fp8"})
-        with pytest.raises(ValueError, match="auto|bf16|nn|nn2|f32"):
-            _resolve_dot_impl()
+        for impl in ("fp8", "nn", "nn2"):
+            set_flags({"FLAGS_flash_dot_impl": impl})
+            with pytest.raises(ValueError, match="auto|bf16|f32"):
+                _resolve_dot_impl()
     finally:
         set_flags({"FLAGS_flash_dot_impl": "auto"})
 
 
 def test_supported_gate():
-    assert flash_attention_supported((2, 256, 4, 64), 64, True)
-    assert not flash_attention_supported((2, 200, 4, 64), 64, True)
-    assert not flash_attention_supported((2, 256, 4, 512), 512, True)
+    """The gate is the plan: multiples of 128 (or one short tile of a
+    multiple of 8 rows), heads up to 256 wide."""
+    assert flash_attention_supported((2, 256, 4, 64), jnp.bfloat16, True)
+    assert flash_attention_supported((2, 1152, 4, 128), jnp.float32, False)
+    assert flash_attention_supported((2, 64, 4, 16), jnp.float32, True)
+    assert not flash_attention_supported((2, 200, 4, 64), jnp.bfloat16, True)
+    assert not flash_attention_supported((2, 100, 4, 64), jnp.bfloat16, True)
+    assert not flash_attention_supported((2, 256, 4, 512), jnp.bfloat16,
+                                         True)
 
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("impl", ["bf16", "nn", "nn2", "f32"])
+@pytest.mark.parametrize("impl", ["bf16", "f32"])
 def test_mosaic_tpu_lowering(causal, dtype, impl):
     """Cross-lower the kernels for the TPU target on the CPU host.
     jax.export only LOWERS (jaxpr -> Mosaic MLIR in a custom call); it
