@@ -11,6 +11,12 @@ def log(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
 
+def verdict(compared: dict) -> bool:
+    """`correct`: every number compared, {name: (value, limit)}, is within
+    its limit. A value that is not a number is not within it."""
+    return all(value <= limit for value, limit in compared.values())
+
+
 def require_chips(n: int):
     """jax's first n devices, or SystemExit (nothing on stdout) where jax
     finds no TPU or fewer chips than the cell asks for."""

@@ -18,6 +18,27 @@ def mfu(tokens_per_s_per_chip: float, n_params: int,
         / peak_flops
 
 
+def serve_model_flops_per_token(architecture: dict) -> float:
+    """2·N: one forward pass's multiply-adds' worth for one token, N the
+    parameters a token passes through — `n_params_active` where the
+    configuration's file gives it (sparse experts), else `n_params`.
+    Attention's own FLOPs over the cache are NOT counted, as in training;
+    nor is a padded row, a draft's pass or a recomputed prefix."""
+    n = architecture.get("n_params_active", architecture.get("n_params"))
+    if n is None:
+        raise KeyError("architecture.n_params (or n_params_active): the "
+                       "configuration's file does not give it")
+    return 2.0 * float(n)
+
+
+def decode_step_mfu(rows_per_step: float, step_seconds: float,
+                    architecture: dict, peak_flops: float) -> float:
+    """The share of the chip's peak that a whole decode step uses: the
+    model FLOPs of its real rows over the step's time and the peak."""
+    return serve_model_flops_per_token(architecture) * rows_per_step \
+        / step_seconds / peak_flops
+
+
 def flash_attention_fwd(batch: int, heads: int, seq_q: int, seq_k: int,
                         head_dim: int, causal: bool,
                         itemsize: int = 2) -> dict:

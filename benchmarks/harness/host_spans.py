@@ -27,7 +27,8 @@ trace_reduce's interval arithmetic unchanged. It looks for:
   — this reader runs in the program's process, after the window.
 - **Kernels.** A `pallas_call(name=…)` names its HLO instruction
   (`%flash_fwd.14`), so the flash kernels are the events whose instruction
-  name, less its number, is `flash_fwd`, `flash_bwd_dq` or `flash_bwd_dkv`.
+  name, less its number, is `flash_fwd` or `flash_bwd_dkv` (the one fused
+  backward since PR 27).
 
 Against a program that has none of these (the parent of the PR that
 brought them) every function here finds nothing and says so with None or
@@ -46,7 +47,7 @@ from . import trace_reduce as tr
 
 HOST_PLANE = "/host:CPU"
 SCOPE_CLASSES = ("forward", "backward", "recompute", "optimizer", "other")
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+KERNELS = ("flash_fwd", "flash_bwd_dkv")
 UNATTRIBUTED = "unattributed"
 
 Span = Tuple[str, str, float, float, dict]     # name, thread, start, end, args
@@ -207,8 +208,22 @@ def idle_by_span(view: dict) -> dict:
     """The lowest device's idle time inside the window, split by program
     span -> {"idle_s", "attributed_share", "by_span": {name: seconds},
     "unattributed_between": {"after A, before B": seconds}, "longest_gaps":
-    [{"seconds", "by_span"}]}; by_span holds "unattributed" too."""
+    [{"seconds", "by_span", "after_op", "before_op"}]}; by_span holds
+    "unattributed" too, and a gap lies between two of the device's
+    operations, named as trace_reduce names them."""
     dev = min(view["ops"])
+    ops = view["ops"][dev]
+
+    def op_ending_before(t: float) -> str:
+        row = max((r for r in ops if r[2] <= t), key=lambda r: r[2],
+                  default=None)
+        return tr.short_name(row[0]) if row else "start"
+
+    def op_starting_after(t: float) -> str:
+        row = min((r for r in ops if r[1] >= t), key=lambda r: r[1],
+                  default=None)
+        return tr.short_name(row[0]) if row else "end"
+
     w0, w1 = view["window"]
     busy = tr.union((max(r[1], w0), min(r[2], w1)) for r in view["ops"][dev])
     gaps = tr.subtract([(w0, w1)], busy)
@@ -225,9 +240,33 @@ def idle_by_span(view: dict) -> dict:
         "unattributed_between": _seconds(between(left, spans), top=6),
         "longest_gaps": [
             {"seconds": (b - a) / 1e9,
-             "by_span": _seconds(split_by_span([(a, b)], spans)[0])}
+             "by_span": _seconds(split_by_span([(a, b)], spans)[0]),
+             "after_op": op_ending_before(a),
+             "before_op": op_starting_after(b)}
             for a, b in longest],
     }
+
+
+def longest_gaps_by_span(run: dict, top: int = 2) -> Optional[list]:
+    """The result line's `breakdown.idle_gaps` by what the host was doing:
+    [[name, seconds]] of the lowest device's five longest idle gaps, each
+    named by the `top` spans that cover most of it with their shares
+    ("generate.decode_step.stage 47% + generate.decode_step.wait 32%");
+    the part no span covers says between which operations the gap lies
+    ("unattributed 100% (after pad_add_fusion, before copy.403)"). None
+    where the run has no profile or the profile no program span."""
+    view = load(run)
+    if view is None or not view["spans"]:
+        return None
+    out = []
+    for gap in idle_by_span(view)["longest_gaps"]:
+        parts = [f"{name} {100 * s / gap['seconds']:.0f}%"
+                 for name, s in list(gap["by_span"].items())[:top] if s > 0]
+        name = " + ".join(parts)
+        if UNATTRIBUTED in name:
+            name += f" (after {gap['after_op']}, before {gap['before_op']})"
+        out.append([name, gap["seconds"]])
+    return out
 
 
 def modules_inside(view: dict, span_names: Sequence[str]) -> dict:
@@ -299,14 +338,12 @@ def flash_roofline(kernels: dict, fwd_s: float, bwd_s: float
     """The kernels' share of their roofline: the least time the calls
     seen could take over the time they took. `fwd_s`/`bwd_s`: the least
     time of ONE forward call and of ONE backward pass. A remat'd forward
-    is a call; a backward pass is counted once although two kernels run
-    it (the larger of their event counts)."""
+    is a call; a backward pass is one `flash_bwd_dkv` event."""
     took = sum(k["seconds"] for k in kernels.values())
     if not took:
         return None
     n_fwd = kernels.get("flash_fwd", {}).get("events", 0)
-    n_bwd = max(kernels.get("flash_bwd_dq", {}).get("events", 0),
-                kernels.get("flash_bwd_dkv", {}).get("events", 0))
+    n_bwd = kernels.get("flash_bwd_dkv", {}).get("events", 0)
     least = n_fwd * fwd_s + n_bwd * bwd_s
     return {"forward_calls": n_fwd, "backward_passes": n_bwd,
             "least_s": least, "took_s": took, "share": least / took}

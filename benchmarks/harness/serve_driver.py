@@ -3,6 +3,13 @@
 paddle_tpu.inference.serve --generate PRESET --http PORT` serves — under
 load from a child process (loadgen.py) that never imports jax.
 
+What differs by architecture comes from the configuration's file: the
+reference (`reference`, loaded by cells.resolve), the sizes the traffic and
+the check draw from (`architecture.vocab_size`, the vocabulary HELD, and
+`architecture.max_seq_len`), the check's tolerance with its reason
+(`serve.check`), and the file that knows how to read the decode program's
+temporaries off this engine (`serve.program_memory`).
+
 Order: build, warm up (the engine's own inventory), the correctness check
 on four greedy requests, then the child offers `ramp_s` seconds of the
 cell's traffic before the window and `--seconds` of it inside. At the
@@ -21,18 +28,11 @@ import urllib.request
 
 import numpy as np
 
-from . import common, end_to_end, reference, stats, trace_reduce
+from . import cells, common, end_to_end, stats, trace_reduce
 from .traffic import Mix
 
 HARNESS_DIR = os.path.dirname(os.path.abspath(__file__))
 TRACE_S = 3.0          # profiled part of a traced window
-# The engine computes in float32 at "highest" precision, like the
-# reference; they differ by summation order (cache, padding buckets). The
-# emitted token's reference logit may lie under the reference's largest by
-# this share of the logits' standard deviation. The chip read 0 — the same
-# argmax at every one of 512 positions (PERF.md §6); bf16 weights or a
-# bf16 cache would read about 1e-2.
-LOGIT_TOL_STD = 2e-3
 
 
 def post_generate(url: str, payload: dict) -> dict:
@@ -43,22 +43,27 @@ def post_generate(url: str, payload: dict) -> dict:
         return json.loads(r.read())
 
 
-def greedy_check(url: str, params: dict, preset, mix: Mix,
+def greedy_check(url: str, params: dict, res: dict, mix: Mix,
                  seed: int) -> dict:
     """Four greedy requests of the cell's lengths, sent together; the
-    reference runs teacher-forced over prompt + answer, and at every
-    answered position the emitted token's reference logit must be within
-    tolerance of the reference's largest. Tokens are not compared as
-    such: with random weights the largest logit changes on rounding."""
+    configuration's reference runs teacher-forced over prompt + answer,
+    and at every answered position the emitted token's reference logit
+    must lie within `serve.check.logit_tol_std` standard deviations of
+    the reference's largest. Tokens are not compared as such: with random
+    weights the largest logit changes on rounding."""
     import jax.numpy as jnp
 
+    config = res["config"]
+    vocab = int(config["architecture"]["vocab_size"])
+    max_seq_len = int(config["architecture"]["max_seq_len"])
+    tol = float(config["serve"]["check"]["logit_tol_std"])
     picks = [0, mix.pool // 3, 2 * mix.pool // 3, mix.pool - 1]
     rng = np.random.default_rng(seed)
     reqs = []
     for a, b in zip(picks, reversed(picks)):
         n_prompt, n_out = mix.prompt_pool[a], mix.output_pool[b]
         reqs.append({"input_ids": [int(t) for t in rng.integers(
-            0, preset.vocab_size, n_prompt)], "max_new_tokens": n_out})
+            0, vocab, n_prompt)], "max_new_tokens": n_out})
     answers: list = [None] * len(reqs)
 
     def ask(i):
@@ -75,7 +80,7 @@ def greedy_check(url: str, params: dict, preset, mix: Mix,
 
     longest = max(len(r["input_ids"]) + len(a)
                   for r, a in zip(reqs, answers))
-    s_ref = min(-(-longest // 128) * 128, preset.max_seq_len)
+    s_ref = min(-(-longest // 128) * 128, max_seq_len)
     ids = np.zeros((len(reqs), s_ref), np.int32)
     nxt = np.zeros((len(reqs), s_ref), np.int32)
     answered = np.zeros((len(reqs), s_ref), bool)
@@ -86,8 +91,7 @@ def greedy_check(url: str, params: dict, preset, mix: Mix,
         # position p-1+j holds the logits that chose answer token j
         nxt[i, p - 1:p - 1 + len(a)] = a
         answered[i, p - 1:p - 1 + len(a)] = True
-    lg = reference.logits(params, ids, n_heads=preset.num_heads,
-                          eps=preset.layer_norm_eps)
+    lg = res["reference"].serve_logits(params, ids, config)
     top = np.asarray(lg.max(-1))
     got = np.asarray(jnp.take_along_axis(
         lg, jnp.asarray(nxt)[..., None], -1)[..., 0])
@@ -100,8 +104,8 @@ def greedy_check(url: str, params: dict, preset, mix: Mix,
         "prompt_lens": [len(r["input_ids"]) for r in reqs],
         "positions": int(answered.sum()),
         "argmax_agree": int(((top == got) & answered).sum()),
-        "max_gap_over_std": gap, "logit_std": std,
-        "ok": gap <= LOGIT_TOL_STD
+        "max_gap_over_std": gap, "logit_std": std, "tolerance": tol,
+        "ok": gap <= tol
         and [len(a) for a in answers] == [r["max_new_tokens"] for r in reqs],
     }
 
@@ -171,22 +175,15 @@ def build(res: dict, seed: int, t_proc0: float):
     return engine, srv, f"http://127.0.0.1:{srv.port}", build_s
 
 
-def program_temp_bytes(engine) -> int:
-    """Temporaries of the engine's largest decode program (all slots), by
-    the compile's memory_analysis: the allocator's peak leaves them out."""
-    import jax
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    cap, b = engine._caps[-1], engine._batch_buckets[-1]
-    params = {n: sds(v.shape, v.dtype) for n, v in engine._params.items()}
-    pool = sds(engine._pool_shape(cap), np.float32)
-    lowered = engine._program("decode", cap, b).lower(
-        params, pool, pool, sds((b,), np.int32), sds((b,), np.int32),
-        sds((b,), np.int32), sds((b,), np.float32), sds((b,), np.int32),
-        sds((b,), np.float32), sds((b, 2), np.uint32))
-    return int(lowered.compile().memory_analysis().temp_size_in_bytes)
+def program_temp_bytes(res: dict, engine) -> int:
+    """Temporaries of the engine's largest decode program, which the
+    allocator's peak leaves out: read by the file the configuration names
+    under `serve.program_memory`, since the program's signature and pools
+    are the architecture's."""
+    probe = cells.load_module(
+        res["root"], res["config"]["serve"]["program_memory"],
+        ("program_temp_bytes",))
+    return int(probe.program_temp_bytes(engine))
 
 
 def run(res: dict, seed: int, seconds: float, trace: bool,
@@ -194,22 +191,21 @@ def run(res: dict, seed: int, seconds: float, trace: bool,
     """t_proc0: time.monotonic() when the process started; set-up is
     counted from it."""
     from paddle_tpu.core import compile_cache as cc
-    from paddle_tpu.models import PRESETS
     from paddle_tpu.observability import trace as tracer
 
     traffic = res["traffic"]
-    preset = PRESETS[res["config"]["serve"]["preset"]]
+    vocab = int(res["config"]["architecture"]["vocab_size"])
     ramp = float(traffic.get("ramp_s", 0.0))
     engine, srv, url, build_s = build(res, seed, t_proc0)
     gen = None
     try:
         out_dir = os.path.join(res["root"], ".bench_tmp")
         os.makedirs(out_dir, exist_ok=True)
-        gen = LoadGen(url, res, seed, preset.vocab_size, ramp, seconds,
+        gen = LoadGen(url, res, seed, vocab, ramp, seconds,
                       os.path.join(out_dir, f"samples-{res['name']}.jsonl"))
-        check = greedy_check(url, engine._params, preset,
-                             Mix(traffic, seed, preset.vocab_size), seed)
-        temp_bytes = program_temp_bytes(engine)
+        check = greedy_check(url, engine._params, res,
+                             Mix(traffic, seed, vocab), seed)
+        temp_bytes = program_temp_bytes(res, engine)
         common.log(phase="setup", build_s=build_s,
                    warmup=engine.warmup_report, greedy_check=check,
                    program_temp_bytes=temp_bytes,
@@ -260,24 +256,30 @@ def run(res: dict, seed: int, seconds: float, trace: bool,
     e2e = {name: end_to_end.METRICS[name](samples, w0, w1)
            for name in (m["name"] for m in res["end_to_end"])
            if name in end_to_end.METRICS}
-    checks = {"greedy_matches_reference": check["ok"],
-              "every_request_whole": not bad,
-              "no_compile_in_window": in_window_lookups == 0,
-              "none_shed": snap1["shed_total"] == snap0["shed_total"]
-              and snap1["failed_total"] == snap0["failed_total"]}
+    # each number the verdict rests on, beside its limit
+    compared = {
+        "greedy_gap_over_std": (check["max_gap_over_std"],
+                                check["tolerance"]),
+        "greedy_answers_short": (sum(a != b for a, b in zip(
+            check["returned"], check["asked"])), 0),
+        "requests_not_whole": (len(bad), 0),
+        "compiles_in_window": (in_window_lookups, 0),
+        "shed_or_failed": (snap1["shed_total"] - snap0["shed_total"]
+                           + snap1["failed_total"] - snap0["failed_total"],
+                           0)}
     common.log(phase="window", child=child, requests=len(samples),
                ended=len(ended), cut=len(samples) - len(ended),
                bad=[{k: r[k] for k in ("i", "status", "error", "asked")}
                     for r in bad[:5]],
                generator_lateness_ms={"p50": stats.percentile(late, 50),
                                       "max": max(late, default=None)},
-               in_window_lookups=in_window_lookups, checks=checks,
+               in_window_lookups=in_window_lookups, compared=compared,
                engine_window={k: snap1[k] - snap0[k] for k in (
                    "steps_total", "step_rows_total",
                    "step_padded_rows_total", "prefills_total",
                    "tokens_out_total", "completed_total")})
     return {
-        "correct": all(checks.values()),
+        "correct": common.verdict(compared), "compared": compared,
         "attempted": len(ended) + check["requests"],
         "failed": len(bad) + (0 if check["ok"] else check["requests"]),
         "setup_s": setup_s, "window_s": seconds,

@@ -246,18 +246,20 @@ def idle_share(reduced: dict) -> float:
     return 1.0 - mean_busy_s(reduced) / reduced["window_s"]
 
 
-def breakdown(reduced: dict) -> dict:
+def breakdown(reduced: dict, gaps_by_span: list | None = None) -> dict:
     """The `breakdown` of the result line, from the lowest-numbered
     device: the ten operations with the most self time and the longest
-    idle gaps. What the host did in a gap cannot be said yet (the
-    program's spans are not on the profiler's clock): "unattributed",
-    with the operations on either side."""
+    idle gaps. A gap is named by what the host was doing in it where the
+    run has the program's spans on the profiler's clock (`gaps_by_span`,
+    host_spans.longest_gaps_by_span); a run without spans (the train
+    cells) says "unattributed", with the operations on either side."""
     dev = lowest_device(reduced)
     return {
         "device_ops": [[n, s] for n, s in list(dev["by_name"].items())[:10]],
-        "idle_gaps": [[f"unattributed (after {g['after_op']}, before "
-                       f"{g['before_op']})", g["seconds"]]
-                      for g in dev["idle_gaps"][:5]],
+        "idle_gaps": gaps_by_span or [
+            [f"unattributed (after {g['after_op']}, before "
+             f"{g['before_op']})", g["seconds"]]
+            for g in dev["idle_gaps"][:5]],
     }
 
 

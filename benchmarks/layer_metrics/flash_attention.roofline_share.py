@@ -1,10 +1,10 @@
 """The flash-attention kernels' share of their roofline on the lowest
 device: the least time the calls seen could take on this chip over the
 self time of the kernels' events. The kernels are found by the names the
-program gave them (`flash_fwd`, `flash_bwd_dq`, `flash_bwd_dkv`); a
-forward call — the remat'd one too — needs `flops.flash_attention_fwd`, a
-backward pass `flops.flash_attention_bwd` once, however many kernels run
-it; shapes are one shard's (batch over dp, heads over tp). The least time
+program gave them (`flash_fwd`, `flash_bwd_dkv`); a forward call — the
+remat'd one too — needs `flops.flash_attention_fwd`, a backward pass (one
+fused kernel) `flops.flash_attention_bwd`; shapes are one shard's (batch
+over dp, heads over tp). The least time
 is `flops.roofline_seconds` on the benchmark's own peaks. None without a
 trace and where no event carries a kernel's name. Moves
 train_tokens_per_s_per_chip."""

@@ -114,16 +114,27 @@ def _head(params, h, eps: float):
                        eps=eps)
 
 
-def logits(params, ids, n_heads: int, eps: float):
-    """[B, S, V] float32 logits of the whole sequences."""
+def _sizes(config: dict) -> tuple:
+    """(heads, LayerNorm epsilon) of a configuration's file."""
+    arch = config["architecture"]
+    return int(arch["num_heads"]), float(arch["layer_norm_eps"])
+
+
+def serve_logits(params, ids, config: dict):
+    """[B, S, V] float32 logits of the whole sequences. `params` is what
+    the engine serves (the stacked layout as it is), `config` the
+    configuration's file."""
+    n_heads, eps = _sizes(config)
     return _head(params, hidden(params, [ids], n_heads, eps)[0], eps)
 
 
-def loss(params, ids, labels, n_heads: int, eps: float,
-         rows: int = 4) -> float:
+def train_loss(params, ids, labels, config: dict, rows: int = 4) -> float:
     """Mean cross-entropy over every position of ids/labels [B, S],
     `rows` sequences at a time (the [rows, S, V] logits are the largest
-    array the reference holds)."""
+    array the reference holds). `params` is a TrainStep's, under its own
+    names."""
+    n_heads, eps = _sizes(config)
+    params = from_train_params(params)
     starts = range(0, len(ids), rows)
     hs = hidden(params, [ids[i:i + rows] for i in starts], n_heads, eps)
     total = 0.0

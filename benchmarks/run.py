@@ -4,10 +4,15 @@
 
 One process: require a TPU with the cell's number of chips (else exit
 non-zero with nothing on stdout), set up, warm every shape the window
-uses, check correctness against the plain reference, measure for
---seconds, print earlier lines freely and LAST one JSON object:
+uses, check correctness against the plain reference the configuration's
+file names, measure for --seconds, print earlier lines freely and LAST one
+JSON object:
 
-    {"correct", "attempted", "failed", "metrics", "device"[, "breakdown"]}
+    {"correct", "attempted", "failed", "metrics", "device"[, "breakdown"],
+     "compared"}
+
+`compared` holds each number the verdict rests on beside its limit; the
+same go to standard error as its last lines.
 
 With --trace 0 the metrics are the cell's end-to-end metrics; with
 --trace 1 its per-layer metrics, read by benchmarks/layer_metrics/<name>.py
@@ -31,7 +36,8 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 sys.path[:0] = [BENCH_DIR, ROOT]
 
-from harness import cells, common, peaks, trace_reduce  # noqa: E402
+from harness import (cells, common, host_spans, peaks,  # noqa: E402
+                     trace_reduce)
 
 
 def main(argv=None) -> int:
@@ -84,13 +90,19 @@ def main(argv=None) -> int:
             raise SystemExit("run.py: the trace holds no device operation")
         device.update(busy_s=trace_reduce.mean_busy_s(reduced),
                       window_s=reduced["window_s"])
-        line["breakdown"] = trace_reduce.breakdown(reduced)
+        line["breakdown"] = trace_reduce.breakdown(
+            reduced, host_spans.longest_gaps_by_span(run))
         with open(os.path.join(trace_dir, "reduced.json"), "w") as fh:
             json.dump(reduced, fh, indent=1)
+    compared = {k: {"value": float(v), "limit": float(limit)}
+                for k, (v, limit) in out["compared"].items()}
     print(json.dumps({"correct": bool(out["correct"]),
                       "attempted": int(out["attempted"]),
                       "failed": int(out["failed"]), **line,
-                      "device": device}), flush=True)
+                      "device": device, "compared": compared}), flush=True)
+    for k, c in compared.items():
+        print(f"compared {k}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -42,9 +42,7 @@ def main(argv=None) -> int:
 
     res = cells.resolve(args.workload, ROOT)
     common.require_chips(int(res["cell"]["chips"]))
-    from paddle_tpu.models import PRESETS
-
-    vocab = PRESETS[res["config"]["serve"]["preset"]].vocab_size
+    vocab = int(res["config"]["architecture"]["vocab_size"])
     engine, srv, url, _ = serve_driver.build(res, args.seed, T_PROC0)
     os.makedirs(os.path.join(ROOT, ".bench_tmp"), exist_ok=True)
     try:

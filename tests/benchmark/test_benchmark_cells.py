@@ -1,7 +1,9 @@
 """BENCHMARK.json against its contract, and the harness being driven by
-data: a configuration, a traffic mix, a cell and a per-layer metric added
-as NEW files (and new entries) are found without editing any file that is
-there. And the command's refusal to run without a TPU."""
+data: a configuration, its reference, a traffic mix, a cell and a
+per-layer metric added as NEW files (and new entries) are found without
+editing any file that is there; a configuration that names no reference,
+or one without the entry its sections need, is refused by the key's name.
+And the command's refusal to run without a TPU."""
 import json
 import os
 import re
@@ -116,6 +118,19 @@ def test_cell_resolves_to_its_files(cell):
     assert res["config"][res["traffic"]["kind"]]["preset"] == \
         res["cell"]["config"]
     assert res["config"]["reduced"] == res["config_entry"]["reduced"]
+    # the architecture is the configuration file's: its reference, the
+    # sizes the drivers draw from, each section's tolerance and its reason
+    assert res["reference"].__file__ == os.path.join(
+        REPO, "benchmarks", "references", "gpt.py")
+    arch = res["config"]["architecture"]
+    assert arch["vocab_size"] > 0 and arch["max_seq_len"] > 0
+    for section, limit in (("train", "loss_rtol"),
+                           ("serve", "logit_tol_std")):
+        if section in res["config"]:
+            check = res["config"][section]["check"]
+            assert 0 < check[limit] < 1 and len(check["why"]) > 40
+            assert callable(getattr(
+                res["reference"], cells.REFERENCE_ENTRY[section]))
     assert [m["name"] for m in res["end_to_end"]].count("setup_s") == 1
     with pytest.raises(SystemExit):
         cells.resolve("no-such.cell", REPO)
@@ -149,8 +164,15 @@ def test_new_files_are_found_without_editing_any(tmp_path):
                 before[os.path.join(d, f)] = fh.read()
 
     bdir = os.path.join(root, "benchmarks")
+    with open(os.path.join(bdir, "references", "gpt_again.py"), "w") as fh:
+        fh.write("def serve_logits(params, ids, config):\n    return 'mine'\n")
     with open(os.path.join(bdir, "configs", "gpt3-small.json"), "w") as fh:
-        json.dump({"reduced": [], "serve": {"preset": "gpt3-small"}}, fh)
+        json.dump({"reference": "benchmarks/references/gpt_again.py",
+                   "architecture": {"vocab_size": 6288, "max_seq_len": 1024},
+                   "reduced": ["vocab_size"],
+                   "serve": {"preset": "gpt3-small",
+                             "check": {"logit_tol_std": 1e-2, "why": "w"}}},
+                  fh)
     with open(os.path.join(bdir, "traffic", "serve-chat.json"), "w") as fh:
         json.dump({"kind": "serve", "loop": "open", "rate_per_s": 2.0,
                    "prompt_tokens": {"dist": "loguniform", "min": 32,
@@ -181,6 +203,9 @@ def test_new_files_are_found_without_editing_any(tmp_path):
     res = cells.resolve("gpt3-small.serve-chat", root)
     assert res["traffic"]["rate_per_s"] == 2.0
     assert res["config"]["serve"]["preset"] == "gpt3-small"
+    # its own reference, and only the entry a served configuration needs
+    assert res["reference"].serve_logits(None, None, None) == "mine"
+    assert not hasattr(res["reference"], "train_loss")
     assert {m["name"] for m in res["end_to_end"]} == {
         "itl_ms_p95", "setup_s"}
     run = {"setup": {"build_s": 2.5, "cache_misses": 0}, "spans": [],
@@ -193,10 +218,55 @@ def test_new_files_are_found_without_editing_any(tmp_path):
     # the generator reads the new mix as it is
     from harness.traffic import Mix
 
-    assert 32 <= Mix(res["traffic"], 1, 50304).lengths(0)[0] <= 512
+    mix = Mix(res["traffic"], 1, res["config"]["architecture"]["vocab_size"])
+    assert 32 <= mix.lengths(0)[0] <= 512
+    assert max(mix.payload(0)["input_ids"]) < 6288
     for path, data in before.items():
         with open(path, "rb") as fh:
             assert fh.read() == data, path
+
+
+@pytest.mark.parametrize("config, reference_source, named", [
+    ({"serve": {}}, None, '"reference"'),
+    ({"reference": "", "serve": {}}, None, '"reference"'),
+    ({"reference": "benchmarks/references/gone.py", "serve": {}}, None,
+     "benchmarks/references/gone.py"),
+    ({"reference": "benchmarks/references/half.py", "serve": {}},
+     "def train_loss(*a):\n    return 0.0\n", "serve_logits"),
+    ({"reference": "benchmarks/references/half.py", "train": {}},
+     "def serve_logits(*a):\n    return 0.0\n", "train_loss"),
+    ({"reference": "benchmarks/references/half.py", "train": {},
+      "serve": {}}, "serve_logits = 3\n", "serve_logits"),
+], ids=["no-key", "empty-key", "no-file", "no-serve_logits",
+        "no-train_loss", "not-a-function"])
+def test_a_configuration_without_its_reference_is_refused_by_name(
+        tmp_path, config, reference_source, named):
+    """No reference is an error, never a default: the message names the
+    key, the file or the entry that is missing."""
+    root = str(tmp_path)
+    if reference_source is not None:
+        os.makedirs(os.path.join(root, "benchmarks", "references"))
+        with open(os.path.join(root, config["reference"]), "w") as fh:
+            fh.write(reference_source)
+    with pytest.raises(SystemExit) as refused:
+        cells.load_reference(root, config, "benchmarks/configs/new.json")
+    assert named in str(refused.value)
+
+
+def test_a_cell_whose_configuration_names_no_reference_does_not_resolve(
+        tmp_path):
+    root = str(tmp_path)
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    shutil.copytree(os.path.join(REPO, "benchmarks"),
+                    os.path.join(root, "benchmarks"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    path = os.path.join(root, "benchmarks", "configs", "gpt3-medium.json")
+    config = cells.load_json(path)
+    del config["reference"]
+    with open(path, "w") as fh:
+        json.dump(config, fh)
+    with pytest.raises(SystemExit, match='gpt3-medium.json: no "reference"'):
+        cells.resolve("gpt3-medium.train", root)
 
 
 def _run_cell(cwd, env_extra=None):

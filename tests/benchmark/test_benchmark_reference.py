@@ -1,4 +1,5 @@
-"""The plain reference against a two-layer case worked out by hand (loops
+"""The GPT reference (benchmarks/references/gpt.py, loaded by path as the
+harness loads it) against a two-layer case worked out by hand (loops
 over positions and heads in numpy, float64), against the system's own
 model at a tiny size, and the operations-and-bytes functions."""
 import math
@@ -13,10 +14,15 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path[:0] = [os.path.join(REPO, "benchmarks"), REPO]
 
-from harness import flops, peaks, reference  # noqa: E402
+from harness import cells, flops, peaks  # noqa: E402
+
+reference = cells.load_module(REPO, "benchmarks/references/gpt.py",
+                              ("serve_logits", "train_loss"))
 
 L, D, H, V, S, B = 2, 8, 2, 11, 5, 2
 EPS = 1e-5
+# what the two entries read of a configuration's file
+CONFIG = {"architecture": {"num_heads": H, "layer_norm_eps": EPS}}
 
 
 @pytest.fixture(scope="module")
@@ -77,7 +83,7 @@ def by_hand(p, ids):
 
 def test_logits_match_the_hand_computed_two_layer_case(case):
     params, ids, _ = case
-    got = np.asarray(reference.logits(params, ids, n_heads=H, eps=EPS))
+    got = np.asarray(reference.serve_logits(params, ids, CONFIG))
     np.testing.assert_allclose(got, by_hand(params, ids), rtol=2e-4,
                                atol=2e-4)
 
@@ -89,16 +95,15 @@ def test_loss_is_mean_token_cross_entropy(case):
     want = -np.mean([logp[b, t, labels[b, t]]
                      for b in range(B) for t in range(S)])
     for rows in (1, 2):
-        got = reference.loss(params, ids, labels, n_heads=H, eps=EPS,
-                             rows=rows)
+        got = reference.train_loss(params, ids, labels, CONFIG, rows=rows)
         assert got == pytest.approx(want, rel=1e-5)
 
 
 def test_causal_padding_after_a_sequence_changes_nothing_before_it(case):
     """serve_driver pads prompt + answer to a fixed length."""
     params, ids, _ = case
-    short = np.asarray(reference.logits(params, ids[:, :3], H, EPS))
-    full = np.asarray(reference.logits(params, ids, H, EPS))
+    short = np.asarray(reference.serve_logits(params, ids[:, :3], CONFIG))
+    full = np.asarray(reference.serve_logits(params, ids, CONFIG))
     np.testing.assert_allclose(short, full[:, :3], rtol=1e-5, atol=1e-5)
 
 
@@ -120,8 +125,10 @@ def test_system_train_loss_matches_reference_at_tiny_size():
 
     cfg = PRESETS["gpt3-tiny"]
     step, ids, labels, _ = bench.build_train_step("gpt3-tiny", 4, 64)
-    want = reference.loss(reference.from_train_params(step.state()[0]),
-                          ids, labels, cfg.num_heads, cfg.layer_norm_eps)
+    want = reference.train_loss(
+        step.state()[0], ids, labels,
+        {"architecture": {"num_heads": cfg.num_heads,
+                          "layer_norm_eps": cfg.layer_norm_eps}})
     got = float(step(ids, labels).numpy())
     assert abs(got - want) / want < 2e-3
 
@@ -134,6 +141,22 @@ def test_mfu_arithmetic():
                                                                 abs=1e-4)
     with pytest.raises(LookupError):
         peaks.device_peaks("TPU v9")
+
+
+def test_decode_step_mfu_arithmetic():
+    """2·N for each real row of a step over the step's time and the peak;
+    a sparse model's N is the parameters a token passes through."""
+    peak = peaks.device_peaks("TPU v5 lite")["bf16_flops"]
+    arch = {"n_params": 354871296}
+    assert flops.serve_model_flops_per_token(arch) == 2 * 354871296
+    # the issue's expectation for serve-decode: 8 rows in 80 ms
+    assert flops.decode_step_mfu(8.0, 0.080, arch, peak) == pytest.approx(
+        8 * 2 * 354871296 / 0.080 / 197e12)
+    assert 3.5e-4 < flops.decode_step_mfu(8.0, 0.080, arch, peak) < 3.7e-4
+    sparse = {"n_params": 7_000_000_000, "n_params_active": 1_000_000_000}
+    assert flops.serve_model_flops_per_token(sparse) == 2e9
+    with pytest.raises(KeyError, match="n_params"):
+        flops.serve_model_flops_per_token({"hidden_size": 8})
 
 
 def test_flash_attention_work():
