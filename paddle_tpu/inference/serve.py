@@ -173,18 +173,22 @@ def build_generator(preset: str, state_dict: str | None = None,
     import paddle_tpu as paddle
     from ..models.gpt import PRESETS, GPTForCausalLM
     from .serving import GenerativeEngine
+    from .serving.generate import stack_gpt_params
 
-    def preset_model(name):
+    def stacked(name, state=None):
+        """(params, cfg) as the engine's programs scan them. The model
+        they are copied out of dies here, before the engine warms up: its
+        weights are not held a second time beside the pools."""
         paddle.seed(0)
         model = GPTForCausalLM(PRESETS[name])
         model.eval()
-        return model
+        if state:
+            model.set_state_dict(paddle.load(state))
+        return stack_gpt_params(model)
 
-    model = preset_model(preset)
-    if state_dict:
-        model.set_state_dict(paddle.load(state_dict))
     return GenerativeEngine(
-        model, draft=None if draft is None else preset_model(draft),
+        params=stacked(preset, state_dict),
+        draft_params=None if draft is None else stacked(draft),
         **engine_kw)
 
 

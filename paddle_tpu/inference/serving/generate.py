@@ -24,14 +24,32 @@ pool, re-cut for the XLA compilation contract):
 
 - **Bucketed KV-cache pool.** Each worker owns a preallocated KV pool:
   per capacity class (pow2 slot sizes, default one class at
-  max_context) a pair of [n_slots+1, L, cap, H, Dh] buffers whose rows
+  max_context) a pair of [n_slots+1, L, cap, H*Dh] buffers (heads
+  folded into the minor dimension: dense lanes on a TPU) whose rows
   are SLOTS handed out from a free list and reused across requests
-  (the +1 row is scratch for decode-batch padding). Prefill scatters
-  the prompt's KV into its slot in-program; each decode step scatters
-  exactly one new position per row. The pool buffers are threaded
-  functionally through the programs (donate-able on accelerators;
-  donation stays off on CPU where the persistent cache must hold the
-  programs — core/compile_cache.donated_cpu_guard).
+  (the +1 row is scratch for decode-batch padding). Prefill stores
+  the prompt's KV into its slot in-program. The decode pass (and the
+  verify and extend passes) works on the pool WHERE IT LIES: the scan
+  over layers carries the two pools; each layer writes its new
+  position(s) into the pool (`kv.write_layer`: buf[slot, layer, pos],
+  a position past the cap into the scratch row) and then attends over
+  pool rows addressed by slot through ONE entry, `pool_attention` —
+  write first, read after, so a row sees its own token and an int8
+  pool is pool-consistent by construction. Nothing gathers, transposes
+  or re-materializes pool rows: with the pools donated the compiled
+  program aliases its output pools to its inputs and holds no
+  temporary of a pool's size. `pool_attention` has two reads, chosen
+  from what the code can observe. One query a row over a float pool,
+  lowered for a TPU (`lax.platform_dependent`): the Pallas kernel of
+  ops/pallas/decode_attention.py, whose `BlockSpec` index map picks
+  block (slots[i], layer, j) of the pool in HBM and which reads only
+  the blocks up to each row's position. Every other lowering, an int8
+  pool, and several queries a row (verify, extend): `buf[slots, layer]`
+  — one layer of the rows, dequantized where the pool is int8 — and a
+  masked softmax over the class capacity. The pool buffers are
+  threaded functionally through the programs (donate-able on
+  accelerators; donation stays off on CPU where the persistent cache
+  must hold the programs — core/compile_cache.donated_cpu_guard).
 
 - **In-flight batching.** The decode step runs the ACTIVE rows padded
   to their pow2 batch bucket; between steps the scheduler admits new
@@ -99,11 +117,11 @@ inventory:
 
 - **int8 KV pool** (``kv_dtype="int8"``). The pool buffers become
   ``kv.QuantizedKV`` pytrees — int8 data + per-(row, layer) float32
-  absmax scales — and the program bodies fuse quantize-on-scatter /
-  dequantize-on-gather through the kv helpers (prefill resets a row's
+  absmax scales — and the program bodies fuse quantize-on-write /
+  dequantize-on-read through the kv helpers (prefill resets a row's
   scale from its block absmax; decode/verify/extend quantize new
-  positions with the row's existing scale, clip semantics). In-scan
-  writes fake-quant with the same row scale, so a verify pass reads
+  positions with the row's existing scale, clip semantics). Every
+  body writes into the pool before it reads, so a verify pass reads
   bitwise what plain decode would read back — spec-on stays bitwise-
   equal to spec-off under int8. Prefix-cache rows copy as raw int8 +
   scale (bit-exact hits), so cache capacity doubles with the pool.
@@ -132,6 +150,7 @@ import numpy as np
 from ...core import compile_cache as _cc
 from ...core.flags import flag
 from ...io.bucketing import bucket_boundaries_pow2, bucket_for
+from ...ops.pallas import decode_attention as _dattn
 from ...quantization import kv as _kvq
 from ...observability import trace as _tr
 from ...testing import chaos as _chaos
@@ -272,77 +291,147 @@ def _prefill_body(p, buf_k, buf_v, slot, ids, length, temp, topk, topp,
     return tok, key, buf_k, buf_v
 
 
+def _kernel_read(q, buf_k, buf_v, layer, slots, pos, interpret=False):
+    """pool_attention's read on a TPU: the Pallas kernel over the pool in
+    HBM, blocks up to each row's position (ops/pallas/decode_attention).
+    `interpret` is for the tests' CPU runs of the kernel."""
+    b, _, H, Dh = q.shape
+    return _dattn.decode_attention(
+        q.reshape(b, H * Dh), buf_k, buf_v, layer, slots, pos[:, 0],
+        num_heads=H, interpret=interpret)[:, None]
+
+
+def _gather_read(q, buf_k, buf_v, layer, slots, pos):
+    """pool_attention's read in plain XLA: one layer of the rows
+    (`buf[slots, layer]`, dequantized where the pool is int8) and a
+    masked softmax over all M positions."""
+    import jax
+    import jax.numpy as jnp
+
+    b, Q, H, Dh = q.shape
+    M = _kvq.capacity(buf_k)
+    k_l = _kvq.read_layer(buf_k, slots, layer).reshape(b, M, H, Dh)
+    v_l = _kvq.read_layer(buf_v, slots, layer).reshape(b, M, H, Dh)
+    kpos = jnp.arange(M, dtype=jnp.int32)
+    mask = kpos[None, None, :] <= pos[:, :, None]      # [b, Q, M]
+    s = jnp.einsum("bqhd,bmhd->bhqm", q, k_l) / math.sqrt(Dh)
+    s = jnp.where(mask[:, None], s, _NEG_INF)
+    att = jnp.einsum("bhqm,bmhd->bqhd", jax.nn.softmax(s, -1), v_l)
+    return att.reshape(b, Q, H * Dh)
+
+
+def _on_tpu(kernel, twin, *args):
+    """`kernel` where the program is lowered for a TPU, `twin` elsewhere
+    — decided at lowering, so a compile for a described chip takes the
+    kernel although the host's default backend is a CPU."""
+    import jax
+
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=twin)
+
+
+def _lowers_for_tpu(device) -> bool:
+    """Whether a program placed on `device` is lowered for a TPU — what
+    `_on_tpu` decides inside the trace, asked host-side for the step's
+    counters and the program report."""
+    return device.platform == "tpu"
+
+
+# program families whose bodies attend over pool rows
+_POOL_READERS = ("decode", "dpropose", "verify", "extend")
+
+
+def kernel_plan(cap: int, num_heads: int, head_dim: int, kv_dtype: str):
+    """The decode kernel's plan for a pool of this geometry, or None
+    where one-query reads take the gather too: a pool that is not float,
+    a shape the kernel does not serve."""
+    if kv_dtype != "f32":
+        return None
+    return _dattn.block_plan(cap, num_heads, head_dim, 4)
+
+
+def pool_attention(q, buf_k, buf_v, layer, slots, pos):
+    """Attention of the queries q [b, Q, H, Dh] over the K/V pool where
+    it lies: query (i, j) attends positions [0, pos[i, j]] of pool row
+    slots[i] at `layer` (the new positions are in the pool already —
+    write first, read after). Returns [b, Q, H*Dh]. One entry, two reads,
+    chosen from what the code can observe: one query a row over a float
+    pool takes the kernel on a TPU; every other lowering, an int8 pool
+    and several queries a row take the gather."""
+    _, Q, H, Dh = q.shape
+    kv_dtype = "int8" if _kvq.is_quantized(buf_k) else "f32"
+    if Q != 1 or kernel_plan(_kvq.capacity(buf_k), H, Dh,
+                             kv_dtype) is None:
+        return _gather_read(q, buf_k, buf_v, layer, slots, pos)
+    return _on_tpu(_kernel_read, _gather_read, q, buf_k, buf_v, layer,
+                   slots, pos)
+
+
+def _pool_writes(pos, slots, cap, scratch):
+    """Where new K/V at the absolute positions `pos` (slots broadcast
+    against it) land in the pool: a position past the class cap is
+    redirected into the scratch row, never into a live slot."""
+    import jax.numpy as jnp
+
+    safe = pos < cap
+    return (jnp.where(safe, slots, jnp.int32(scratch)),
+            jnp.where(safe, pos, 0))
+
+
+def _layers(p):
+    """The scan's per-layer inputs: the stacked params and the layer's
+    index into the pool."""
+    import jax.numpy as jnp
+
+    return _layer_stack(p) + (jnp.arange(p["ln1_w"].shape[0],
+                                         dtype=jnp.int32),)
+
+
 def _decode_core(p, buf_k, buf_v, slots, tokens, lengths, scratch,
                  num_heads, eps):
     """The shared fixed-shape decode pass for `b` rows of the pool:
-    embed each row's pending token at its position, attend over the
-    row's cached prefix (+ the token itself), scatter exactly one new
-    K/V per row back into the pool (a position past the class cap —
-    possible only inside a fused draft burst — lands in the scratch
-    row), return the logits. Rows are independent — padding rows
-    target the scratch slot with length 0 and their outputs are
-    discarded by the caller."""
+    embed each row's pending token at its position; per layer write the
+    row's new K/V into the pool in place (a position past the class cap
+    — possible only inside a fused draft burst — lands in the scratch
+    row) and attend over the row's cached prefix + the token itself,
+    read from the pool by slot; return the logits. The scan over layers
+    carries the two pools: nothing gathers, transposes or re-materializes
+    pool rows. Rows are independent — padding rows target the scratch
+    slot with length 0 and their outputs are discarded by the caller."""
     import jax
     import jax.numpy as jnp
 
     p = _kvq.dequant_params(p)
     b = tokens.shape[0]
-    M = buf_k.shape[2] if not _kvq.is_quantized(buf_k) \
-        else buf_k.data.shape[2]
     D = p["wte"].shape[1]
     H = int(num_heads)
     Dh = D // H
     x = p["wte"][tokens] + p["wpe"][jnp.minimum(
         lengths, p["wpe"].shape[0] - 1)]               # [b, D]
-    k_rows, k_scl = _kvq.gather_rows(buf_k, slots)     # [b, L, M, H, Dh]
-    v_rows, v_scl = _kvq.gather_rows(buf_v, slots)
-    k_rows = jnp.swapaxes(k_rows, 0, 1)                # [L, b, M, H, Dh]
-    v_rows = jnp.swapaxes(v_rows, 0, 1)
-    kpos = jnp.arange(M, dtype=jnp.int32)
-    mask = kpos[None, :] <= lengths[:, None]           # [b, M]
-    rowix = jnp.arange(b)
-    xs = _layer_stack(p) + (k_rows, v_rows)
-    if k_scl is not None:
-        # per-layer scale rows ride the scan so in-scan writes fake-
-        # quant new positions with the SAME row scale the final scatter
-        # quantizes with — every attended read is pool-consistent
-        xs = xs + (jnp.swapaxes(k_scl, 0, 1), jnp.swapaxes(v_scl, 0, 1))
+    wslot, wpos = _pool_writes(lengths, slots, _kvq.capacity(buf_k),
+                               scratch)
+    pos = lengths[:, None]                             # [b, 1]
 
-    def body(h, lp):
-        if k_scl is None:
-            (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-             k_l, v_l) = lp
-            sk = sv = None
-        else:
-            (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-             k_l, v_l, sk, sv) = lp
+    def body(carry, lp):
+        h, buf_k, buf_v = carry
+        (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
+         layer) = lp
         y = _ln(h, l1w, l1b, eps)
         qkv = (y @ qw + qb).reshape(b, 3, H, Dh)
         q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-        k_l = k_l.at[rowix, lengths].set(
-            _kvq.fake_quant(k_new, sk).astype(k_l.dtype), mode="drop")
-        v_l = v_l.at[rowix, lengths].set(
-            _kvq.fake_quant(v_new, sv).astype(v_l.dtype), mode="drop")
-        s = jnp.einsum("bhd,bmhd->bhm", q, k_l) / math.sqrt(Dh)
-        s = jnp.where(mask[:, None, :], s, _NEG_INF)
-        att = jnp.einsum("bhm,bmhd->bhd", jax.nn.softmax(s, -1), v_l)
-        h = h + att.reshape(b, D) @ ow + ob
+        buf_k = _kvq.write_layer(buf_k, layer, wslot, wpos,
+                                 k_new.reshape(b, D))
+        buf_v = _kvq.write_layer(buf_v, layer, wslot, wpos,
+                                 v_new.reshape(b, D))
+        att = pool_attention(q[:, None], buf_k, buf_v, layer, slots, pos)
+        h = h + att[:, 0] @ ow + ob
         y = _ln(h, l2w, l2b, eps)
         h = h + jax.nn.gelu(y @ f1w + f1b,
                             approximate=True) @ f2w + f2b
-        return h, (k_new, v_new)                       # [b, H, Dh]
+        return (h, buf_k, buf_v), None
 
-    h, (k_news, v_news) = jax.lax.scan(body, x, xs)
+    (h, buf_k, buf_v), _ = jax.lax.scan(body, (x, buf_k, buf_v),
+                                        _layers(p))
     h = _ln(h, p["lnf_w"], p["lnf_b"], eps)
-    # scatter ONLY the new position back (the gathered copies die here);
-    # an out-of-cap position is redirected into the scratch row
-    safe = lengths < M
-    wslot = jnp.where(safe, slots, jnp.int32(scratch))
-    wpos = jnp.where(safe, lengths, 0)
-    k_t = jnp.swapaxes(k_news, 0, 1)                   # [b, L, H, Dh]
-    v_t = jnp.swapaxes(v_news, 0, 1)
-    buf_k = _kvq.scatter_rows(buf_k, wslot, wpos, k_t)
-    buf_v = _kvq.scatter_rows(buf_v, wslot, wpos, v_t)
     return _logits_head(p, h), buf_k, buf_v
 
 
@@ -398,54 +487,39 @@ def _verify_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
 
     p = _kvq.dequant_params(p)
     b, kk = tokens.shape
-    M = buf_k.shape[2] if not _kvq.is_quantized(buf_k) \
-        else buf_k.data.shape[2]
     D = p["wte"].shape[1]
     H = int(num_heads)
     Dh = D // H
     pos = lengths[:, None] + jnp.arange(kk, dtype=jnp.int32)[None, :]
     x = p["wte"][tokens] + p["wpe"][jnp.minimum(
         pos, p["wpe"].shape[0] - 1)]                   # [b, k, D]
-    k_rows, k_scl = _kvq.gather_rows(buf_k, slots)     # [b, L, M, H, Dh]
-    v_rows, v_scl = _kvq.gather_rows(buf_v, slots)
-    k_rows = jnp.swapaxes(k_rows, 0, 1)                # [L, b, M, H, Dh]
-    v_rows = jnp.swapaxes(v_rows, 0, 1)
-    kpos = jnp.arange(M, dtype=jnp.int32)
-    mask = kpos[None, None, :] <= pos[:, :, None]      # [b, k, M]
-    rowix = jnp.arange(b)[:, None]
-    xs = _layer_stack(p) + (k_rows, v_rows)
-    if k_scl is not None:
-        xs = xs + (jnp.swapaxes(k_scl, 0, 1), jnp.swapaxes(v_scl, 0, 1))
+    wslot, wpos = _pool_writes(pos, slots[:, None],
+                               _kvq.capacity(buf_k), scratch)
 
-    def body(h, lp):
-        if k_scl is None:
-            (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-             k_l, v_l) = lp
-            sk = sv = None
-        else:
-            (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-             k_l, v_l, sk, sv) = lp
+    def body(carry, lp):
+        h, buf_k, buf_v = carry
+        (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
+         layer) = lp
         y = _ln(h, l1w, l1b, eps)
         qkv = (y @ qw + qb).reshape(b, kk, 3, H, Dh)
         q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        # in-bounds block positions land in the gathered copy (so the
-        # intra-block causal mask sees them); overflow writes drop.
-        # fake-quant keeps them bitwise what plain decode's next-step
-        # gather would read — spec-on == spec-off under the int8 pool
-        k_l = k_l.at[rowix, pos].set(
-            _kvq.fake_quant(k_new, sk).astype(k_l.dtype), mode="drop")
-        v_l = v_l.at[rowix, pos].set(
-            _kvq.fake_quant(v_new, sv).astype(v_l.dtype), mode="drop")
-        s = jnp.einsum("bqhd,bmhd->bhqm", q, k_l) / math.sqrt(Dh)
-        s = jnp.where(mask[:, None], s, _NEG_INF)
-        att = jnp.einsum("bhqm,bmhd->bqhd", jax.nn.softmax(s, -1), v_l)
-        h = h + att.reshape(b, kk, D) @ ow + ob
+        # the block's positions go into the pool first (so the intra-
+        # block causal mask sees them, bitwise as plain decode's next
+        # step would read them back — spec-on == spec-off under the int8
+        # pool too); overflow lands in the scratch row
+        buf_k = _kvq.write_layer(buf_k, layer, wslot, wpos,
+                                 k_new.reshape(b, kk, D))
+        buf_v = _kvq.write_layer(buf_v, layer, wslot, wpos,
+                                 v_new.reshape(b, kk, D))
+        h = h + pool_attention(q, buf_k, buf_v, layer, slots,
+                               pos) @ ow + ob
         y = _ln(h, l2w, l2b, eps)
         h = h + jax.nn.gelu(y @ f1w + f1b,
                             approximate=True) @ f2w + f2b
-        return h, (k_new, v_new)                       # [b, k, H, Dh]
+        return (h, buf_k, buf_v), None
 
-    h, (k_news, v_news) = jax.lax.scan(body, x, xs)
+    (h, buf_k, buf_v), _ = jax.lax.scan(body, (x, buf_k, buf_v),
+                                        _layers(p))
     h = _ln(h, p["lnf_w"], p["lnf_b"], eps)
     logits = _logits_head(p, h)                        # [b, k, V]
     outs, hist = [], []
@@ -457,13 +531,6 @@ def _verify_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
         hist.append(cur)
     ys = jnp.stack(outs, axis=1)                       # [b, k]
     khist = jnp.stack(hist, axis=1)                    # [b, k, 2]
-    safe = pos < M
-    wslot = jnp.where(safe, slots[:, None], jnp.int32(scratch))
-    wpos = jnp.where(safe, pos, 0)
-    k_t = jnp.moveaxis(k_news, 0, 2)                   # [b, k, L, H, Dh]
-    v_t = jnp.moveaxis(v_news, 0, 2)
-    buf_k = _kvq.scatter_rows(buf_k, wslot, wpos, k_t)
-    buf_v = _kvq.scatter_rows(buf_v, wslot, wpos, v_t)
     return ys, khist, buf_k, buf_v
 
 
@@ -483,63 +550,40 @@ def _extend_body(p, buf_k, buf_v, slot, ids, start, length, temp, topk,
 
     p = _kvq.dequant_params(p)
     T = ids.shape[1]
-    M = buf_k.shape[2] if not _kvq.is_quantized(buf_k) \
-        else buf_k.data.shape[2]
     D = p["wte"].shape[1]
     H = int(num_heads)
     Dh = D // H
     pos = start + jnp.arange(T, dtype=jnp.int32)       # absolute
     x = p["wte"][ids] + p["wpe"][jnp.minimum(
         pos, p["wpe"].shape[0] - 1)][None]             # [1, T, D]
-    kpos = jnp.arange(M, dtype=jnp.int32)
-    mask = kpos[None, :] <= pos[:, None]               # [T, M]
     slot = slot.astype(jnp.int32)
-    row_k, k_scl = _kvq.gather_rows(buf_k, slot)       # [L, M, H, Dh]
-    row_v, v_scl = _kvq.gather_rows(buf_v, slot)
-    xs = _layer_stack(p) + (row_k, row_v)
-    if k_scl is not None:
-        xs = xs + (k_scl, v_scl)                       # per-layer [L]
+    wslot, wpos = _pool_writes(pos, slot, _kvq.capacity(buf_k), scratch)
 
-    def body(h, lp):
-        if k_scl is None:
-            (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-             k_l, v_l) = lp
-            sk = sv = None
-        else:
-            (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-             k_l, v_l, sk, sv) = lp
+    def body(carry, lp):
+        h, buf_k, buf_v = carry
+        (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
+         layer) = lp
         y = _ln(h, l1w, l1b, eps)
         qkv = (y @ qw + qb).reshape(1, T, 3, H, Dh)
         q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        k_l = k_l.at[pos].set(
-            _kvq.fake_quant(k_new[0], sk).astype(k_l.dtype),
-            mode="drop")
-        v_l = v_l.at[pos].set(
-            _kvq.fake_quant(v_new[0], sv).astype(v_l.dtype),
-            mode="drop")
-        qh = jnp.swapaxes(q, 1, 2)                     # [1, H, T, Dh]
-        s = jnp.einsum("bhqd,mhd->bhqm", qh, k_l) / math.sqrt(Dh)
-        s = jnp.where(mask[None, None], s, _NEG_INF)
-        att = jnp.einsum("bhqm,mhd->bhqd", jax.nn.softmax(s, -1), v_l)
-        h = h + jnp.swapaxes(att, 1, 2).reshape(1, T, D) @ ow + ob
+        buf_k = _kvq.write_layer(buf_k, layer, wslot, wpos,
+                                 k_new.reshape(T, D))
+        buf_v = _kvq.write_layer(buf_v, layer, wslot, wpos,
+                                 v_new.reshape(T, D))
+        h = h + pool_attention(q, buf_k, buf_v, layer, slot[None],
+                               pos[None]) @ ow + ob
         y = _ln(h, l2w, l2b, eps)
         h = h + jax.nn.gelu(y @ f1w + f1b,
                             approximate=True) @ f2w + f2b
-        return h, (k_new[0], v_new[0])                 # [T, H, Dh]
+        return (h, buf_k, buf_v), None
 
-    h, (ks, vs) = jax.lax.scan(body, x, xs)
+    (h, buf_k, buf_v), _ = jax.lax.scan(body, (x, buf_k, buf_v),
+                                        _layers(p))
     h = _ln(h, p["lnf_w"], p["lnf_b"], eps)
     h_last = jax.lax.dynamic_index_in_dim(h[0], length - 1 - start,
                                           axis=0, keepdims=False)
     key, sub = jax.random.split(key)
     tok = _sample_token(_logits_head(p, h_last), temp, topk, topp, sub)
-    safe = pos < M
-    wslot = jnp.where(safe, slot, jnp.int32(scratch))  # [T]
-    wpos = jnp.where(safe, pos, 0)
-    k_t = jnp.swapaxes(ks, 0, 1)                       # [T, L, H, Dh]
-    v_t = jnp.swapaxes(vs, 0, 1)
-    buf_k = _kvq.scatter_rows(buf_k, wslot, wpos, k_t)
-    buf_v = _kvq.scatter_rows(buf_v, wslot, wpos, v_t)
     return tok, key, buf_k, buf_v
 
 
@@ -778,9 +822,12 @@ def aggregate_snapshot() -> Optional[dict]:
                 # a maximum merges as a maximum — summing would report
                 # an occupancy no single engine ever reached
                 out[k] = max(out[k], v)
-            elif not (k.startswith(("ttft_", "latency_", "kv_", "avg_"))
-                      or k.endswith("_rate")):
+            elif k.startswith("kv_positions_") or not (
+                    k.startswith(("ttft_", "latency_", "kv_", "avg_"))
+                    or k.endswith("_rate")):
                 out[k] = out[k] + v
+    out["kv_read_share"] = _sm.rate(out["kv_positions_read_total"],
+                                    out["kv_positions_capacity_total"])
     out["engines"] = len(snaps)
     return out
 
@@ -799,7 +846,8 @@ _REGISTRY = _sm.EngineRegistry("generative", aggregate_snapshot)
                "prefix_misses_total", "prefix_evictions_total",
                "prefix_tokens_reused_total", "handoffs_out_total",
                "handoffs_in_total", "migrations_out_total",
-               "handoff_bytes_total")
+               "handoff_bytes_total", "kv_positions_read_total",
+               "kv_positions_capacity_total")
 class GenerativeMetrics:
     """Thread-safe metric store for one GenerativeEngine: the four
     numbers a generation tier is judged by — tokens/s, TTFT, decode
@@ -822,6 +870,8 @@ class GenerativeMetrics:
         self.steps_total = 0
         self.step_rows_total = 0          # real rows over all steps
         self.step_padded_rows_total = 0   # pad rows added by batch bucket
+        self.kv_positions_read_total = 0      # positions the steps read
+        self.kv_positions_capacity_total = 0  # real rows x class cap
         self.draft_steps_total = 0        # fused k-step draft bursts
         self.spec_steps_total = 0         # target verify passes
         self.spec_proposed_total = 0      # draft tokens offered (k-1/row)
@@ -872,11 +922,17 @@ class GenerativeMetrics:
             self.prefills_total += 1
             self.prompt_tokens_total += prompt_tokens
 
-    def on_step(self, rows: int, bucket: int):
+    def on_step(self, rows: int, bucket: int, kv_read: int = 0,
+                kv_capacity: int = 0):
+        """One decode step of `rows` real rows in a batch bucket;
+        `kv_read` of the rows' `kv_capacity` (rows x class cap) pool
+        positions were read by the step's attention."""
         with self._lock:
             self.steps_total += 1
             self.step_rows_total += rows
             self.step_padded_rows_total += max(bucket - rows, 0)
+            self.kv_positions_read_total += int(kv_read)
+            self.kv_positions_capacity_total += int(kv_capacity)
             self.occupancy_hist[rows] = \
                 self.occupancy_hist.get(rows, 0) + 1
 
@@ -983,6 +1039,12 @@ class GenerativeMetrics:
                 "steps_total": self.steps_total,
                 "step_rows_total": self.step_rows_total,
                 "step_padded_rows_total": self.step_padded_rows_total,
+                "kv_positions_read_total": self.kv_positions_read_total,
+                "kv_positions_capacity_total":
+                    self.kv_positions_capacity_total,
+                "kv_read_share": _sm.rate(
+                    self.kv_positions_read_total,
+                    self.kv_positions_capacity_total),
                 "draft_steps_total": self.draft_steps_total,
                 "spec_steps_total": self.spec_steps_total,
                 "spec_proposed_total": self.spec_proposed_total,
@@ -1052,6 +1114,15 @@ class GenerativeMetrics:
         metric("paddle_generate_kv_pool_utilization", "gauge",
                s["kv_pool"]["utilization"],
                "fraction of KV-pool positions holding live sequences")
+        metric("paddle_generate_kv_positions_read_total", "counter",
+               s["kv_positions_read_total"],
+               "KV-pool positions the decode steps' attention read")
+        metric("paddle_generate_kv_positions_capacity_total", "counter",
+               s["kv_positions_capacity_total"],
+               "decoded rows times their class capacity")
+        metric("paddle_generate_kv_read_share", "gauge",
+               s["kv_read_share"],
+               "positions read / capacity over the decode steps (lifetime)")
         metric("paddle_generate_kv_pool_bytes", "gauge",
                s["kv_pool"].get("pool_bytes", 0),
                "bytes the KV pools allocate across active replicas")
@@ -1409,11 +1480,33 @@ class GenerativeEngine:
                            dk, dv)
 
     def _pool_shape(self, cap: int) -> tuple:
+        """[rows, L, cap, H*Dh]: the heads folded into the minor
+        dimension, so that a TPU tiles a row's positions (8, 128) over
+        (cap, H*Dh) with dense lanes — heads 64 wide as a minor
+        dimension of their own pad to 128 lanes, and the compiler then
+        puts a pool-sized copy in front of the decode kernel. The bodies
+        cut the heads after the read; the handoff wire keeps
+        [L, cap, H, Dh] by a host-side view."""
         return (self._slots + 1 + self._pc_slots, self._L, cap,
-                self._H, self._Dh)
+                self._H * self._Dh)
 
     def _draft_pool_shape(self, cap: int) -> tuple:
-        return (self._slots + 1, self._dL, cap, self._dH, self._dDh)
+        return (self._slots + 1, self._dL, cap, self._dH * self._dDh)
+
+    def _kv_plan(self, kind: str, cap: int):
+        """The decode kernel's plan where a program of family `kind` is
+        built with the kernel's read on this engine's devices; None
+        where it takes the gather (or attends over no pool rows).
+        Mirrors pool_attention's choice."""
+        if kind == "decode":
+            H, Dh = self._H, self._Dh
+        elif kind == "dpropose":
+            H, Dh = self._dH, self._dDh
+        else:
+            return None
+        if not _lowers_for_tpu(self._device_pool[0]):
+            return None
+        return kernel_plan(cap, H, Dh, self._kv_dtype)
 
     def kv_pool_bytes(self) -> int:
         """Bytes ONE worker's KV pools allocate (all capacity classes,
@@ -1434,14 +1527,20 @@ class GenerativeEngine:
         """The compile-shape inventory: which programs exist and which
         (device, program) pairs have been executed at least once."""
         with self._prog_lock:
-            progs = sorted(
-                f"{k[0]}[cap={k[1]},b={k[2]}"
-                + ("" if k[3] == 1 else f",k={k[3]}")
-                + ("" if k[4] == "f32" else f",kv={k[4]}") + "]"
+            named = sorted(
+                (f"{k[0]}[cap={k[1]},b={k[2]}"
+                 + ("" if k[3] == 1 else f",k={k[3]}")
+                 + ("" if k[4] == "f32" else f",kv={k[4]}") + "]",
+                 k[0], k[1])
                 for k in self._programs)
+        progs = [name for name, _, _ in named]
         with self._cv:
             warmed = len(self._warmed)
         return {
+            # the read each pool-attending program was built with
+            "kv_read": {
+                name: "kernel" if self._kv_plan(kind, cap) else "gather"
+                for name, kind, cap in named if kind in _POOL_READERS},
             "prefill_buckets": [b for b in self._prompt_boundaries],
             "decode_batch_buckets": list(self._batch_buckets),
             "kv_classes": list(self._caps),
@@ -1705,11 +1804,12 @@ class GenerativeEngine:
                 parts = self._program("kvget", cap, 1)(
                     cs.buf_k, cs.buf_v, put(np.int32(scratch)))
             parts[0].block_until_ready()
+            del parts       # a row pair: not held beside kvput's operands
             with self._cv:
                 self._warmed.add((devk, "kvget", cap, 1))
             n += 1
             row_dt = np.int8 if self._kv_dtype == "int8" else np.float32
-            row = np.zeros((self._L, cap, self._H, self._Dh), row_dt)
+            row = np.zeros(self._pool_shape(cap)[1:], row_dt)
             scl = None if self._kv_dtype == "f32" else \
                 np.ones((self._L,), np.float32)
             with _cc.donated_cpu_guard(self._donate):
@@ -2255,7 +2355,10 @@ class GenerativeEngine:
                 cs.free = list(range(cs.n_slots))
                 self._live_rows.pop((w.rid, cap), None)
                 self._pc_index.pop((w.rid, cap), None)
-        for cap in list(state):
+        for cap, old in list(state.items()):
+            # the poisoned pools are dropped before the fresh ones are
+            # made: never two pool pairs beside each other on the device
+            old.buf_k = old.buf_v = old.dbuf_k = old.dbuf_v = None
             state[cap] = self._alloc_class(cap, w.device)
         self._requeue(stuck)
 
@@ -2476,10 +2579,17 @@ class GenerativeEngine:
                          (devk, "verify", cs.cap, bucket)]
         else:
             prog_keys = [(devk, "decode", cs.cap, bucket)]
+        # pool positions the target's read copies for the real rows:
+        # whole blocks up to each row's position under the kernel, every
+        # position under the gather
+        plan = self._kv_plan(prog_keys[-1][1], cs.cap)
+        kv_read = n * cs.cap if plan is None else sum(
+            plan.positions_read(x, cs.cap) for x in lens[:n])
         args = None
         if _tr.enabled():
             args = {"replica": w.rid, "rows": n, "bucket": bucket,
-                    "cap": cs.cap, "spec_k": k if spec else 0,
+                    "cap": cs.cap, "kv_read": kv_read,
+                    "spec_k": k if spec else 0,
                     "traces": [r.req.ctx.trace_id for r in rows
                                if r.req.ctx is not None]}
         with self._cv:
@@ -2557,12 +2667,12 @@ class GenerativeEngine:
                     w.compiling = False
                 w.batches += 1
         with _tr.span("generate.emit", "serving", phase):
-            self._emit_step(w, gen, cs, rows, prog_keys, bucket,
+            self._emit_step(w, gen, cs, rows, prog_keys, bucket, kv_read,
                             (props, ys, khist) if spec else (nxt, nkeys))
 
     def _emit_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                    rows: list, prog_keys: list, bucket: int,
-                   results: tuple) -> None:
+                   kv_read: int, results: tuple) -> None:
         """What follows a decode step's read on the worker thread: the
         rows' bookkeeping under the lock, every accepted token to its
         stream, finished rows out of their slots."""
@@ -2572,7 +2682,7 @@ class GenerativeEngine:
         with self._cv:
             for pk in prog_keys:
                 self._warmed.add(pk)
-        self.metrics.on_step(n, bucket)
+        self.metrics.on_step(n, bucket, kv_read, n * cs.cap)
         finished = []
         if spec:
             props, ys, khist = results
@@ -2658,9 +2768,12 @@ class GenerativeEngine:
                 kd, ks, vd, vs = self._program("kvget", cs.cap, 1)(
                     cs.buf_k, cs.buf_v,
                     jax.device_put(np.int32(slot), w.device))
+            # the pool stores heads folded; the wire keeps
+            # [L, cap, H, Dh] (a view, host-side)
+            wire = (self._L, int(cs.cap), self._H, self._Dh)
             arrays = {"prompt": np.asarray(req.prompt, np.int32),
-                      "key": key, "k": np.asarray(kd),
-                      "v": np.asarray(vd)}
+                      "key": key, "k": np.asarray(kd).reshape(wire),
+                      "v": np.asarray(vd).reshape(wire)}
             if ks is not None:
                 arrays["k_scale"] = np.asarray(ks)
                 arrays["v_scale"] = np.asarray(vs)
@@ -2672,7 +2785,7 @@ class GenerativeEngine:
                     lineage.append([int(F), _prefix_hash(req.prompt, F)])
                     break
             meta = {"cap": int(cs.cap), "kv_dtype": self._kv_dtype,
-                    "shape": [self._L, int(cs.cap), self._H, self._Dh],
+                    "shape": list(wire),
                     "length": length, "tokens": tokens,
                     "streamed": sent, "max_new": int(req.max_new),
                     "eos": None if req.eos is None else int(req.eos),
@@ -2863,14 +2976,16 @@ class GenerativeEngine:
             with _tr.span("generate.kv_import", "serving", args,
                           parent=req.ctx):
                 with _cc.donated_cpu_guard(self._donate):
+                    # the wire's [L, cap, H, Dh] row, heads folded as
+                    # the pool stores them (a view, host-side)
+                    row = self._pool_shape(cs.cap)[1:]
+                    kd = put(arrays["k"].reshape(row))
+                    vd = put(arrays["v"].reshape(row))
                     if self._kv_dtype == "int8":
-                        kparts = (put(arrays["k"]),
-                                  put(arrays["k_scale"]),
-                                  put(arrays["v"]),
-                                  put(arrays["v_scale"]))
+                        kparts = (kd, put(arrays["k_scale"]),
+                                  vd, put(arrays["v_scale"]))
                     else:
-                        kparts = (put(arrays["k"]), None,
-                                  put(arrays["v"]), None)
+                        kparts = (kd, None, vd, None)
                     cs.buf_k, cs.buf_v = self._program(
                         "kvput", cs.cap, 1)(
                             cs.buf_k, cs.buf_v, put(np.int32(slot)),

@@ -1,33 +1,37 @@
 """Quantized serving tier: int8 KV-cache pool + weight-only int8 params.
 
 The serving engine's KV pool is where generation memory actually goes:
-per capacity class a [rows, L, cap, H, Dh] float32 buffer pair whose
-rows are decode slots, scratch, and prefix-cache entries. This module
-re-types that buffer as a ``QuantizedKV`` — int8 data plus a per-(row,
-layer) float32 absmax scale tensor — and provides the quantize-on-
-scatter / dequantize-on-gather primitives the generation program bodies
-fuse in-trace. Because ``QuantizedKV`` is a NamedTuple (a jax pytree),
-it rides the existing program signatures, ``donate_argnums`` sets,
-``device_put`` paths and the persistent compile cache exactly like the
-float32 array it replaces; the float path's helpers reduce to the
-original ops, so f32 engines trace byte-identical HLO.
+per capacity class a [rows, L, cap, H*Dh] float32 buffer pair (the
+heads folded into the minor dimension: dense 128-lane tiles on a TPU)
+whose rows are decode slots, scratch, and prefix-cache entries. This
+module re-types that buffer as a ``QuantizedKV`` — int8 data plus a
+per-(row, layer) float32 absmax scale tensor — and provides the
+quantize-on-write / dequantize-on-read primitives the generation
+program bodies fuse in-trace. The bodies carry the pool through their
+scan over layers and touch it where it lies: ``write_layer`` puts a
+layer's new positions into the (donated) pool, ``read_layer`` reads one
+layer of the rows a step decodes — no program gathers whole rows.
+Because ``QuantizedKV`` is a NamedTuple (a jax pytree), it rides the
+existing program signatures, ``donate_argnums`` sets, ``device_put``
+paths and the persistent compile cache exactly like the float32 array
+it replaces; the float path's helpers reduce to the plain ops.
 
 Scale scheme (per (pool row, layer), symmetric, no zero point):
 
 - ``store_block`` (prefill) RESETS the row's scale from the scattered
   block's per-layer absmax (floored at ``_ABSMAX_FLOOR`` so an all-zero
   warmup block cannot divide by zero), then quantizes the block.
-- ``scatter_rows`` (decode / verify / extend) quantizes new positions
+- ``write_layer`` (decode / verify / extend) quantizes new positions
   with the row's EXISTING scale — clip semantics: a late outlier
   saturates at +-127 rather than rescaling (and thus requantizing) the
   whole row. This is the documented long-context error source
   (DESIGN.md "Quantized serving").
-- ``fake_quant`` is the in-scan write helper: the round trip it applies
-  is bitwise what a scatter-then-gather through the pool produces, so
-  a verify program attending freshly-written block positions sees the
-  SAME values a plain decode step would read back next iteration —
-  which is what keeps spec-on output bitwise-equal to spec-off under
-  the int8 pool.
+- Write first, read after: a body writes a layer's new positions into
+  the pool and only then attends over ``read_layer``, so a verify
+  program attending freshly-written block positions sees the SAME
+  values a plain decode step would read back next iteration — pool-
+  consistent by construction, which is what keeps spec-on output
+  bitwise-equal to spec-off under the int8 pool.
 - ``copy_row`` copies raw int8 rows plus their scale row: a prefix-
   cache hit is bit-exact, never a requantization.
 
@@ -63,7 +67,7 @@ class QuantizedKV(NamedTuple):
     donation sets and device placement like the float array it
     replaces."""
 
-    data: Any    # int8 [rows, L, cap, H, Dh]
+    data: Any    # int8 [rows, L, cap, H*Dh]
     scale: Any   # f32  [rows, L] — absmax/127 per pool row per layer
 
     def block_until_ready(self):
@@ -91,16 +95,6 @@ def quant(x, s):
     import jax.numpy as jnp
 
     return jnp.clip(jnp.round(x / _bscale(s, x)), -_QMAX, _QMAX)
-
-
-def fake_quant(x, s):
-    """Quantize-dequantize x with scale s; identity when s is None
-    (the float pool). The round trip is bitwise what scatter-then-
-    gather through the int8 pool produces — the in-scan writes use this
-    so every attention read sees pool-consistent values."""
-    if s is None:
-        return x
-    return quant(x, s) * _bscale(s, x)
 
 
 def block_scale(ks):
@@ -136,53 +130,53 @@ def pool_nbytes(shape, kv_dtype: str) -> int:
     return n + int(shape[0]) * int(shape[1]) * 4
 
 
+def capacity(buf) -> int:
+    """Positions one pool row holds a layer (the class cap)."""
+    return (buf.data if is_quantized(buf) else buf).shape[2]
+
+
 def store_block(buf, slot, ks):
-    """Prefill-style full-block store: ks [L, S, H, Dh] lands at
-    positions [0, S) of pool row `slot` (S <= cap). Quantized pool:
-    the row's scale is RESET from this block's per-layer absmax, then
-    the block is quantized with it."""
+    """Prefill-style full-block store: ks [L, S, H, Dh] lands, heads
+    folded, at positions [0, S) of pool row `slot` (S <= cap).
+    Quantized pool: the row's scale is RESET from this block's per-layer
+    absmax, then the block is quantized with it."""
     import jax
     import jax.numpy as jnp
 
     z = jnp.int32(0)
+    fold = ks.shape[:2] + (-1,)
     if not is_quantized(buf):
         return jax.lax.dynamic_update_slice(
-            buf, ks[None].astype(buf.dtype), (slot, z, z, z, z))
+            buf, ks.reshape(fold)[None].astype(buf.dtype), (slot, z, z, z))
     s = block_scale(ks)                                        # [L]
-    q = quant(ks, s).astype(jnp.int8)
+    q = quant(ks, s).astype(jnp.int8).reshape(fold)
     data = jax.lax.dynamic_update_slice(buf.data, q[None],
-                                        (slot, z, z, z, z))
+                                        (slot, z, z, z))
     scale = jax.lax.dynamic_update_slice(buf.scale, s[None], (slot, z))
     return QuantizedKV(data, scale)
 
 
-def gather_rows(buf, slots):
-    """Pool rows for `slots` (array or scalar): (rows f32
-    [..., L, M, H, Dh], scales [..., L] | None). Dequantize-on-gather
-    is one fused multiply; the scales come back too so in-scan writes
-    can fake-quant new positions with the SAME row scale the final
-    scatter will quantize with."""
+def read_layer(buf, slots, layer):
+    """One layer of the pool rows `slots` (an array), dequantized:
+    f32 [..., cap, H*Dh]. The only read a program makes of rows it
+    decodes — a layer at a time, never the whole rows."""
     if not is_quantized(buf):
-        return buf[slots], None
-    s = buf.scale[slots]
-    return (buf.data[slots].astype(buf.scale.dtype)
-            * s[..., None, None, None]), s
+        return buf[slots, layer]
+    s = buf.scale[slots, layer]
+    return buf.data[slots, layer].astype(s.dtype) * s[..., None, None]
 
 
-def scatter_rows(buf, wslot, wpos, vals):
-    """Post-scan scatter of new positions: vals has shape
-    wslot.shape + (L, H, Dh); quantized writes use each target row's
+def write_layer(buf, layer, wslot, wpos, vals):
+    """New positions of one layer into the pool, in place where the pool
+    is donated: vals, of shape wslot.shape + (H*Dh,), land at
+    buf[wslot, layer, wpos]. Quantized writes use each target row's
     EXISTING scale (clip semantics — no rescaling)."""
     import jax.numpy as jnp
 
-    L = vals.shape[wslot.ndim]
-    lix = jnp.arange(L).reshape((1,) * wslot.ndim + (L,))
-    sidx = wslot[..., None]
-    pidx = wpos[..., None]
     if not is_quantized(buf):
-        return buf.at[sidx, lix, pidx].set(vals.astype(buf.dtype))
-    q = quant(vals, buf.scale[wslot]).astype(jnp.int8)
-    return buf._replace(data=buf.data.at[sidx, lix, pidx].set(q))
+        return buf.at[wslot, layer, wpos].set(vals.astype(buf.dtype))
+    q = quant(vals, buf.scale[wslot, layer]).astype(jnp.int8)
+    return buf._replace(data=buf.data.at[wslot, layer, wpos].set(q))
 
 
 def copy_row(buf, src, dst):
@@ -195,8 +189,8 @@ def copy_row(buf, src, dst):
 
 
 def row_raw(buf, slot):
-    """One pool row in its STORED dtype: ``(data [L, cap, H, Dh],
-    scale [L] | None)``. The KV-handoff export path — an int8 row
+    """One pool row in its STORED dtype and layout: ``(data
+    [L, cap, H*Dh], scale [L] | None)``. The KV-handoff export path — an int8 row
     ships as int8 bytes plus its scale row (half the f32 wire bytes)
     and never round-trips through float."""
     if not is_quantized(buf):
@@ -255,6 +249,6 @@ def dequant_params(p: dict) -> dict:
 
 
 __all__ = ["QuantizedKV", "is_quantized", "alloc", "pool_nbytes",
-           "quant", "fake_quant", "block_scale", "store_block",
-           "gather_rows", "scatter_rows", "copy_row", "row_raw",
+           "quant", "block_scale", "capacity", "store_block",
+           "read_layer", "write_layer", "copy_row", "row_raw",
            "set_row_raw", "quantize_stacked_params", "dequant_params"]

@@ -7,9 +7,9 @@ emitted token is EXACT vs float (prefill attention runs on in-program
 full-precision K/V; only the stored rows are quantized), full sequences
 match within tolerance (exactly on these tiny presets), and everything
 that was exact AMONG float paths stays exact AMONG quantized paths —
-batched == sequential == streaming == HTTP, spec-on == spec-off (the
-in-scan fake-quant writes are bitwise the scatter-then-gather round
-trip, so a verify pass reads what plain decode would), and chaos
+batched == sequential == streaming == HTTP, spec-on == spec-off (every
+pass writes its new positions into the pool before it attends, so a
+verify pass reads what plain decode would), and chaos
 requeue replays reproduce the original tokens. Density is asserted on
 allocator-real buffer nbytes, not arithmetic."""
 import json
@@ -145,48 +145,62 @@ class TestKVPrimitives:
         err = np.abs(qa.astype(np.float32) * sa - w)
         assert np.all(err.max(axis=(1, 2), keepdims=True) <= sa)
 
-    def test_store_gather_round_trip_error_bounded(self):
+    def test_store_read_round_trip_error_bounded(self):
         import jax
 
         rng = np.random.RandomState(1)
-        shape = (3, 2, 16, 4, 8)                       # rows L cap H Dh
+        shape = (3, 2, 16, 4 * 8)                      # rows L cap H*Dh
         dev = jax.devices()[0]
         buf = kvq.alloc(shape, dev, "int8")
         ks = rng.randn(2, 16, 4, 8).astype(np.float32)
         buf = kvq.store_block(buf, np.int32(1), ks)
-        rows, scl = kvq.gather_rows(buf, np.asarray([1], np.int32))
-        got = np.asarray(rows)[0]
-        s = np.asarray(scl)[0]                         # [L]
-        assert np.max(np.abs(got - ks)) <= float(s.max())
-        # untouched rows stay zero
-        other, _ = kvq.gather_rows(buf, np.asarray([0], np.int32))
-        assert np.all(np.asarray(other) == 0.0)
+        for layer in range(2):
+            got = np.asarray(kvq.read_layer(
+                buf, np.asarray([1], np.int32), layer))[0]
+            s = float(np.asarray(buf.scale)[1, layer])
+            assert np.max(np.abs(got - ks[layer].reshape(16, 32))) <= s
+            # untouched rows stay zero
+            other = kvq.read_layer(buf, np.asarray([0], np.int32), layer)
+            assert np.all(np.asarray(other) == 0.0)
 
-    def test_fake_quant_is_scatter_gather_bitwise(self):
-        """THE spec-parity lemma: fake_quant(x, s) equals the value a
-        scatter (quantize with s) then gather (dequantize with s)
-        reproduces, bitwise."""
+    def test_write_then_read_is_the_quant_round_trip_bitwise(self):
+        """THE spec-parity lemma: a position written into the pool
+        (quantize with the row's scale) and read back (dequantize with
+        it) is bitwise quant(x, s) * s, whichever program wrote it — a
+        verify pass attending its own block reads what plain decode's
+        next step would."""
+        import jax
         import jax.numpy as jnp
 
         rng = np.random.RandomState(2)
-        x = jnp.asarray(rng.randn(2, 4, 8).astype(np.float32) * 3)
-        s = jnp.asarray(np.abs(rng.randn(2)).astype(np.float32) + 0.01)
-        via_pool = (np.asarray(kvq.quant(x, s)).astype(np.int8)
-                    .astype(np.float32)
-                    * np.asarray(s)[:, None, None])
-        direct = np.asarray(kvq.fake_quant(x, s))
-        assert np.array_equal(via_pool, direct)
+        buf = kvq.alloc((3, 2, 8, 32), jax.devices()[0], "int8")
+        s = np.abs(rng.randn(3, 2)).astype(np.float32) + 0.01
+        buf = buf._replace(scale=jnp.asarray(s))
+        x = rng.randn(2, 32).astype(np.float32) * 3
+        wslot = np.asarray([2, 0], np.int32)
+        wpos = np.asarray([5, 7], np.int32)
+        buf = kvq.write_layer(buf, 1, wslot, wpos, jnp.asarray(x))
+        back = np.asarray(kvq.read_layer(buf, wslot, 1))
+        for i in range(2):
+            sc = s[wslot[i], 1]
+            want = (np.asarray(kvq.quant(jnp.asarray(x[i]),
+                                         jnp.asarray(sc)))
+                    .astype(np.int8).astype(np.float32) * sc)
+            assert np.array_equal(back[i, wpos[i]], want)
+        # the other layer and the other positions were not touched
+        assert np.all(np.asarray(buf.data)[:, 0] == 0)
+        assert np.count_nonzero(np.asarray(buf.data)[:, 1]) <= 2 * 32
 
     def test_zero_block_does_not_divide_by_zero(self):
         import jax
 
         dev = jax.devices()[0]
-        buf = kvq.alloc((2, 1, 8, 2, 4), dev, "int8")
+        buf = kvq.alloc((2, 1, 8, 2 * 4), dev, "int8")
         buf = kvq.store_block(buf, np.int32(0),
                               np.zeros((1, 8, 2, 4), np.float32))
-        rows, scl = kvq.gather_rows(buf, np.asarray([0], np.int32))
+        rows = kvq.read_layer(buf, np.asarray([0], np.int32), 0)
         assert np.all(np.isfinite(np.asarray(rows)))
-        assert np.all(np.asarray(scl) > 0.0)
+        assert np.all(np.asarray(buf.scale) > 0.0)
 
     def test_dequant_params_identity_for_float_dict(self):
         p = {"wte": np.ones((4, 2), np.float32)}
