@@ -256,7 +256,7 @@ def __getattr__(name):
                 "metric", "hapi", "profiler", "incubate", "static", "models",
                 "framework", "autograd_api", "device", "sparse", "distribution",
                 "text", "audio", "onnx", "quantization", "inference",
-                "observability"):
+                "observability", "geometric"):
         mod = importlib.import_module(f".{name}" if name != "autograd_api"
                                       else ".autograd_api", __name__)
         globals()[name] = mod

@@ -28,8 +28,10 @@ pool, re-cut for the XLA compilation contract):
   folded into the minor dimension: dense lanes on a TPU) whose rows
   are SLOTS handed out from a free list and reused across requests
   (the +1 row is scratch for decode-batch padding). Prefill stores
-  the prompt's KV into its slot in-program. The decode pass (and the
-  verify and extend passes) works on the pool WHERE IT LIES: the scan
+  the prompt's KV into its slot in-program. ONE pass, `_pool_pass`
+  (decode, the draft burst, verify and extend are that pass plus what
+  each does with the hidden states; what a block computes comes from
+  models/gpt.py), works on the pool WHERE IT LIES: the scan
   over layers carries the two pools; each layer writes its new
   position(s) into the pool (`kv.write_layer`: buf[slot, layer, pos],
   a position past the cap into the scratch row) and then attends over
@@ -145,11 +147,14 @@ from collections import OrderedDict, deque
 from queue import Queue
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ...core import compile_cache as _cc
 from ...core.flags import flag
 from ...io.bucketing import bucket_boundaries_pow2, bucket_for
+from ...models import gpt as _gpt
 from ...ops.pallas import decode_attention as _dattn
 from ...quantization import kv as _kvq
 from ...observability import trace as _tr
@@ -181,35 +186,12 @@ def _prefix_hash(prompt: np.ndarray, n: int) -> str:
 # pure program bodies (jitted per bucket; params is a dict of stacked
 # per-layer arrays — one lax.scan body instead of L unrolled blocks)
 # ===================================================================
-def _ln(h, w, b, eps):
-    mu = h.mean(-1, keepdims=True)
-    var = ((h - mu) ** 2).mean(-1, keepdims=True)
-    import jax.numpy as jnp
-
-    return (h - mu) / jnp.sqrt(var + eps) * w + b
-
-
-def _logits_head(p, h):
-    if "lm_head" in p:
-        return h @ p["lm_head"]
-    return h @ p["wte"].T
-
-
-def _layer_stack(p):
-    return (p["ln1_w"], p["ln1_b"], p["qkv_w"], p["qkv_b"], p["out_w"],
-            p["out_b"], p["ln2_w"], p["ln2_b"], p["fc1_w"], p["fc1_b"],
-            p["fc2_w"], p["fc2_b"])
-
-
 def _sample_token(logits, temp, topk, topp, key):
     """One row's next token from its logits [V]: argmax when temp == 0,
     else temperature/top-k/top-p with `key` (raw uint32[2] PRNG key).
     Both branches are computed (cheap at serving vocab sizes) so every
     program has ONE shape regardless of the batch's sampling mix — and
     the greedy value stays bitwise what the argmax-only program made."""
-    import jax
-    import jax.numpy as jnp
-
     greedy = jnp.argmax(logits).astype(jnp.int32)
     V = logits.shape[-1]
     scaled = logits / jnp.maximum(temp, 1e-6)
@@ -232,8 +214,6 @@ def _split_keys(keys):
     [b, 2]. vmapped so a row's chain is a pure function of its own key
     — independent of batch size, which is what makes sampled output
     identical across the batched and sequential paths."""
-    import jax
-
     kk = jax.vmap(lambda k: jax.random.split(k))(keys)
     return kk[:, 0], kk[:, 1]
 
@@ -246,36 +226,26 @@ def _prefill_body(p, buf_k, buf_v, slot, ids, length, temp, topk, topp,
     split consumed. ids [1, S] int32. Attention runs over the
     in-program full-precision K/V; only the POOL store quantizes (int8
     pool), so the emitted first token is exact vs the float pool."""
-    import jax
-    import jax.numpy as jnp
-
     p = _kvq.dequant_params(p)
     S = ids.shape[1]
-    D = p["wte"].shape[1]
-    H = int(num_heads)
-    Dh = D // H
     pos = jnp.arange(S, dtype=jnp.int32)
     x = p["wte"][ids] + p["wpe"][pos][None]            # [1, S, D]
     causal = pos[None, :] <= pos[:, None]              # [S, S]
 
     def body(h, lp):
-        l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b = lp
-        y = _ln(h, l1w, l1b, eps)
-        qkv = (y @ qw + qb).reshape(1, S, 3, H, Dh)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k, v = _gpt.block_qkv(h, lp, num_heads, eps)
         qh = jnp.swapaxes(q, 1, 2)                     # [1, H, S, Dh]
         kh = jnp.swapaxes(k, 1, 2)
         vh = jnp.swapaxes(v, 1, 2)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(Dh)
+        s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) \
+            / math.sqrt(q.shape[-1])
         s = jnp.where(causal[None, None], s, _NEG_INF)
         att = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vh)
-        h = h + jnp.swapaxes(att, 1, 2).reshape(1, S, D) @ ow + ob
-        y = _ln(h, l2w, l2b, eps)
-        h = h + jax.nn.gelu(y @ f1w + f1b,
-                            approximate=True) @ f2w + f2b
+        h = _gpt.block_out(h, jnp.swapaxes(att, 1, 2).reshape(h.shape),
+                           lp, eps)
         return h, (k[0], v[0])                         # [S, H, Dh]
 
-    h, (ks, vs) = jax.lax.scan(body, x, _layer_stack(p))
+    h, (ks, vs) = jax.lax.scan(body, x, _gpt.layer_stack(p))
     # ks/vs [L, S, H, Dh] -> pool rows are [L, cap, H, Dh]; positions
     # [length, S) hold junk from the pad — overwritten by the decode
     # steps before the mask (kpos <= length) ever admits them. An int8
@@ -283,11 +253,11 @@ def _prefill_body(p, buf_k, buf_v, slot, ids, length, temp, topk, topp,
     slot = slot.astype(jnp.int32)
     buf_k = _kvq.store_block(buf_k, slot, ks)
     buf_v = _kvq.store_block(buf_v, slot, vs)
-    h = _ln(h, p["lnf_w"], p["lnf_b"], eps)
+    h = _gpt.layer_norm(h, p["lnf_w"], p["lnf_b"], eps)
     h_last = jax.lax.dynamic_index_in_dim(h[0], length - 1, axis=0,
                                           keepdims=False)     # [D]
     key, sub = jax.random.split(key)
-    tok = _sample_token(_logits_head(p, h_last), temp, topk, topp, sub)
+    tok = _sample_token(_gpt.lm_head(p, h_last), temp, topk, topp, sub)
     return tok, key, buf_k, buf_v
 
 
@@ -305,9 +275,6 @@ def _gather_read(q, buf_k, buf_v, layer, slots, pos):
     """pool_attention's read in plain XLA: one layer of the rows
     (`buf[slots, layer]`, dequantized where the pool is int8) and a
     masked softmax over all M positions."""
-    import jax
-    import jax.numpy as jnp
-
     b, Q, H, Dh = q.shape
     M = _kvq.capacity(buf_k)
     k_l = _kvq.read_layer(buf_k, slots, layer).reshape(b, M, H, Dh)
@@ -324,8 +291,6 @@ def _on_tpu(kernel, twin, *args):
     """`kernel` where the program is lowered for a TPU, `twin` elsewhere
     — decided at lowering, so a compile for a described chip takes the
     kernel although the host's default backend is a CPU."""
-    import jax
-
     return jax.lax.platform_dependent(*args, tpu=kernel, default=twin)
 
 
@@ -370,80 +335,65 @@ def _pool_writes(pos, slots, cap, scratch):
     """Where new K/V at the absolute positions `pos` (slots broadcast
     against it) land in the pool: a position past the class cap is
     redirected into the scratch row, never into a live slot."""
-    import jax.numpy as jnp
-
     safe = pos < cap
     return (jnp.where(safe, slots, jnp.int32(scratch)),
             jnp.where(safe, pos, 0))
 
 
-def _layers(p):
-    """The scan's per-layer inputs: the stacked params and the layer's
-    index into the pool."""
-    import jax.numpy as jnp
-
-    return _layer_stack(p) + (jnp.arange(p["ln1_w"].shape[0],
-                                         dtype=jnp.int32),)
-
-
-def _decode_core(p, buf_k, buf_v, slots, tokens, lengths, scratch,
-                 num_heads, eps):
-    """The shared fixed-shape decode pass for `b` rows of the pool:
-    embed each row's pending token at its position; per layer write the
-    row's new K/V into the pool in place (a position past the class cap
-    — possible only inside a fused draft burst — lands in the scratch
-    row) and attend over the row's cached prefix + the token itself,
-    read from the pool by slot; return the logits. The scan over layers
-    carries the two pools: nothing gathers, transposes or re-materializes
-    pool rows. Rows are independent — padding rows target the scratch
-    slot with length 0 and their outputs are discarded by the caller."""
-    import jax
-    import jax.numpy as jnp
-
-    p = _kvq.dequant_params(p)
-    b = tokens.shape[0]
-    D = p["wte"].shape[1]
-    H = int(num_heads)
-    Dh = D // H
+def _pool_pass(p, buf_k, buf_v, slots, tokens, pos, scratch, num_heads,
+               eps):
+    """The one pass over rows of the pool that decode, the draft burst,
+    verify and extend share: embed row i's tokens at their absolute
+    positions pos (both [b], one query a row, or [b, Q]); per layer write
+    the new K/V into the pool in place (a position past the class cap —
+    a draft burst's or a bucket's overshoot — lands in the scratch row)
+    and attend over pool row slots[i] up to each position, the new ones
+    included: write first, read after, so that a block's causal mask sees
+    its own positions bitwise as a later step would read them back (spec-on
+    == spec-off under the int8 pool too). Returns the hidden states after
+    the final norm, shaped like `tokens` + [D], and the pools. The scan
+    over layers carries the two pools: nothing gathers, transposes or
+    re-materializes pool rows. Rows are independent — padding rows target
+    the scratch slot with length 0 and their outputs are discarded by the
+    caller. `p` holds float weights: a caller dequantizes once
+    (`kv.dequant_params`), for the pass and for its own head."""
+    # one query a row carries no query axis through the matmuls
+    one = tokens.ndim == 1
     x = p["wte"][tokens] + p["wpe"][jnp.minimum(
-        lengths, p["wpe"].shape[0] - 1)]               # [b, D]
-    wslot, wpos = _pool_writes(lengths, slots, _kvq.capacity(buf_k),
-                               scratch)
-    pos = lengths[:, None]                             # [b, 1]
+        pos, p["wpe"].shape[0] - 1)]                   # [b, (Q,) D]
+    wslot, wpos = _pool_writes(pos, slots if one else slots[:, None],
+                               _kvq.capacity(buf_k), scratch)
+    qpos = pos[:, None] if one else pos                # [b, Q]
 
-    def body(carry, lp):
+    def body(carry, xs):
         h, buf_k, buf_v = carry
-        (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-         layer) = lp
-        y = _ln(h, l1w, l1b, eps)
-        qkv = (y @ qw + qb).reshape(b, 3, H, Dh)
-        q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+        lp, layer = xs
+        q, k_new, v_new = _gpt.block_qkv(h, lp, num_heads, eps)
         buf_k = _kvq.write_layer(buf_k, layer, wslot, wpos,
-                                 k_new.reshape(b, D))
+                                 k_new.reshape(h.shape))
         buf_v = _kvq.write_layer(buf_v, layer, wslot, wpos,
-                                 v_new.reshape(b, D))
-        att = pool_attention(q[:, None], buf_k, buf_v, layer, slots, pos)
-        h = h + att[:, 0] @ ow + ob
-        y = _ln(h, l2w, l2b, eps)
-        h = h + jax.nn.gelu(y @ f1w + f1b,
-                            approximate=True) @ f2w + f2b
+                                 v_new.reshape(h.shape))
+        att = pool_attention(q[:, None] if one else q, buf_k, buf_v,
+                             layer, slots, qpos)
+        h = _gpt.block_out(h, att[:, 0] if one else att, lp, eps)
         return (h, buf_k, buf_v), None
 
-    (h, buf_k, buf_v), _ = jax.lax.scan(body, (x, buf_k, buf_v),
-                                        _layers(p))
-    h = _ln(h, p["lnf_w"], p["lnf_b"], eps)
-    return _logits_head(p, h), buf_k, buf_v
+    layers = _gpt.layer_stack(p)
+    (h, buf_k, buf_v), _ = jax.lax.scan(
+        body, (x, buf_k, buf_v),
+        (layers, jnp.arange(layers.ln1_w.shape[0], dtype=jnp.int32)))
+    return _gpt.layer_norm(h, p["lnf_w"], p["lnf_b"], eps), buf_k, buf_v
 
 
 def _decode_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
                  topps, keys, scratch, num_heads, eps):
-    """One fixed-shape decode step: the shared decode pass plus the
-    sampling head — one key split per row, greedy rows (temp 0) stay
-    bitwise-identical to the argmax-only program."""
-    import jax
-
-    logits, buf_k, buf_v = _decode_core(p, buf_k, buf_v, slots, tokens,
-                                        lengths, scratch, num_heads, eps)
+    """One fixed-shape decode step: each row's pending token through the
+    pool pass, plus the sampling head — one key split per row, greedy
+    rows (temp 0) stay bitwise-identical to the argmax-only program."""
+    p = _kvq.dequant_params(p)
+    h, buf_k, buf_v = _pool_pass(p, buf_k, buf_v, slots, tokens, lengths,
+                                 scratch, num_heads, eps)
+    logits = _gpt.lm_head(p, h)
     keys, subs = _split_keys(keys)
     nxt = jax.vmap(_sample_token)(logits, temps, topks, topps, subs)
     return nxt, keys, buf_k, buf_v
@@ -456,14 +406,13 @@ def _propose_body(p, buf_k, buf_v, slots, tokens, lengths, k, scratch,
     per row and leaves the draft pool's K/V advanced through all k
     consumed inputs (so a fully-accepted burst finds every cached
     position it needs on the next iteration)."""
-    import jax
-    import jax.numpy as jnp
+    p = _kvq.dequant_params(p)
 
     def step(carry, _):
         toks, lens, bk, bv = carry
-        logits, bk, bv = _decode_core(p, bk, bv, slots, toks, lens,
-                                      scratch, num_heads, eps)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        h, bk, bv = _pool_pass(p, bk, bv, slots, toks, lens, scratch,
+                               num_heads, eps)
+        nxt = jnp.argmax(_gpt.lm_head(p, h), axis=-1).astype(jnp.int32)
         return (nxt, lens + 1, bk, bv), nxt
 
     (_, _, buf_k, buf_v), props = jax.lax.scan(
@@ -474,54 +423,18 @@ def _propose_body(p, buf_k, buf_v, slots, tokens, lengths, k, scratch,
 def _verify_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
                  topps, keys, scratch, num_heads, eps):
     """Speculative verification: tokens [b, k] are each row's pending
-    token followed by k-1 draft proposals; ONE batched pass computes
-    the target's own token at every position — sampled with exactly
-    the key chain the plain decode path would consume, one split per
-    position — scatters the block's K/V (positions past the class cap
-    land in the scratch row) and returns the per-position tokens plus
-    the key chain [b, k, 2] so the host can accept the longest agreed
-    prefix and carry the key advanced by as many splits as tokens it
-    emitted."""
-    import jax
-    import jax.numpy as jnp
-
+    token followed by k-1 draft proposals; ONE pool pass computes the
+    target's own token at every position — sampled with exactly the key
+    chain the plain decode path would consume, one split per position —
+    and returns the per-position tokens plus the key chain [b, k, 2] so
+    the host can accept the longest agreed prefix and carry the key
+    advanced by as many splits as tokens it emitted."""
     p = _kvq.dequant_params(p)
-    b, kk = tokens.shape
-    D = p["wte"].shape[1]
-    H = int(num_heads)
-    Dh = D // H
+    kk = tokens.shape[1]
     pos = lengths[:, None] + jnp.arange(kk, dtype=jnp.int32)[None, :]
-    x = p["wte"][tokens] + p["wpe"][jnp.minimum(
-        pos, p["wpe"].shape[0] - 1)]                   # [b, k, D]
-    wslot, wpos = _pool_writes(pos, slots[:, None],
-                               _kvq.capacity(buf_k), scratch)
-
-    def body(carry, lp):
-        h, buf_k, buf_v = carry
-        (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-         layer) = lp
-        y = _ln(h, l1w, l1b, eps)
-        qkv = (y @ qw + qb).reshape(b, kk, 3, H, Dh)
-        q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        # the block's positions go into the pool first (so the intra-
-        # block causal mask sees them, bitwise as plain decode's next
-        # step would read them back — spec-on == spec-off under the int8
-        # pool too); overflow lands in the scratch row
-        buf_k = _kvq.write_layer(buf_k, layer, wslot, wpos,
-                                 k_new.reshape(b, kk, D))
-        buf_v = _kvq.write_layer(buf_v, layer, wslot, wpos,
-                                 v_new.reshape(b, kk, D))
-        h = h + pool_attention(q, buf_k, buf_v, layer, slots,
-                               pos) @ ow + ob
-        y = _ln(h, l2w, l2b, eps)
-        h = h + jax.nn.gelu(y @ f1w + f1b,
-                            approximate=True) @ f2w + f2b
-        return (h, buf_k, buf_v), None
-
-    (h, buf_k, buf_v), _ = jax.lax.scan(body, (x, buf_k, buf_v),
-                                        _layers(p))
-    h = _ln(h, p["lnf_w"], p["lnf_b"], eps)
-    logits = _logits_head(p, h)                        # [b, k, V]
+    h, buf_k, buf_v = _pool_pass(p, buf_k, buf_v, slots, tokens, pos,
+                                 scratch, num_heads, eps)
+    logits = _gpt.lm_head(p, h)                        # [b, k, V]
     outs, hist = [], []
     cur = keys
     for i in range(kk):
@@ -537,53 +450,21 @@ def _verify_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
 def _extend_body(p, buf_k, buf_v, slot, ids, start, length, temp, topk,
                  topp, key, scratch, num_heads, eps):
     """Prefix-cache tail prefill: slot already holds valid K/V for
-    positions [0, start); compute the T-token tail block in one pass
-    (queries attend the cached prefix + causally within the block),
-    scatter its K/V at [start, start+T) (bucket overshoot past the
-    class cap lands in the scratch row) and emit the first token from
-    the logits at absolute position length-1. ids [1, T] int32. An int8
-    pool KEEPS the row's scale (set by the cached prefix's original
-    prefill): tail positions quantize with it, clip semantics — the
-    scale-granularity error source DESIGN.md documents."""
-    import jax
-    import jax.numpy as jnp
-
+    positions [0, start); the T-token tail block ids [1, T] goes through
+    the pool pass as one row's T queries at [start, start+T), and the
+    first token comes from the hidden state at absolute position
+    length-1. An int8 pool KEEPS the row's scale (set by the cached
+    prefix's original prefill): tail positions quantize with it, clip
+    semantics — the scale-granularity error source DESIGN.md documents."""
     p = _kvq.dequant_params(p)
-    T = ids.shape[1]
-    D = p["wte"].shape[1]
-    H = int(num_heads)
-    Dh = D // H
-    pos = start + jnp.arange(T, dtype=jnp.int32)       # absolute
-    x = p["wte"][ids] + p["wpe"][jnp.minimum(
-        pos, p["wpe"].shape[0] - 1)][None]             # [1, T, D]
-    slot = slot.astype(jnp.int32)
-    wslot, wpos = _pool_writes(pos, slot, _kvq.capacity(buf_k), scratch)
-
-    def body(carry, lp):
-        h, buf_k, buf_v = carry
-        (l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b,
-         layer) = lp
-        y = _ln(h, l1w, l1b, eps)
-        qkv = (y @ qw + qb).reshape(1, T, 3, H, Dh)
-        q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        buf_k = _kvq.write_layer(buf_k, layer, wslot, wpos,
-                                 k_new.reshape(T, D))
-        buf_v = _kvq.write_layer(buf_v, layer, wslot, wpos,
-                                 v_new.reshape(T, D))
-        h = h + pool_attention(q, buf_k, buf_v, layer, slot[None],
-                               pos[None]) @ ow + ob
-        y = _ln(h, l2w, l2b, eps)
-        h = h + jax.nn.gelu(y @ f1w + f1b,
-                            approximate=True) @ f2w + f2b
-        return (h, buf_k, buf_v), None
-
-    (h, buf_k, buf_v), _ = jax.lax.scan(body, (x, buf_k, buf_v),
-                                        _layers(p))
-    h = _ln(h, p["lnf_w"], p["lnf_b"], eps)
+    pos = start + jnp.arange(ids.shape[1], dtype=jnp.int32)   # absolute
+    h, buf_k, buf_v = _pool_pass(
+        p, buf_k, buf_v, slot.astype(jnp.int32)[None], ids, pos[None],
+        scratch, num_heads, eps)
     h_last = jax.lax.dynamic_index_in_dim(h[0], length - 1 - start,
                                           axis=0, keepdims=False)
     key, sub = jax.random.split(key)
-    tok = _sample_token(_logits_head(p, h_last), temp, topk, topp, sub)
+    tok = _sample_token(_gpt.lm_head(p, h_last), temp, topk, topp, sub)
     return tok, key, buf_k, buf_v
 
 
@@ -618,59 +499,16 @@ def _kvput_body(buf_k, buf_v, slot, kd, ks, vd, vs):
 
 
 def stack_gpt_params(model) -> Tuple[dict, object]:
-    """Stack a GPTForCausalLM / GPTForCausalLMScan's weights into the
-    [L, ...] param dict the generation programs scan over (REAL copies
-    — a donated train step elsewhere must not kill the serving arrays).
+    """A GPTForCausalLM / GPTForCausalLMScan's weights as the [L, ...]
+    param dict the generation programs scan over (REAL copies — a
+    donated train step elsewhere must not kill the serving arrays).
     Returns (params, cfg)."""
-    import jax.numpy as jnp
-
-    from ...models.gpt import GPTForCausalLM, GPTForCausalLMScan
-
-    def cp(t):
-        return jnp.array(t._data, copy=True)
-
-    cfg = model.cfg
-    if isinstance(model, GPTForCausalLMScan):
-        p = {"wte": cp(model.wte.weight), "wpe": cp(model.wpe.weight),
-             "ln1_w": cp(model.ln1_w), "ln1_b": cp(model.ln1_b),
-             "qkv_w": cp(model.qkv_w), "qkv_b": cp(model.qkv_b),
-             "out_w": cp(model.out_w), "out_b": cp(model.out_b),
-             "ln2_w": cp(model.ln2_w), "ln2_b": cp(model.ln2_b),
-             "fc1_w": cp(model.fc1_w), "fc1_b": cp(model.fc1_b),
-             "fc2_w": cp(model.fc2_w), "fc2_b": cp(model.fc2_b),
-             "lnf_w": cp(model.ln_f.weight), "lnf_b": cp(model.ln_f.bias)}
-        if not cfg.tie_embeddings:
-            p["lm_head"] = cp(model.lm_head_w)
-    elif isinstance(model, GPTForCausalLM):
-        blocks = model.gpt.blocks
-
-        def stack(get):
-            return jnp.stack([jnp.array(get(b)._data, copy=True)
-                              for b in blocks])
-
-        p = {"wte": cp(model.gpt.wte.weight),
-             "wpe": cp(model.gpt.wpe.weight),
-             "ln1_w": stack(lambda b: b.ln1.weight),
-             "ln1_b": stack(lambda b: b.ln1.bias),
-             "qkv_w": stack(lambda b: b.attn.qkv_proj.weight),
-             "qkv_b": stack(lambda b: b.attn.qkv_proj.bias),
-             "out_w": stack(lambda b: b.attn.out_proj.weight),
-             "out_b": stack(lambda b: b.attn.out_proj.bias),
-             "ln2_w": stack(lambda b: b.ln2.weight),
-             "ln2_b": stack(lambda b: b.ln2.bias),
-             "fc1_w": stack(lambda b: b.mlp.fc1.weight),
-             "fc1_b": stack(lambda b: b.mlp.fc1.bias),
-             "fc2_w": stack(lambda b: b.mlp.fc2.weight),
-             "fc2_b": stack(lambda b: b.mlp.fc2.bias),
-             "lnf_w": cp(model.gpt.ln_f.weight),
-             "lnf_b": cp(model.gpt.ln_f.bias)}
-        if not cfg.tie_embeddings:
-            p["lm_head"] = cp(model.lm_head.weight)
-    else:
+    if not isinstance(model, (_gpt.GPTForCausalLM,
+                              _gpt.GPTForCausalLMScan)):
         raise TypeError(
             f"GenerativeEngine wants a GPTForCausalLM[Scan] (or a "
             f"(params, cfg) pair via params=); got {type(model).__name__}")
-    return p, cfg
+    return model.stacked_params(), model.cfg
 
 
 # ===================================================================
@@ -1226,8 +1064,6 @@ class GenerativeEngine:
                  prefix_cache_slots: int = 0,
                  kv_dtype: str = "f32",
                  quantize_weights: bool = False):
-        import jax
-
         if params is not None:
             self._params, self._cfg = params
         else:
@@ -1385,8 +1221,6 @@ class GenerativeEngine:
         key = (kind, cap, bucket, k, self._kv_dtype)
         import functools
 
-        import jax
-
         # always under the lock (no unlocked fast path): workers on
         # different devices race the first build of a (family, cap,
         # bucket) entry, and an uncontended acquire is noise next to a
@@ -1438,8 +1272,6 @@ class GenerativeEngine:
         return prog
 
     def _params_for(self, device):
-        import jax
-
         key = self._device_key(device)
         with self._prog_lock:
             p = self._params_by_dev.get(key)
@@ -1453,8 +1285,6 @@ class GenerativeEngine:
         return p
 
     def _draft_params_for(self, device):
-        import jax
-
         key = self._device_key(device)
         with self._prog_lock:
             p = self._draft_by_dev.get(key)
@@ -1754,8 +1584,6 @@ class GenerativeEngine:
         an XLA compile. Inputs are committed to `device` EXACTLY like
         the execution path's (an uncommitted warm input would compile a
         sibling executable and leave the real first call cold)."""
-        import jax
-
         def put(a):
             return jax.device_put(a, device)
 
@@ -2370,8 +2198,6 @@ class GenerativeEngine:
 
     def _prefill_one(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                      slot: int, req: _GenRequest) -> None:
-        import jax
-
         P = int(req.prompt.size)
         bounds = [b for b in self._prompt_boundaries if b <= cs.cap]
         S = bucket_for(P, bounds)
@@ -2538,8 +2364,6 @@ class GenerativeEngine:
         arrays' device_puts), `.launch` (the program call until it
         returns), `.wait` (the blocking read of the results), all three
         inside `generate.decode_step`, then `generate.emit`."""
-        import jax
-
         with self._cv:
             if w.generation != gen:
                 return
@@ -2749,8 +2573,6 @@ class GenerativeEngine:
         prefix-cache lineage. Runs the warmed kvget program on the
         owning worker thread, OUTSIDE the engine lock. None when the
         row vanished under us (supersede race)."""
-        import jax
-
         from ..fabric import handoff as _ho
 
         with self._cv:
@@ -2929,8 +2751,6 @@ class GenerativeEngine:
         invisible to output — only the acceptance rate could shift),
         and the payload's prefix lineage is admitted into the local
         cache so follow-up prompts hit it."""
-        import jax
-
         meta, arrays = req.handoff
         P = int(req.prompt.size)
         length = int(meta["length"])

@@ -12,6 +12,7 @@ TPU-first details:
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -187,6 +188,30 @@ class GPTForCausalLM(nn.Layer):
             logits.reshape([-1, self.cfg.vocab_size]),
             labels.reshape([-1]))
 
+    def stacked_items(self):
+        """The weights as (name, fresh array) pairs, made one at a time:
+        wte, wpe, the LAYER_PARAMS stacked [L, ...], lnf_w, lnf_b and,
+        untied, lm_head. A caller that hands each on as it comes
+        (GPTForCausalLMScan.from_unrolled) never holds a second whole
+        copy of the weights — at 1.3B on one chip that copy does not fit."""
+        blocks = [GPTLayer(b.ln1.weight, b.ln1.bias,
+                           b.attn.qkv_proj.weight, b.attn.qkv_proj.bias,
+                           b.attn.out_proj.weight, b.attn.out_proj.bias,
+                           b.ln2.weight, b.ln2.bias,
+                           b.mlp.fc1.weight, b.mlp.fc1.bias,
+                           b.mlp.fc2.weight, b.mlp.fc2.bias)
+                  for b in self.gpt.blocks]
+        layers = ((n, jnp.stack([t._data for t in ts]))
+                  for n, ts in zip(LAYER_PARAMS, zip(*blocks)))
+        return _stacked_items(
+            self.gpt, layers, self.gpt.ln_f,
+            None if self.cfg.tie_embeddings else self.lm_head.weight)
+
+    def stacked_params(self) -> dict:
+        """`stacked_items()` as the dict the scans over layers read —
+        what the serving engine's programs take."""
+        return dict(self.stacked_items())
+
     def _logits_from_hidden(self, h):
         if self.cfg.tie_embeddings:
             return paddle.matmul(h, self.gpt.wte.weight, transpose_y=True)
@@ -248,18 +273,84 @@ class GPTForCausalLM(nn.Layer):
         return paddle.concat(out_ids, axis=1)
 
 
+# One block's arrays, in the order every scan over layers takes them:
+# GPTForCausalLMScan's parameters and the engine's param dict hold them
+# stacked [L, ...] under these names, a scan body sees one layer's.
+GPTLayer = collections.namedtuple("GPTLayer", (
+    "ln1_w", "ln1_b", "qkv_w", "qkv_b", "out_w", "out_b",
+    "ln2_w", "ln2_b", "fc1_w", "fc1_b", "fc2_w", "fc2_b"))
+LAYER_PARAMS = GPTLayer._fields
+
+
+def layer_stack(p) -> GPTLayer:
+    """A scan's per-layer input from a stacked param dict."""
+    return GPTLayer(*(p[n] for n in LAYER_PARAMS))
+
+
+# What a GPT block computes, as plain jax.numpy over [..., D] residual
+# streams: two halves with the caller's own attention between them (the
+# train scan's flash kernel, the engine's in-program causal softmax or
+# its read of the K/V pool).
+def layer_norm(h, w, b, eps):
+    mu = h.mean(-1, keepdims=True)
+    var = ((h - mu) ** 2).mean(-1, keepdims=True)
+    return (h - mu) / jnp.sqrt(var + eps) * w + b
+
+
+def block_qkv(h, lp: GPTLayer, num_heads, eps):
+    """First half: norm and fused QKV projection of the residual stream
+    h [..., D]; returns q, k, v, each [..., H, Dh]."""
+    qkv = layer_norm(h, lp.ln1_w, lp.ln1_b, eps) @ lp.qkv_w + lp.qkv_b
+    H = int(num_heads)
+    qkv = qkv.reshape(h.shape[:-1] + (3, H, h.shape[-1] // H))
+    return qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+
+
+def block_out(h, att, lp: GPTLayer, eps):
+    """Second half: the attention's output att [..., D] through the output
+    projection, second norm and GELU MLP, onto the residual stream."""
+    h = h + att @ lp.out_w + lp.out_b
+    y = layer_norm(h, lp.ln2_w, lp.ln2_b, eps)
+    y = jax.nn.gelu(y @ lp.fc1_w + lp.fc1_b, approximate=True) \
+        @ lp.fc2_w + lp.fc2_b
+    return h + y
+
+
+def lm_head(p, h):
+    """Logits of hidden states h [..., D] (after the final norm) under a
+    stacked param dict: its own head, or the tied embedding."""
+    if "lm_head" in p:
+        return h @ p["lm_head"]
+    return h @ p["wte"].T
+
+
+def _copy(t):
+    # REAL copies, not aliases: the source model's arrays die the moment
+    # a donated train step updates it
+    return jnp.array(t._data, copy=True)
+
+
+def _stacked_items(emb, layers, ln_f, head):
+    """`layers`: the (name, fresh array) pairs of the LAYER_PARAMS, lazy."""
+    yield "wte", _copy(emb.wte.weight)
+    yield "wpe", _copy(emb.wpe.weight)
+    yield from layers
+    yield "lnf_w", _copy(ln_f.weight)
+    yield "lnf_b", _copy(ln_f.bias)
+    if head is not None:
+        yield "lm_head", _copy(head)
+
+
 @defop("gpt_scan_blocks")
-def _gpt_scan_blocks_p(x, ln1_w, ln1_b, qkv_w, qkv_b, out_w, out_b,
-                       ln2_w, ln2_b, fc1_w, fc1_b, fc2_w, fc2_b,
-                       num_heads=8, eps=1e-5, remat=False,
+def _gpt_scan_blocks_p(x, *layers, num_heads=8, eps=1e-5, remat=False,
                        attn_shard=None):
     """The whole transformer stack as ONE lax.scan over stacked per-layer
-    params ([L, ...] leading axis) — XLA sees one block body instead of L
-    unrolled copies, so compile time drops ~L-fold (same math as the
-    unrolled GPTBlock list; dropout-free path). remat=True checkpoints
-    each scan iteration (activation memory ~1 block). attn_shard =
-    (mesh, spec of the [B, L, H, hd] q/k/v) runs attention per shard
-    (GPTForCausalLMScan.shard_attention)."""
+    params (`layers`: the LAYER_PARAMS, [L, ...] leading axis) — XLA sees
+    one block body instead of L unrolled copies, so compile time drops
+    ~L-fold (same math as the unrolled GPTBlock list; dropout-free path).
+    remat=True checkpoints each scan iteration (activation memory ~1
+    block). attn_shard = (mesh, spec of the [B, L, H, hd] q/k/v) runs
+    attention per shard (GPTForCausalLMScan.shard_attention)."""
     from ..nn.functional import _sdpa_p
 
     sdpa = functools.partial(_sdpa_p._pure_fn, is_causal=True)
@@ -269,32 +360,14 @@ def _gpt_scan_blocks_p(x, ln1_w, ln1_b, qkv_w, qkv_b, out_w, out_b,
         mesh, spec = attn_shard
         sdpa = shard_map(sdpa, mesh, in_specs=(spec,) * 3, out_specs=spec,
                          check=False)
-    H = int(num_heads)
-    D = x.shape[-1]
-    hd = D // H
 
-    def ln(h, w, b):
-        mu = h.mean(-1, keepdims=True)
-        var = ((h - mu) ** 2).mean(-1, keepdims=True)
-        return (h - mu) / jnp.sqrt(var + eps) * w + b
-
-    def body(h, p):
-        l1w, l1b, qw, qb, ow, ob, l2w, l2b, f1w, f1b, f2w, f2b = p
-        y = ln(h, l1w, l1b)
-        qkv = y @ qw + qb                       # [B, L, 3D]
-        b_, l_, _ = qkv.shape
-        qkv = qkv.reshape(b_, l_, 3, H, hd)
-        att = sdpa(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
-        h = h + att.reshape(b_, l_, D) @ ow + ob
-        y = ln(h, l2w, l2b)
-        y = jax.nn.gelu(y @ f1w + f1b, approximate=True) @ f2w + f2b
-        return h + y, None
+    def body(h, lp):
+        att = sdpa(*block_qkv(h, lp, num_heads, eps))
+        return block_out(h, att.reshape(h.shape), lp, eps), None
 
     if remat:
         body = jax.checkpoint(body)
-    out, _ = jax.lax.scan(body, x, (ln1_w, ln1_b, qkv_w, qkv_b, out_w,
-                                    out_b, ln2_w, ln2_b, fc1_w, fc1_b,
-                                    fc2_w, fc2_b))
+    out, _ = jax.lax.scan(body, x, GPTLayer(*layers))
     return out
 
 
@@ -317,21 +390,17 @@ class GPTForCausalLMScan(nn.Layer):
         self.wte = nn.Embedding(cfg.vocab_size, D)
         self.wpe = nn.Embedding(cfg.max_seq_len, D)
         mk = self.create_parameter
-        z = nn.initializer.Constant(0.0)
-        one = nn.initializer.Constant(1.0)
         xav = nn.initializer.XavierNormal()
-        self.ln1_w = mk([L, D], default_initializer=one)
-        self.ln1_b = mk([L, D], default_initializer=z)
-        self.qkv_w = mk([L, D, 3 * D], default_initializer=xav)
-        self.qkv_b = mk([L, 3 * D], default_initializer=z)
-        self.out_w = mk([L, D, D], default_initializer=xav)
-        self.out_b = mk([L, D], default_initializer=z)
-        self.ln2_w = mk([L, D], default_initializer=one)
-        self.ln2_b = mk([L, D], default_initializer=z)
-        self.fc1_w = mk([L, D, Hf], default_initializer=xav)
-        self.fc1_b = mk([L, Hf], default_initializer=z)
-        self.fc2_w = mk([L, Hf, D], default_initializer=xav)
-        self.fc2_b = mk([L, D], default_initializer=z)
+        one = nn.initializer.Constant(1.0)
+        z = nn.initializer.Constant(0.0)
+        # one STACKED parameter per LAYER_PARAMS entry: its initializer
+        # and one layer's shape
+        inits = GPTLayer((one, [D]), (z, [D]), (xav, [D, 3 * D]),
+                         (z, [3 * D]), (xav, [D, D]), (z, [D]),
+                         (one, [D]), (z, [D]), (xav, [D, Hf]), (z, [Hf]),
+                         (xav, [Hf, D]), (z, [D]))
+        for n, (init, shape) in zip(LAYER_PARAMS, inits):
+            setattr(self, n, mk([L] + shape, default_initializer=init))
         self.ln_f = nn.LayerNorm(D, cfg.layer_norm_eps)
         if not cfg.tie_embeddings:
             self.lm_head_w = mk([D, cfg.vocab_size],
@@ -367,46 +436,30 @@ class GPTForCausalLMScan(nn.Layer):
                             max_seq_len=cfg.max_seq_len, dropout=0.0,
                             layer_norm_eps=cfg.layer_norm_eps,
                             tie_embeddings=cfg.tie_embeddings))
-        # REAL copies, not aliases: the source model's arrays die the
-        # moment a donated train step updates it
-        out.wte.weight.set_value(jnp.array(model.gpt.wte.weight._data,
-                                           copy=True))
-        out.wpe.weight.set_value(jnp.array(model.gpt.wpe.weight._data,
-                                           copy=True))
-        blocks = model.gpt.blocks
-
-        def stack(get):
-            return jnp.stack([get(b)._data for b in blocks])
-
-        out.ln1_w.set_value(stack(lambda b: b.ln1.weight))
-        out.ln1_b.set_value(stack(lambda b: b.ln1.bias))
-        out.qkv_w.set_value(stack(lambda b: b.attn.qkv_proj.weight))
-        out.qkv_b.set_value(stack(lambda b: b.attn.qkv_proj.bias))
-        out.out_w.set_value(stack(lambda b: b.attn.out_proj.weight))
-        out.out_b.set_value(stack(lambda b: b.attn.out_proj.bias))
-        out.ln2_w.set_value(stack(lambda b: b.ln2.weight))
-        out.ln2_b.set_value(stack(lambda b: b.ln2.bias))
-        out.fc1_w.set_value(stack(lambda b: b.mlp.fc1.weight))
-        out.fc1_b.set_value(stack(lambda b: b.mlp.fc1.bias))
-        out.fc2_w.set_value(stack(lambda b: b.mlp.fc2.weight))
-        out.fc2_b.set_value(stack(lambda b: b.mlp.fc2.bias))
-        out.ln_f.weight.set_value(jnp.array(model.gpt.ln_f.weight._data,
-                                            copy=True))
-        out.ln_f.bias.set_value(jnp.array(model.gpt.ln_f.bias._data,
-                                          copy=True))
+        dest = {"wte": out.wte.weight, "wpe": out.wpe.weight,
+                "lnf_w": out.ln_f.weight, "lnf_b": out.ln_f.bias,
+                **{n: getattr(out, n) for n in LAYER_PARAMS}}
         if not cfg.tie_embeddings:
-            out.lm_head_w.set_value(jnp.array(model.lm_head.weight._data,
-                                              copy=True))
+            dest["lm_head"] = out.lm_head_w
+        # one at a time: each set_value frees the array it replaces
+        for name, value in model.stacked_items():
+            dest[name].set_value(value)
         return out
+
+    def stacked_params(self) -> dict:
+        """Copies of the weights as the stacked param dict (see
+        GPTForCausalLM.stacked_params)."""
+        layers = ((n, _copy(getattr(self, n))) for n in LAYER_PARAMS)
+        return dict(_stacked_items(
+            self, layers, self.ln_f,
+            None if self.cfg.tie_embeddings else self.lm_head_w))
 
     def hidden(self, input_ids):
         b, l = input_ids.shape
         pos = paddle.arange(l, dtype="int64").unsqueeze(0)
         x = self.wte(input_ids) + self.wpe(pos)
         h = _gpt_scan_blocks_p(
-            x, self.ln1_w, self.ln1_b, self.qkv_w, self.qkv_b,
-            self.out_w, self.out_b, self.ln2_w, self.ln2_b,
-            self.fc1_w, self.fc1_b, self.fc2_w, self.fc2_b,
+            x, *(getattr(self, n) for n in LAYER_PARAMS),
             num_heads=self.cfg.num_heads, eps=self.cfg.layer_norm_eps,
             remat=bool(self.remat), attn_shard=self._attn_shard)
         return self.ln_f(h)

@@ -35,6 +35,18 @@ def pytest_configure(config):
     _armed = not is_controller
     if _armed:
         _fh.dump_traceback_later(_WEDGE_WINDOW_S, exit=True)
+    # Build cpp/ (git-ignored output) BEFORE collection, through the one
+    # function that builds it under cpp/.build_lock: test_pd_infer_capi.py
+    # and test_shm_channel.py decide their skips at collection, and a
+    # library that only some later test's store builds lazily would skip
+    # them on a clean tree's first run and pass them on every later one.
+    # Under xdist the controller gets here before it starts a worker; the
+    # workers' calls find the library fresh. Without make or a compiler
+    # this returns None and those tests skip. The NATIVE_* snapshots below
+    # were taken at import, before this.
+    from paddle_tpu.distributed.store import _load_lib
+
+    _load_lib()
 
 
 def pytest_runtest_logstart(nodeid, location):
@@ -85,3 +97,39 @@ def require_native(loaded: bool) -> None:
                 "cpp/ was built but the native runtime failed to "
                 "load/rebuild — C++ build regression")
         pytest.skip("native library unavailable (cpp/ never built here)")
+
+
+# -------------------------------------------------------- fabric threads --
+import pytest as _pytest
+
+
+@_pytest.fixture
+def fabric_threads_stopped(monkeypatch):
+    """Stops, when the test ends, every HostLease heartbeat and
+    MembershipView poll loop the test started (inference/fabric/
+    membership.py `_loop`): the policy tests register leases on dict
+    stores and never leave, and a daemon thread left behind lives on in
+    the xdist worker under every later test. Module-scoped fixtures are
+    set up before this one and keep their threads."""
+    from paddle_tpu.inference.fabric.membership import (HostLease,
+                                                        MembershipView)
+
+    started = []
+
+    def recording(method):
+        def wrapped(self, *args, **kwargs):
+            started.append(self)
+            return method(self, *args, **kwargs)
+        return wrapped
+
+    monkeypatch.setattr(HostLease, "register",
+                        recording(HostLease.register))
+    monkeypatch.setattr(MembershipView, "start",
+                        recording(MembershipView.start))
+    yield
+    for obj in started:
+        obj._stop.set()
+    for obj in started:
+        if obj._thread is not None:
+            obj._thread.join(5.0)
+            assert not obj._thread.is_alive()

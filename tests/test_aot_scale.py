@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.models.gpt import PRESETS, _gpt_scan_blocks_p
+from paddle_tpu.models.gpt import (PRESETS, _gpt_scan_blocks_p,
+                                   layer_stack)
 from paddle_tpu.nn.functional_more import fused_linear_cross_entropy
 
 V4_HBM_GB = 32.0  # TPU v4 per-chip HBM (BASELINE.md runs on v4-32)
@@ -50,10 +51,7 @@ def _hidden(params, ids, cfg, remat=True):
     x = jnp.take(params["wte"], ids, axis=0) + \
         params["wpe"][None, : ids.shape[1]]
     h = _gpt_scan_blocks_p._pure_fn(
-        x, params["ln1_w"], params["ln1_b"], params["qkv_w"],
-        params["qkv_b"], params["out_w"], params["out_b"],
-        params["ln2_w"], params["ln2_b"], params["fc1_w"],
-        params["fc1_b"], params["fc2_w"], params["fc2_b"],
+        x, *layer_stack(params),
         num_heads=cfg.num_heads, eps=cfg.layer_norm_eps, remat=remat)
     mu = h.mean(-1, keepdims=True)
     var = ((h - mu) ** 2).mean(-1, keepdims=True)
@@ -181,10 +179,7 @@ class TestGPT67BStagePrograms:
 
         def stage_fwd(params, x):
             return _gpt_scan_blocks_p._pure_fn(
-                x, params["ln1_w"], params["ln1_b"], params["qkv_w"],
-                params["qkv_b"], params["out_w"], params["out_b"],
-                params["ln2_w"], params["ln2_b"], params["fc1_w"],
-                params["fc1_b"], params["fc2_w"], params["fc2_b"],
+                x, *layer_stack(params),
                 num_heads=cfg.num_heads, eps=cfg.layer_norm_eps,
                 remat=True)
 

@@ -146,6 +146,14 @@ class TestDynamicReplicas:
         cache BEFORE it can see traffic: its report says so, and the
         traffic that follows records only bucket HITS (zero new
         compiles) — the executables were all pre-built."""
+        # The added replica lands on a second device, and a cold
+        # persistent cache (a clean checkout's first run) has never held
+        # that device's executables: it would compile them anew, rightly.
+        # "Never an XLA re-compile" is a warm cache's property, so build
+        # them once first, as an earlier life of the server would have —
+        # then the verdict no longer depends on what an untracked
+        # directory held when the run began.
+        make_engine(saved_model, replicas=2).shutdown()
         eng = make_engine(saved_model)
         # shut down whatever the verdict: a failed assertion that left the
         # engine's threads running made every later test of this xdist

@@ -179,11 +179,17 @@ def model():
 
 def full_forward_greedy(model, prompt, n_new):
     """Greedy tokens with no cache at all: the whole sequence through the
-    model at every step."""
+    model at every step, padded to the context — attention is causal, so
+    the logits at the last real position are those of the unpadded
+    sequence, and the eager model compiles its ops for ONE length (a
+    fresh length at each of the 72 steps cost 83 s of a cold 103 s)."""
     ids = [int(t) for t in prompt]
     for _ in range(n_new):
-        logits = model(paddle.to_tensor(np.asarray(ids, "int64")[None]))
-        ids.append(int(np.argmax(np.asarray(logits.numpy())[0, -1])))
+        padded = np.zeros((1, CAP), "int64")
+        padded[0, :len(ids)] = ids
+        logits = model(paddle.to_tensor(padded))
+        ids.append(int(np.argmax(
+            np.asarray(logits.numpy())[0, len(ids) - 1])))
     return ids[len(prompt):]
 
 

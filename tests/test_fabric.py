@@ -59,7 +59,7 @@ def _lockcheck_module():
 
 
 @pytest.fixture(autouse=True)
-def _chaos_reset():
+def _chaos_reset(fabric_threads_stopped):
     chaos.reset()
     yield
     chaos.reset()

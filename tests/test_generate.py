@@ -461,23 +461,29 @@ class TestAutoscaleIntegration:
 
         eng = make_engine(tiny_model, replicas=2)
         try:
-            prompts = mixed_prompts(4, seed=20)
+            # more rows than one worker has slots, and every worker's
+            # first incarnation hangs at its first decode step: whichever
+            # worker admits first hangs holding at most its 4 slots, and
+            # the other is then the only one that can take the rest — so
+            # each CERTAINLY hangs holding a row (a rule on worker 0 alone
+            # never fires when worker 1 wakes first and takes them all)
+            prompts = mixed_prompts(8, seed=20)
             ref = [eng.generate(p, 8, timeout=60)["tokens"]
                    for p in prompts]
-            w0 = eng._workers[0]
-            chaos.add_rule(
-                "serving.decode_step", "delay", 8.0,
-                match={"replica": w0.rid, "generation": w0.generation})
+            for w in eng._workers:
+                chaos.add_rule(
+                    "serving.decode_step", "delay", 8.0,
+                    match={"replica": w.rid, "generation": w.generation})
             wd = HealthWatchdog(eng, exec_deadline_s=0.3,
                                 beat_deadline_s=30.0, backoff_s=0.1)
             handles = [eng.submit(p, 8) for p in prompts]
-            acted = 0
             deadline = time.monotonic() + 20
-            while time.monotonic() < deadline and not acted:
-                acted = wd.poll_once()
+            while time.monotonic() < deadline and \
+                    wd.counters["watchdog_revives"] < 2:
+                wd.poll_once()
                 time.sleep(0.05)
-            assert acted, "watchdog never fired on the hung worker"
-            assert wd.counters["watchdog_revives"] >= 1
+            assert wd.counters["watchdog_revives"] == 2, \
+                "watchdog never fired on a hung worker"
             assert [h.result(60)["tokens"] for h in handles] == ref
             assert eng.metrics.failed_total == 0
         finally:

@@ -23,6 +23,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _cpu_env import cpu_subprocess_env  # noqa: E402
+from _spec_draft import noisy_draft  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.core import compile_cache as cc  # noqa: E402
@@ -75,16 +76,11 @@ def tiny_model():
 
 
 @pytest.fixture(scope="module")
-def draft_model():
-    """A genuinely DIFFERENT (smaller, differently-seeded) draft: its
-    proposals disagree with the target often, so the accept/reject
-    fallback path actually runs."""
-    paddle.seed(1)
-    cfg = GPTConfig(vocab_size=256, hidden_size=32, num_layers=1,
-                    num_heads=2, max_seq_len=64, dropout=0.0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    return model
+def draft_model(tiny_model):
+    """A genuinely DIFFERENT draft that agrees sometimes: its proposals
+    are accepted about half the time, so both the accept path and the
+    reject fallback actually run."""
+    return noisy_draft(tiny_model)
 
 
 def make_engine(model, **kw):

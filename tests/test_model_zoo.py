@@ -262,6 +262,64 @@ class TestGPTTorchParity:
             atol=3e-5)
 
 
+class TestGPTPureBlock:
+    """The one pure definition of the block (models/gpt.py: `block_qkv`,
+    `block_out`, `layer_norm`, `lm_head`, over `stacked_params()`) — what
+    the train scan and every program of the serving engine compute —
+    against the nn.Layer spelling, GPTBlock inside GPTForCausalLM."""
+
+    @pytest.mark.parametrize("tied", [True, False],
+                             ids=["tied", "untied"])
+    def test_halves_around_plain_softmax_equal_the_layers(self, tied):
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+        from paddle_tpu.models import gpt
+
+        cfg = GPTConfig(vocab_size=96, hidden_size=32, num_layers=3,
+                        num_heads=4, max_seq_len=16, dropout=0.0,
+                        tie_embeddings=tied)
+        paddle.seed(0)
+        model = GPTForCausalLM(cfg)
+        model.eval()
+        # nn.LayerNorm starts at (1, 0) and the biases at 0: seed them
+        # all, or half of what a block adds goes untested
+        rng = np.random.RandomState(1)
+        for prm in model.parameters():
+            if prm.ndim == 1:
+                prm.set_value(prm.numpy() + 0.1 * rng.standard_normal(
+                    prm.shape).astype("float32"))
+        ids = np.random.RandomState(2).randint(0, cfg.vocab_size, (2, 12))
+        want = model(paddle.to_tensor(ids.astype("int64"))).numpy()
+
+        # lazy, one array at a time: from_unrolled hands each on, and a
+        # whole second copy of the weights does not fit a chip at 1.3B
+        items = model.stacked_items()
+        assert iter(items) is items
+        p = dict(items)
+        assert set(p) == {"wte", "wpe", "lnf_w", "lnf_b",
+                          *gpt.LAYER_PARAMS} | (
+                              set() if tied else {"lm_head"})
+        S = ids.shape[1]
+        eps, H = cfg.layer_norm_eps, cfg.num_heads
+        causal = jnp.tril(jnp.ones((S, S), bool))
+
+        def body(h, lp):
+            q, k, v = gpt.block_qkv(h, lp, H, eps)       # [B, S, H, Dh]
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+            s = jnp.where(causal, s, -jnp.inf)
+            att = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+            return gpt.block_out(h, att.reshape(h.shape), lp, eps), None
+
+        x = p["wte"][ids] + p["wpe"][jnp.arange(S)]
+        h, _ = jax.lax.scan(body, x, gpt.layer_stack(p))
+        got = gpt.lm_head(p, gpt.layer_norm(h, p["lnf_w"], p["lnf_b"],
+                                            eps))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5)
+
+
 class TestEndToEndLanguageModel:
     """The user story in one test: ragged token stream -> bucketed
     DataLoader -> GPT (scan execution) -> fused LM-head CE -> compiled

@@ -23,6 +23,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _cpu_env import cpu_subprocess_env  # noqa: E402
+from _spec_draft import noisy_draft  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.inference.serving import (GenerativeEngine,  # noqa: E402
@@ -69,13 +70,11 @@ def tiny_model():
 
 
 @pytest.fixture(scope="module")
-def draft_model():
-    paddle.seed(1)
-    cfg = GPTConfig(vocab_size=256, hidden_size=32, num_layers=1,
-                    num_heads=2, max_seq_len=64, dropout=0.0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    return model
+def draft_model(tiny_model):
+    """A genuinely DIFFERENT draft that agrees sometimes: its proposals
+    are accepted about half the time, so both the accept path and the
+    reject fallback actually run."""
+    return noisy_draft(tiny_model)
 
 
 def make_engine(model, **kw):
@@ -293,6 +292,29 @@ class TestGreedyParity:
                for p in prompts]
         out = [int8w_engine.generate(p, 12, timeout=60)["tokens"]
                for p in prompts]
+        assert all(a[0] == b[0] for a, b in zip(ref, out))
+        assert match_frac(ref, out) >= 0.6
+
+    def test_weight_int8_untied_head_within_tolerance(self):
+        """An UNTIED head is one of the int8 weights (`lm_head__q`): every
+        body — the prefill and the pool pass's callers — must put its
+        logits through the dequantized head, not fall back on `wte`."""
+        paddle.seed(3)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+            max_seq_len=64, dropout=0.0, tie_embeddings=False))
+        model.eval()
+        f32 = make_engine(model)
+        int8w = make_engine(model, quantize_weights=True)
+        try:
+            prompts = mixed_prompts(6, seed=5)
+            ref = [f32.generate(p, 12, timeout=60)["tokens"]
+                   for p in prompts]
+            out = [int8w.generate(p, 12, timeout=60)["tokens"]
+                   for p in prompts]
+        finally:
+            f32.shutdown()
+            int8w.shutdown()
         assert all(a[0] == b[0] for a, b in zip(ref, out))
         assert match_frac(ref, out) >= 0.6
 
