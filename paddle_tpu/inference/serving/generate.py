@@ -14,7 +14,8 @@ pool, re-cut for the XLA compilation contract):
     prefill[S]  (params, pool_k, pool_v, slot, ids[1,S], len) ->
                 (first_token, pool_k', pool_v')
     decode[b]   (params, pool_k, pool_v, slots[b], tokens[b],
-                 lengths[b]) -> (next_tokens[b], pool_k', pool_v')
+                 lengths[b]) -> (next_tokens[b], lengths[b] + 1,
+                                 pool_k', pool_v')
 
   Every program is memoized per (family, bucket) and pre-compiled
   through the persistent compile cache (core/compile_cache), so a warm
@@ -58,6 +59,23 @@ pool, re-cut for the XLA compilation contract):
   requests into free slots (prefill happens right then, on the worker
   thread) and retires finished rows (EOS/max_tokens) without ever
   stalling the rest of the batch.
+
+- **The step-to-step dependency stays on the device.** A decode program
+  returns its rows advanced — next token, key, length + 1 — in the shapes
+  it took them, and plain decode feeds step n+1 from step n's outputs
+  (`_DeviceRows`): in the steady state a pass of the worker is LAUNCH the
+  next step, BLOCK on the oldest unread one, EMIT it (`_STEPS_AHEAD`), so
+  the device goes from one step into the next while the host reads and
+  streams the one before. What only the host knows — slots, temperature,
+  top-k, top-p — is staged when the row set changes and not otherwise; a
+  row-set change reads everything launched first and stages the seven
+  arrays once. The host's row.length / row.key / req.tokens are current
+  as of the last step READ; whoever takes a row elsewhere (export,
+  migration) settles the launched steps first, and a requeue replays
+  from the tokens emitted. An EOS is learned one step late: the
+  overshoot step wrote past the row's end in its own slot, its token is
+  discarded and it counts as no row. The speculative loop, whose host
+  decides in the middle of every step, stays closed (`_spec_step`).
 
 - **Streaming.** Tokens are emitted per step onto each request's
   stream queue (GenerateHandle iterates them; server.py chunks them
@@ -389,14 +407,17 @@ def _decode_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
                  topps, keys, scratch, num_heads, eps):
     """One fixed-shape decode step: each row's pending token through the
     pool pass, plus the sampling head — one key split per row, greedy
-    rows (temp 0) stay bitwise-identical to the argmax-only program."""
+    rows (temp 0) stay bitwise-identical to the argmax-only program.
+    Returns the rows advanced — next token, key, length + 1 — in the
+    shapes it took them, so the next step of the same rows takes its
+    inputs from this one's outputs without a trip through the host."""
     p = _kvq.dequant_params(p)
     h, buf_k, buf_v = _pool_pass(p, buf_k, buf_v, slots, tokens, lengths,
                                  scratch, num_heads, eps)
     logits = _gpt.lm_head(p, h)
     keys, subs = _split_keys(keys)
     nxt = jax.vmap(_sample_token)(logits, temps, topks, topps, subs)
-    return nxt, keys, buf_k, buf_v
+    return nxt, keys, lengths + 1, buf_k, buf_v
 
 
 def _propose_body(p, buf_k, buf_v, slots, tokens, lengths, k, scratch,
@@ -602,6 +623,59 @@ class _Row:
         self.key = key if key is not None else np.zeros(2, np.uint32)
 
 
+# Decode steps the worker launches beyond the oldest one it has not read.
+# One: with step n+1 queued behind it the device goes from step n straight
+# into n+1 while the host reads n, emits it and launches n+2 — 1 ms of
+# host work and 2 ms of late notice against a program of 4.1 ms at
+# gpt3-medium on a v5e (PERF.md §5: the steady pass is the device's), so
+# one step ahead keeps the device fed. A second would only put every EOS
+# and every stream another step behind.
+_STEPS_AHEAD = 1
+
+
+class _Launched:
+    """One decode step on the device's queue that the host has not read:
+    its rows in launch order, what they produce (device futures) and what
+    the step's counters need once the rows are known to be real."""
+
+    __slots__ = ("rows", "prog_key", "kv_reads", "ahead", "nxt", "nkeys")
+
+    def __init__(self, rows, prog_key, kv_reads, ahead, nxt, nkeys):
+        self.rows = rows
+        self.prog_key = prog_key      # (device, "decode", cap, bucket)
+        self.kv_reads = kv_reads      # per row: pool positions its read copies
+        self.ahead = ahead            # an earlier step was still unread
+        self.nxt = nxt
+        self.nkeys = nkeys
+
+
+class _DeviceRows:
+    """A class's plain-decode state where the device keeps it. `rows[i]`
+    is index i of every array, padded to the batch bucket with scratch rows.
+    `fixed` — slots, temperatures, top-k, top-p — is what only the host
+    knows and changes only with the row set; `toks`, `lens`, `keys` are
+    each row's pending token, length and PRNG key as the last launched
+    step returned them (futures while it runs). `unread` holds the
+    launched steps the host has not read, oldest first: the host's
+    row.length / row.key / req.tokens are current as of the last step
+    READ, `len(unread)` steps behind the device. Owned by the worker
+    thread; dies with the class state."""
+
+    __slots__ = ("rows", "fixed", "toks", "lens", "keys", "unread")
+
+    def __init__(self, rows, fixed, toks, lens, keys):
+        self.rows = rows
+        self.fixed = fixed
+        self.toks = toks
+        self.lens = lens
+        self.keys = keys
+        self.unread: "deque[_Launched]" = deque()
+
+    def holds(self, rows) -> bool:
+        return len(rows) == len(self.rows) and all(
+            a is b for a, b in zip(rows, self.rows))
+
+
 @_shared_state("free", "rows", "pcache", "pc_free")
 class _ClassState:
     """Per-worker, per-capacity-class device state: the pool buffer
@@ -612,10 +686,13 @@ class _ClassState:
     model's K/V for the same slots; with prefix caching the pool is
     allocated with ``pc_slots`` extra rows addressed by the LRU
     ``pcache`` — cache state dies with the worker generation exactly
-    like the buffers (a fresh _ClassState is allocated on revive)."""
+    like the buffers (a fresh _ClassState is allocated on revive).
+    `dev` is the plain-decode loop's device-held rows (None until its
+    first step, and always with a draft model)."""
 
     __slots__ = ("cap", "n_slots", "buf_k", "buf_v", "free", "rows",
-                 "pc_slots", "pcache", "pc_free", "dbuf_k", "dbuf_v")
+                 "pc_slots", "pcache", "pc_free", "dbuf_k", "dbuf_v",
+                 "dev")
 
     def __init__(self, cap: int, n_slots: int, buf_k, buf_v,
                  pc_slots: int = 0, dbuf_k=None, dbuf_v=None):
@@ -634,6 +711,7 @@ class _ClassState:
             range(n_slots + 1, n_slots + 1 + self.pc_slots))
         self.dbuf_k = dbuf_k
         self.dbuf_v = dbuf_v
+        self.dev: Optional[_DeviceRows] = None
 
 
 # ===================================================================
@@ -662,10 +740,12 @@ def aggregate_snapshot() -> Optional[dict]:
                 out[k] = max(out[k], v)
             elif k.startswith("kv_positions_") or not (
                     k.startswith(("ttft_", "latency_", "kv_", "avg_"))
-                    or k.endswith("_rate")):
+                    or k.endswith(("_rate", "_share"))):
                 out[k] = out[k] + v
     out["kv_read_share"] = _sm.rate(out["kv_positions_read_total"],
                                     out["kv_positions_capacity_total"])
+    out["launch_ahead_share"] = _sm.rate(out["steps_ahead_total"],
+                                         out["steps_total"])
     out["engines"] = len(snaps)
     return out
 
@@ -676,8 +756,9 @@ _REGISTRY = _sm.EngineRegistry("generative", aggregate_snapshot)
 @_shared_state("requests_total", "completed_total", "failed_total",
                "shed_total", "rejected_total", "requeues_total",
                "tokens_out_total", "prompt_tokens_total",
-               "prefills_total", "steps_total", "step_rows_total",
-               "step_padded_rows_total", "occupancy_hist", "_ttft",
+               "prefills_total", "steps_total", "steps_ahead_total",
+               "step_rows_total", "step_padded_rows_total",
+               "occupancy_hist", "_ttft",
                "_latency", "_token_stamps", "draft_steps_total",
                "spec_steps_total", "spec_proposed_total",
                "spec_accepted_total", "prefix_hits_total",
@@ -706,6 +787,7 @@ class GenerativeMetrics:
         self.prompt_tokens_total = 0
         self.prefills_total = 0
         self.steps_total = 0
+        self.steps_ahead_total = 0        # launched, an earlier one unread
         self.step_rows_total = 0          # real rows over all steps
         self.step_padded_rows_total = 0   # pad rows added by batch bucket
         self.kv_positions_read_total = 0      # positions the steps read
@@ -761,12 +843,14 @@ class GenerativeMetrics:
             self.prompt_tokens_total += prompt_tokens
 
     def on_step(self, rows: int, bucket: int, kv_read: int = 0,
-                kv_capacity: int = 0):
+                kv_capacity: int = 0, ahead: bool = False):
         """One decode step of `rows` real rows in a batch bucket;
         `kv_read` of the rows' `kv_capacity` (rows x class cap) pool
-        positions were read by the step's attention."""
+        positions were read by the step's attention. `ahead`: it was
+        launched while an earlier step was still unread."""
         with self._lock:
             self.steps_total += 1
+            self.steps_ahead_total += bool(ahead)
             self.step_rows_total += rows
             self.step_padded_rows_total += max(bucket - rows, 0)
             self.kv_positions_read_total += int(kv_read)
@@ -875,6 +959,9 @@ class GenerativeMetrics:
                 "prompt_tokens_total": self.prompt_tokens_total,
                 "prefills_total": self.prefills_total,
                 "steps_total": self.steps_total,
+                "steps_ahead_total": self.steps_ahead_total,
+                "launch_ahead_share": _sm.rate(self.steps_ahead_total,
+                                               self.steps_total),
                 "step_rows_total": self.step_rows_total,
                 "step_padded_rows_total": self.step_padded_rows_total,
                 "kv_positions_read_total": self.kv_positions_read_total,
@@ -941,6 +1028,12 @@ class GenerativeMetrics:
                s["tokens_out_total"], "tokens generated")
         metric("paddle_generate_steps_total", "counter", s["steps_total"],
                "decode steps executed")
+        metric("paddle_generate_steps_ahead_total", "counter",
+               s["steps_ahead_total"],
+               "decode steps launched while an earlier one was unread")
+        metric("paddle_generate_launch_ahead_share", "gauge",
+               s["launch_ahead_share"],
+               "steps launched ahead / decode steps (lifetime)")
         metric("paddle_generate_prefills_total", "counter",
                s["prefills_total"], "prefill calls executed")
         metric("paddle_generate_queue_depth", "gauge", s["queue_depth"],
@@ -1031,6 +1124,14 @@ class GenerativeEngine:
     extra program family per class — default is one class at
     ``max_context``, which keeps the program inventory at exactly the
     prefill bucket ladder plus one decode program per batch bucket).
+
+    Each worker thread runs ``_worker_loop``: admit, prefill what was
+    admitted, then one decode pass a capacity class. Without a draft
+    model that pass is ``_decode_step`` — launch the next step from the
+    rows the device holds, then read and emit the step before it; with
+    one it is ``_spec_step``, the closed stage / launch / read / emit
+    loop a host-side accept needs. Which one is decided by what the
+    engine was built with, never by an option.
 
     ``kv_dtype="int8"`` quantizes the KV pool (quantization/kv.py):
     ~4x the decode slots and prefix-cache rows per byte, with quantize-
@@ -1611,7 +1712,7 @@ class GenerativeEngine:
                 n += 1
             for b in self._batch_buckets:
                 with _cc.donated_cpu_guard(self._donate):
-                    nxt, _, cs.buf_k, cs.buf_v = self._program(
+                    nxt, _, _, cs.buf_k, cs.buf_v = self._program(
                         "decode", cap, b)(
                             p, cs.buf_k, cs.buf_v,
                             put(np.full((b,), scratch, np.int32)),
@@ -2185,7 +2286,16 @@ class GenerativeEngine:
                 self._pc_index.pop((w.rid, cap), None)
         for cap, old in list(state.items()):
             # the poisoned pools are dropped before the fresh ones are
-            # made: never two pool pairs beside each other on the device
+            # made: never two pool pairs beside each other on the device.
+            # A step launched ahead holds them until it ends, so it is
+            # waited for (and never read: a requeue replays from the
+            # tokens emitted); where it failed, the wait raises what it
+            # raised, and the pools go all the same
+            try:
+                jax.block_until_ready((old.buf_k, old.buf_v))
+            except Exception:  # noqa: BLE001
+                pass
+            old.dev = None
             old.buf_k = old.buf_v = old.dbuf_k = old.dbuf_v = None
             state[cap] = self._alloc_class(cap, w.device)
         self._requeue(stuck)
@@ -2243,14 +2353,7 @@ class GenerativeEngine:
         if _tr.enabled():
             args = {"replica": w.rid, "bucket": S, "prompt_tokens": P,
                     "cap": cs.cap, "prefix_hit": hitF or 0}
-        with self._cv:
-            owned = w.generation == gen
-            if owned:
-                w.busy_since = time.monotonic()
-                if w.thread is threading.current_thread():
-                    w.compiling = any(pk not in self._warmed
-                                      for pk in prog_keys)
-        if not owned:
+        if not self._busy(w, gen, prog_keys):
             return
         try:
             with _tr.span("generate.prefill", "serving", args,
@@ -2316,13 +2419,11 @@ class GenerativeEngine:
                                 put(np.int32(crow)))
                         if evict:
                             self.metrics.on_prefix_evict()
+                kcar.copy_to_host_async()
                 tok = int(tok)
                 kcar = np.asarray(kcar)
         finally:
-            with self._cv:
-                if w.generation == gen:
-                    w.busy_since = None
-                    w.compiling = False
+            self._idle(w, gen)
         with self._cv:
             for pk in prog_keys:
                 self._warmed.add(pk)
@@ -2356,28 +2457,152 @@ class GenerativeEngine:
                 self._finish(w, gen, cs, slot, req, "handoff",
                              extra={"handoff": _ho.to_b64(raw)})
 
+    def _busy(self, w: ReplicaSlot, gen: int, prog_keys: list) -> bool:
+        """Mark worker `w` busy on the device from now (the watchdog's
+        clock), compiling where a program of `prog_keys` was never run.
+        False where its generation was superseded: the caller returns."""
+        with self._cv:
+            if w.generation != gen:
+                return False
+            w.busy_since = time.monotonic()
+            if w.thread is threading.current_thread():
+                w.compiling = any(pk not in self._warmed
+                                  for pk in prog_keys)
+        return True
+
+    def _idle(self, w: ReplicaSlot, gen: int, batches: int = 0) -> None:
+        with self._cv:
+            if w.generation == gen:
+                w.busy_since = None
+                w.compiling = False
+            w.batches += batches
+
     def _decode_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                      phase: Optional[dict] = None) -> None:
-        """One batched decode step of class `cs`. `phase` is the worker
-        loop's {"iter", "rid"} (None with tracing off): the args of the
-        spans that partition this thread's time — `.stage` (the host
-        arrays' device_puts), `.launch` (the program call until it
-        returns), `.wait` (the blocking read of the results), all three
-        inside `generate.decode_step`, then `generate.emit`."""
+        """One pass of plain decode over class `cs`: launch the next step
+        of the live rows, THEN block on the oldest step not yet read and
+        emit it. The step-to-step dependency stays on the device
+        (`_DeviceRows`): while the row set is what the last step ran, the
+        launch takes each row's token, key and length from that step's
+        outputs and stages nothing; when it changed (an admission, a
+        finished or migrated row) every launched step is read first and
+        the seven arrays are staged anew from the rows — the one restage
+        a row-set change costs. The host learns of an EOS one step late:
+        that row's overshoot step wrote past its end in its own slot (the
+        slot's next owner is prefilled behind it on the device's queue),
+        its token is discarded and it counts as no row. A finish by
+        length is known ahead: where every row's last token is already on
+        the queue, nothing is launched.
+
+        `phase` is the worker loop's {"iter", "rid"} (None with tracing
+        off): the args of the spans that partition this thread's time —
+        one `generate.decode_step` a launched step with `.stage` (the
+        restage's device_puts: nothing on a step ahead), `.launch` (the
+        program call until it returns) and `.wait` (the blocking read of
+        the oldest unread step, where one is due), then `generate.emit`.
+        A read with no launch is a bare `.wait` + `generate.emit`."""
         with self._cv:
             if w.generation != gen:
                 return
             rows = [cs.rows[s] for s in sorted(cs.rows)]
         if not rows:
             return
+        devk = self._device_key(w.device)
+        dev = stale = cs.dev
+        if dev is not None and not dev.holds(rows):
+            if not self._settle(w, gen, cs, phase):
+                return
+            # `stale` keeps the old rows' device arrays until the new
+            # launch is on the queue: freeing device buffers takes the
+            # host time the device would wait for
+            cs.dev = dev = None
+            with self._cv:     # the steps just read may have ended rows
+                rows = [cs.rows[s] for s in sorted(cs.rows)]
+            if not rows:
+                return
         n = len(rows)
         bucket = bucket_for(n, self._batch_buckets)
-        scratch = cs.n_slots    # the +1 row: padding lands there
-        spec = self._spec
-        k = self._spec_k
+        prog_key = (devk, "decode", cs.cap, bucket)
+        behind = len(dev.unread) if dev is not None else 0
 
         # the rows are read here, before the hang-injection point below: a
         # worker that unwedges must not read rows its replacement owns
+        with self._cv:
+            due = [len(r.req.tokens) + behind < r.req.max_new
+                   for r in rows]
+            fresh = None if dev is not None else self._row_arrays(
+                rows, bucket, cs.n_slots)
+            positions = [r.length + behind for r in rows]
+        if not any(due):
+            # every row's last token is on the queue already
+            self._read_oldest(w, gen, cs, phase)
+            return
+        # pool positions the read copies for each row: whole blocks up to
+        # its position under the kernel, every position under the gather
+        plan = self._kv_plan("decode", cs.cap)
+        kv_reads = [cs.cap if plan is None else
+                    plan.positions_read(x, cs.cap) for x in positions]
+        args = None
+        if _tr.enabled():
+            args = {"replica": w.rid, "rows": n, "bucket": bucket,
+                    "cap": cs.cap, "kv_read": sum(kv_reads), "spec_k": 0,
+                    "ahead": int(behind > 0),
+                    "staged": 0 if fresh is None else len(fresh),
+                    "traces": [r.req.ctx.trace_id for r in rows
+                               if r.req.ctx is not None]}
+        if not self._busy(w, gen, [prog_key]):
+            return
+        read = None
+        try:
+            # hang/raise injection for the watchdog + requeue ladder:
+            # a chaos `delay` rule here wedges this worker mid-decode
+            # exactly like a stuck device; generation rides the context
+            # so a rule can be scoped to ONE worker incarnation
+            _chaos.hit("serving.decode_step", replica=w.rid,
+                       generation=gen)
+            with _tr.span("generate.decode_step", "serving", args,
+                          parent=rows[0].req.ctx), \
+                    _cc.donated_cpu_guard(self._donate):
+                with _tr.span("generate.decode_step.stage", "serving",
+                              phase):
+                    if fresh is not None:
+                        slots, toks, lens, temps, topks, topps, keys = \
+                            jax.device_put(fresh, w.device)
+                        cs.dev = dev = _DeviceRows(
+                            rows, (slots, temps, topks, topps), toks,
+                            lens, keys)
+                with _tr.span("generate.decode_step.launch", "serving",
+                              phase):
+                    slots, temps, topks, topps = dev.fixed
+                    nxt, nkeys, dev.lens, cs.buf_k, cs.buf_v = \
+                        self._program("decode", cs.cap, bucket)(
+                            self._params_for(w.device), cs.buf_k,
+                            cs.buf_v, slots, dev.toks, dev.lens, temps,
+                            topks, topps, dev.keys)
+                    dev.toks, dev.keys = nxt, nkeys
+                    # on their way to the host as soon as the step ends,
+                    # not when the host comes to ask
+                    nxt.copy_to_host_async()
+                    nkeys.copy_to_host_async()
+                    dev.unread.append(_Launched(
+                        rows, prog_key, kv_reads, behind > 0, nxt, nkeys))
+                    del stale
+                with _tr.span("generate.decode_step.wait", "serving",
+                              phase):
+                    if len(dev.unread) > _STEPS_AHEAD:
+                        read = self._wait_oldest(dev)
+        finally:
+            self._idle(w, gen, batches=1)
+        if read is not None:
+            with _tr.span("generate.emit", "serving", phase):
+                self._emit_read(w, gen, cs, *read)
+
+    @staticmethod
+    def _row_arrays(rows: list, bucket: int, scratch: int) -> list:
+        """The seven host arrays of a decode step over `rows`, padded to
+        `bucket` with rows that target the scratch slot at length 0:
+        slots, pending tokens, lengths, temperatures, top-k, top-p,
+        keys — the decode programs' own order (caller holds _cv)."""
         slots = np.full((bucket,), scratch, np.int32)
         toks = np.zeros((bucket,), np.int32)
         lens = np.zeros((bucket,), np.int32)
@@ -2393,43 +2618,88 @@ class GenerativeEngine:
             topks[i] = row.req.top_k
             topps[i] = row.req.top_p
             keys[i] = row.key
+        return [slots, toks, lens, temps, topks, topps, keys]
+
+    @staticmethod
+    def _wait_oldest(dev: _DeviceRows) -> tuple:
+        """Block on the oldest launched step: (step, tokens, keys)."""
+        step = dev.unread.popleft()
+        return step, np.asarray(step.nxt), np.asarray(step.nkeys)
+
+    def _emit_read(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                   step: _Launched, nxt, nkeys) -> bool:
+        return self._emit_step(
+            w, gen, cs, step.rows, [step.prog_key], step.prog_key[3],
+            step.kv_reads, [[int(t)] for t in nxt[:len(step.rows)]],
+            nkeys, step.ahead)
+
+    def _read_oldest(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                     phase: Optional[dict]) -> bool:
+        """Read and emit the oldest launched step with no launch beside
+        it. False where the worker was superseded meanwhile."""
+        if not self._busy(w, gen, [cs.dev.unread[0].prog_key]):
+            return False
+        try:
+            with _tr.span("generate.decode_step.wait", "serving", phase):
+                read = self._wait_oldest(cs.dev)
+        finally:
+            self._idle(w, gen)
+        with _tr.span("generate.emit", "serving", phase):
+            return self._emit_read(w, gen, cs, *read)
+
+    def _settle(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                phase: Optional[dict] = None) -> bool:
+        """Read and emit every launched step of class `cs`, oldest first:
+        after it row.length / row.key / req.tokens are what the device
+        holds. Whoever reads a row's host state to move it elsewhere, or
+        stages the rows anew, settles first. False where the worker was
+        superseded meanwhile."""
+        while cs.dev is not None and cs.dev.unread:
+            if not self._read_oldest(w, gen, cs, phase):
+                return False
+        return True
+
+    def _spec_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                   phase: Optional[dict] = None) -> None:
+        """One speculative step of class `cs`: a fused k-step draft burst,
+        the host's look at its proposals, one target verify pass. The
+        host decides in the middle of every step, so this loop stays
+        closed — stage, launch, read, emit, and only then the next step's
+        arrays. Spans as in `_decode_step`, `.stage` / `.launch` /
+        `.wait` twice each."""
+        with self._cv:
+            if w.generation != gen:
+                return
+            rows = [cs.rows[s] for s in sorted(cs.rows)]
+            if not rows:
+                return
+            n = len(rows)
+            bucket = bucket_for(n, self._batch_buckets)
+            # the rows are read here, before the hang-injection point
+            # below: a worker that unwedges must not read rows its
+            # replacement owns
+            fresh = self._row_arrays(rows, bucket, cs.n_slots)
+        toks = fresh[1]
+        k = self._spec_k
 
         def put(a):
             return jax.device_put(a, w.device)
 
         devk = self._device_key(w.device)
-        if spec:
-            prog_keys = [(devk, "dpropose", cs.cap, bucket),
-                         (devk, "verify", cs.cap, bucket)]
-        else:
-            prog_keys = [(devk, "decode", cs.cap, bucket)]
-        # pool positions the target's read copies for the real rows:
-        # whole blocks up to each row's position under the kernel, every
-        # position under the gather
-        plan = self._kv_plan(prog_keys[-1][1], cs.cap)
-        kv_read = n * cs.cap if plan is None else sum(
-            plan.positions_read(x, cs.cap) for x in lens[:n])
+        prog_keys = [(devk, "dpropose", cs.cap, bucket),
+                     (devk, "verify", cs.cap, bucket)]
+        # verify reads every position of the rows (the gather)
+        kv_reads = [cs.cap] * n
         args = None
         if _tr.enabled():
             args = {"replica": w.rid, "rows": n, "bucket": bucket,
-                    "cap": cs.cap, "kv_read": kv_read,
-                    "spec_k": k if spec else 0,
+                    "cap": cs.cap, "kv_read": n * cs.cap, "spec_k": k,
+                    "ahead": 0, "staged": len(fresh) + 1,
                     "traces": [r.req.ctx.trace_id for r in rows
                                if r.req.ctx is not None]}
-        with self._cv:
-            owned = w.generation == gen
-            if owned:
-                w.busy_since = time.monotonic()
-                if w.thread is threading.current_thread():
-                    w.compiling = any(pk not in self._warmed
-                                      for pk in prog_keys)
-        if not owned:
+        if not self._busy(w, gen, prog_keys):
             return
         try:
-            # hang/raise injection for the watchdog + requeue ladder:
-            # a chaos `delay` rule here wedges this worker mid-decode
-            # exactly like a stuck device; generation rides the context
-            # so a rule can be scoped to ONE worker incarnation
             _chaos.hit("serving.decode_step", replica=w.rid,
                        generation=gen)
             with _tr.span("generate.decode_step", "serving", args,
@@ -2437,79 +2707,41 @@ class GenerativeEngine:
                     _cc.donated_cpu_guard(self._donate):
                 with _tr.span("generate.decode_step.stage", "serving",
                               phase):
-                    staged = [put(slots), put(toks), put(lens),
-                              put(temps), put(topks), put(topps),
-                              put(keys)]
+                    staged = [put(a) for a in fresh]
                 # `staged` is dropped inside the last launch: its device
                 # buffers are freed while the program runs, not between
-                # two steps
-                if spec:
-                    # ONE fused k-step draft burst; the draft pool
-                    # advances through all k inputs so a full accept
-                    # finds every cached position next round
-                    with _tr.span("generate.decode_step.launch",
-                                  "serving", phase):
-                        props, cs.dbuf_k, cs.dbuf_v = self._program(
-                            "dpropose", cs.cap, bucket, k)(
-                                self._draft_params_for(w.device),
-                                cs.dbuf_k, cs.dbuf_v, *staged[:3])
-                    with _tr.span("generate.decode_step.wait",
-                                  "serving", phase):
-                        props = np.asarray(props)      # [bucket, k]
-                    with _tr.span("generate.decode_step.stage",
-                                  "serving", phase):
-                        staged[1] = put(np.concatenate(
-                            [toks[:, None], props[:, :k - 1]],
-                            axis=1).astype(np.int32))
-                    with _tr.span("generate.decode_step.launch",
-                                  "serving", phase):
-                        ys, khist, cs.buf_k, cs.buf_v = self._program(
-                            "verify", cs.cap, bucket, k)(
-                                self._params_for(w.device),
-                                cs.buf_k, cs.buf_v, *staged)
-                        del staged
-                    with _tr.span("generate.decode_step.wait",
-                                  "serving", phase):
-                        ys = np.asarray(ys)            # [bucket, k]
-                        khist = np.asarray(khist)      # [bucket, k, 2]
-                else:
-                    with _tr.span("generate.decode_step.launch",
-                                  "serving", phase):
-                        nxt, nkeys, cs.buf_k, cs.buf_v = self._program(
-                            "decode", cs.cap, bucket)(
-                                self._params_for(w.device),
-                                cs.buf_k, cs.buf_v, *staged)
-                        del staged
-                    with _tr.span("generate.decode_step.wait",
-                                  "serving", phase):
-                        nxt = np.asarray(nxt)
-                        nkeys = np.asarray(nkeys)
+                # two steps.
+                # ONE fused k-step draft burst; the draft pool advances
+                # through all k inputs so a full accept finds every
+                # cached position next round
+                with _tr.span("generate.decode_step.launch", "serving",
+                              phase):
+                    props, cs.dbuf_k, cs.dbuf_v = self._program(
+                        "dpropose", cs.cap, bucket, k)(
+                            self._draft_params_for(w.device),
+                            cs.dbuf_k, cs.dbuf_v, *staged[:3])
+                with _tr.span("generate.decode_step.wait", "serving",
+                              phase):
+                    props = np.asarray(props)      # [bucket, k]
+                with _tr.span("generate.decode_step.stage", "serving",
+                              phase):
+                    staged[1] = put(np.concatenate(
+                        [toks[:, None], props[:, :k - 1]],
+                        axis=1).astype(np.int32))
+                with _tr.span("generate.decode_step.launch", "serving",
+                              phase):
+                    ys, khist, cs.buf_k, cs.buf_v = self._program(
+                        "verify", cs.cap, bucket, k)(
+                            self._params_for(w.device),
+                            cs.buf_k, cs.buf_v, *staged)
+                    del staged
+                with _tr.span("generate.decode_step.wait", "serving",
+                              phase):
+                    ys = np.asarray(ys)            # [bucket, k]
+                    khist = np.asarray(khist)      # [bucket, k, 2]
         finally:
-            with self._cv:
-                if w.generation == gen:
-                    w.busy_since = None
-                    w.compiling = False
-                w.batches += 1
+            self._idle(w, gen, batches=1)
         with _tr.span("generate.emit", "serving", phase):
-            self._emit_step(w, gen, cs, rows, prog_keys, bucket, kv_read,
-                            (props, ys, khist) if spec else (nxt, nkeys))
-
-    def _emit_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
-                   rows: list, prog_keys: list, bucket: int,
-                   kv_read: int, results: tuple) -> None:
-        """What follows a decode step's read on the worker thread: the
-        rows' bookkeeping under the lock, every accepted token to its
-        stream, finished rows out of their slots."""
-        n = len(rows)
-        spec = self._spec
-        k = self._spec_k
-        with self._cv:
-            for pk in prog_keys:
-                self._warmed.add(pk)
-        self.metrics.on_step(n, bucket, kv_read, n * cs.cap)
-        finished = []
-        if spec:
-            props, ys, khist = results
             # accept the longest agreed prefix per row: ys[i, j] is
             # the target's OWN token at position j (same key chain as
             # plain decode), valid while every earlier draft proposal
@@ -2524,43 +2756,54 @@ class GenerativeEngine:
             self.metrics.on_spec_step(
                 proposed=n * (k - 1),
                 accepted=sum(m - 1 for m in ms))
-            with self._cv:
-                if w.generation != gen:
-                    return
-                for i, row in enumerate(rows):
-                    row.length += ms[i]
-                    row.key = khist[i, ms[i] - 1].copy()
-                self._update_liveness_locked(w, cs)
-            for i, row in enumerate(rows):
-                done_row = False
-                for j in range(ms[i]):
-                    status = self._emit(w, gen, row.req, int(ys[i, j]))
-                    if status == "dead":
-                        return
-                    if status == "done":
-                        done_row = True
-                        break
-                if done_row:
-                    finished.append(row)
-        else:
-            nxt, nkeys = results
-            with self._cv:
-                if w.generation != gen:
-                    return
-                for i, row in enumerate(rows):
-                    row.length += 1
-                    row.key = nkeys[i].copy()
-                self._update_liveness_locked(w, cs)
-            for i, row in enumerate(rows):
-                status = self._emit(w, gen, row.req, int(nxt[i]))
+            self._emit_step(
+                w, gen, cs, rows, prog_keys, bucket, kv_reads,
+                [[int(t) for t in ys[i, :m]] for i, m in enumerate(ms)],
+                [khist[i, m - 1] for i, m in enumerate(ms)])
+
+    def _emit_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                   rows: list, prog_keys: list, bucket: int,
+                   kv_reads: list, toks: list, keys,
+                   ahead: bool = False) -> bool:
+        """What follows a decode step's read on the worker thread: row i
+        takes the tokens toks[i] (one, or a speculative burst's accepted
+        prefix) and the key keys[i] that follows them — the rows'
+        bookkeeping under the lock, every token to its stream, finished
+        rows out of their slots. A row that left its slot before this
+        step was read (it ended on an earlier step's EOS while this one
+        was on the queue) was launched in vain: its token is discarded
+        and the step's counters leave it out. False where the worker was
+        superseded."""
+        with self._cv:
+            for pk in prog_keys:
+                self._warmed.add(pk)
+            if w.generation != gen:
+                return False
+            live = [i for i, row in enumerate(rows)
+                    if cs.rows.get(row.slot) is row]
+            for i in live:
+                rows[i].length += len(toks[i])
+                rows[i].key = np.array(keys[i], np.uint32)
+            self._update_liveness_locked(w, cs)
+        if live:
+            self.metrics.on_step(len(live), bucket,
+                                 sum(kv_reads[i] for i in live),
+                                 len(live) * cs.cap, ahead)
+        finished = []
+        for i in live:
+            row = rows[i]
+            for tok in toks[i]:
+                status = self._emit(w, gen, row.req, tok)
                 if status == "dead":
-                    return
+                    return False
                 if status == "done":
                     finished.append(row)
+                    break
         for row in finished:
             self._finish(w, gen, cs, row.slot, row.req,
                          "eos" if row.req.eos is not None and
                          row.req.tokens[-1] == row.req.eos else "length")
+        return True
 
     # ------------------------------------------------- KV-slot handoff --
     def _export_row(self, w: ReplicaSlot, gen: int, cs: _ClassState,
@@ -2571,7 +2814,10 @@ class GenerativeEngine:
         the metadata that makes the continuation bitwise — position,
         emitted tokens, the PRNG key-chain cursor, sampling params and
         prefix-cache lineage. Runs the warmed kvget program on the
-        owning worker thread, OUTSIDE the engine lock. None when the
+        owning worker thread, OUTSIDE the engine lock. The row's host
+        state must be what the device holds: a caller that takes a row
+        out of the decode loop has settled its launched steps
+        (`_settle`); a row just prefilled is in none. None when the
         row vanished under us (supersede race)."""
         from ..fabric import handoff as _ho
 
@@ -2783,14 +3029,7 @@ class GenerativeEngine:
         if _tr.enabled():
             args = {"replica": w.rid, "cap": cs.cap, "length": length,
                     "tokens": len(toks)}
-        with self._cv:
-            owned = w.generation == gen
-            if owned:
-                w.busy_since = time.monotonic()
-                if w.thread is threading.current_thread():
-                    w.compiling = any(pk not in self._warmed
-                                      for pk in prog_keys)
-        if not owned:
+        if not self._busy(w, gen, prog_keys):
             return
         try:
             with _tr.span("generate.kv_import", "serving", args,
@@ -2850,10 +3089,7 @@ class GenerativeEngine:
                         if evict:
                             self.metrics.on_prefix_evict()
         finally:
-            with self._cv:
-                if w.generation == gen:
-                    w.busy_since = None
-                    w.compiling = False
+            self._idle(w, gen)
         with self._cv:
             for pk in prog_keys:
                 self._warmed.add(pk)
@@ -2884,7 +3120,8 @@ class GenerativeEngine:
                          req.tokens[-1] == req.eos else "length")
 
     def _migrate_rows(self, w: ReplicaSlot, gen: int,
-                      state: Dict[int, _ClassState]) -> None:
+                      state: Dict[int, _ClassState],
+                      phase: Optional[dict] = None) -> None:
         """Drain-with-migration sweep: export every in-flight STREAMED
         row (the client is mid-stream — finishing locally would hold
         the drain hostage to the longest decode) and end each local
@@ -2897,6 +3134,10 @@ class GenerativeEngine:
         from ..fabric import handoff as _ho
 
         for cs in state.values():
+            # a row leaves with the state the device holds: every launched
+            # step is read and emitted before any row is exported
+            if not self._settle(w, gen, cs, phase):
+                return
             with self._cv:
                 if w.generation != gen:
                     return
@@ -2951,8 +3192,11 @@ class GenerativeEngine:
             cap: self._alloc_class(cap, w.device) for cap in self._caps}
         # with tracing on, this thread's time is a partition of spans
         # (admit | prefill | decode_step{stage, launch, wait} | emit |
-        # idle); those of one pass carry the same `iter`, so a reader
-        # adds up a pass's phases without guessing from times
+        # idle, and a bare decode_step.wait where a pass reads a step and
+        # launches none); those of one pass carry the same `iter`, so a
+        # reader adds up a pass's phases without guessing from times. A
+        # class's launched, unread steps live in its state (`cs.dev`) from
+        # one pass to the next and die with it
         it = 0
         while True:
             it += 1
@@ -2983,7 +3227,7 @@ class GenerativeEngine:
                     migrating = self._migrate_streams and \
                         w.generation == gen
                 if migrating:
-                    self._migrate_rows(w, gen, state)
+                    self._migrate_rows(w, gen, state, phase)
                 active = sum(len(cs.rows) for cs in state.values())
                 if active == 0:
                     with self._cv:
@@ -3007,9 +3251,10 @@ class GenerativeEngine:
                         w, gen, state,
                         ServingError(503, "server shutting down"))
                     continue
+                step = self._spec_step if self._spec else self._decode_step
                 for cs in state.values():
                     if cs.rows:
-                        self._decode_step(w, gen, cs, phase)
+                        step(w, gen, cs, phase)
             except Exception as e:  # noqa: BLE001 — last line of
                 # defense: the worker thread must NEVER die (its slots
                 # would leak and the queue would starve); requeue the

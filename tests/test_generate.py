@@ -344,6 +344,12 @@ print(json.dumps({"warm": eng.warmup_report,
 
 class TestElasticity:
     def test_add_replica_warm_before_admission(self, tiny_model):
+        # the added replica lands on a second device, whose executables a
+        # cold persistent cache has never held: build them once first, as
+        # an earlier life of the server would have (test_autoscale.py's
+        # twin does the same), so the verdict does not hang on what an
+        # untracked cache directory held when the run began
+        make_engine(tiny_model, replicas=2).shutdown()
         eng = make_engine(tiny_model)
         try:
             report = eng.add_replica()
@@ -440,6 +446,174 @@ class TestElasticity:
                 if rows[w0.rid]["busy_s"] > 0.3:
                     break
                 time.sleep(0.02)
+            eng.revive_replica(w0.rid)
+            for t in threads:
+                t.join(60)
+            assert collected == ref    # exact: no dup, no corruption
+            assert eng.metrics.failed_total == 0
+        finally:
+            chaos.reset()
+            eng.shutdown()
+
+
+class TestLaunchAhead:
+    """Plain decode keeps its step-to-step state on the device and
+    launches step n+1 before it reads step n (PR 32): what the host may
+    no longer do between two steps, and what a late-learned end, a raise
+    and a hang must still leave intact."""
+
+    @staticmethod
+    def _fixed_pair(model, max_new, monkeypatch):
+        """Two requests admitted in one pass, ending on one step: the row
+        set never changes while they decode. -> (snapshot, device_puts of
+        the worker thread, the Prometheus text)."""
+        import jax
+
+        eng = make_engine(model, slots=2, max_new_tokens_cap=32,
+                          auto_start=False)
+        puts = []
+        real = jax.device_put
+
+        def counting(x, *a, **k):
+            # the worker's own puts by array (a restage puts its seven as
+            # one list), its two zeroed pools left out
+            if threading.current_thread().name.startswith(
+                    "generate-worker"):
+                puts.extend(np.shape(leaf) for leaf in jax.tree.leaves(x)
+                            if np.ndim(leaf) <= 2)
+            return real(x, *a, **k)
+
+        monkeypatch.setattr(jax, "device_put", counting)
+        try:
+            handles = [eng.submit(p, max_new)
+                       for p in mixed_prompts(2, seed=21)]
+            eng.start()
+            for h in handles:
+                assert len(h.result(60)["tokens"]) == max_new
+            return (eng.metrics.snapshot(), puts,
+                    eng.metrics.prometheus_text())
+        finally:
+            monkeypatch.setattr(jax, "device_put", real)
+            eng.shutdown()
+
+    def test_fixed_row_set_is_launched_ahead_and_stages_nothing(
+            self, tiny_model, monkeypatch):
+        short, puts_short, _ = self._fixed_pair(tiny_model, 6,
+                                                monkeypatch)
+        snap, puts, text = self._fixed_pair(tiny_model, 24, monkeypatch)
+        n = snap["steps_total"]
+        assert n == 23 and snap["step_rows_total"] == 2 * n
+        # every step but the first was launched with its predecessor
+        # still unread
+        assert snap["steps_ahead_total"] >= n - 2
+        assert snap["launch_ahead_share"] >= round((n - 2) / n, 4)
+        assert (f"paddle_generate_launch_ahead_share "
+                f"{snap['launch_ahead_share']}") in text
+        assert (f"paddle_generate_steps_ahead_total "
+                f"{snap['steps_ahead_total']}") in text
+        # the host staged two prefills (seven arrays each) and ONE set of
+        # seven row arrays, however many steps followed: 18 more steps
+        # put nothing
+        assert short["steps_total"] == 5
+        assert len(puts) == len(puts_short) == 2 * 7 + 7
+        assert puts == puts_short
+
+    def test_eos_learned_a_step_late_emits_nothing_after_it(
+            self, tiny_model):
+        """The host reads a row's EOS with the next step already on the
+        queue: that step's token for the row is discarded, the step counts
+        as no row, and the slot's next owner — prefilled behind the
+        overshoot — decodes what a fresh engine gives it."""
+        pa, pb = mixed_prompts(2, seed=5)
+        fresh = make_engine(tiny_model, slots=1)
+        try:
+            full = fresh.generate(pa, 10, timeout=60)["tokens"]
+            want_b = fresh.generate(pb, 9, timeout=60)["tokens"]
+        finally:
+            fresh.shutdown()
+        k = next(i for i in range(2, 10) if full[i] not in full[:i])
+        eng = make_engine(tiny_model, slots=1, auto_start=False)
+        try:
+            ha = eng.submit(pa, 10, eos_token_id=full[k])
+            hb = eng.submit(pb, 9)          # waits for A's slot
+            eng.start()
+            events = list(ha.events())
+            assert [v for kind, v in events if kind == "tok"] \
+                == full[:k + 1]
+            assert events[-1][0] == "done"
+            assert events[-1][1]["finish_reason"] == "eos"
+            assert hb.result(60)["tokens"] == want_b
+            snap = eng.metrics.snapshot()
+            # A ran k real steps (its first token is the prefill's), B 8;
+            # A's overshoot step held no real row: not a step
+            assert snap["steps_total"] == k + 8
+            assert snap["step_rows_total"] == k + 8
+            assert snap["tokens_out_total"] == k + 1 + 9
+        finally:
+            eng.shutdown()
+
+    def test_raise_with_a_step_in_flight_requeues_without_dup_or_loss(
+            self, tiny_model):
+        eng = make_engine(tiny_model, max_new_tokens_cap=32)
+        try:
+            prompts = mixed_prompts(3, seed=9)
+            ref = [eng.generate(p, 24, timeout=60)["tokens"]
+                   for p in prompts]
+            # slow steps, so the raise is armed mid-stream: by a row's
+            # third token the loop is steady and every pass meets the
+            # injection point with a launched step unread
+            chaos.add_rule("serving.decode_step", "delay", 0.01)
+            handles = [eng.submit(p, 24) for p in prompts]
+            streams = [[] for _ in prompts]
+            for tok in handles[0]:
+                streams[0].append(tok)
+                if len(streams[0]) == 3:
+                    assert eng.metrics.steps_ahead_total >= 1
+                    chaos.add_rule("serving.decode_step", "raise_n", 1)
+            streams[1:] = [list(h) for h in handles[1:]]
+            assert streams == ref                 # no dups, no holes
+            assert eng.metrics.requeues_total >= 1
+            assert eng.metrics.failed_total == 0
+        finally:
+            chaos.reset()
+            eng.shutdown()
+
+    def test_hang_with_a_step_in_flight_revives_without_dup_or_loss(
+            self, tiny_model):
+        eng = make_engine(tiny_model, max_new_tokens_cap=32)
+        try:
+            prompts = mixed_prompts(3, seed=12)
+            ref = [eng.generate(p, 24, timeout=60)["tokens"]
+                   for p in prompts]
+            w0 = eng._workers[0]
+            gen0 = w0.generation
+            chaos.add_rule("serving.decode_step", "delay", 0.01)
+            handles = [eng.submit(p, 24) for p in prompts]
+            collected = [[] for _ in prompts]
+
+            def consume(i, h):
+                for tok in h:
+                    collected[i].append(tok)
+                    if i == 0 and len(collected[0]) == 3:
+                        # wedge THIS incarnation at its next pass, a
+                        # launched step still unread behind it
+                        chaos.add_rule(
+                            "serving.decode_step", "delay", 8.0,
+                            match={"replica": w0.rid,
+                                   "generation": gen0})
+
+            threads = [threading.Thread(target=consume, args=(i, h),
+                                        name=f"consume-{i}")
+                       for i, h in enumerate(handles)]
+            for t in threads:
+                t.start()
+            deadline = time.monotonic() + 10
+            while time.monotonic() < deadline:
+                rows = {r["rid"]: r for r in eng.replica_states()}
+                if rows[w0.rid]["busy_s"] > 0.3:
+                    break
+                time.sleep(0.02)
+            assert eng.metrics.steps_ahead_total >= 1
             eng.revive_replica(w0.rid)
             for t in threads:
                 t.join(60)

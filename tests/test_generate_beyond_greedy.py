@@ -218,6 +218,83 @@ class TestSeededSamplingParity:
         assert k1 == greedy
 
 
+class TestDeviceHeldKeys:
+    """The key chain runs through keys the device holds from step to
+    step (PR 32): the host reads each step's keys one step late and
+    stages them again only when the row set changes."""
+
+    @pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+    def test_staggered_mixed_batch_matches_one_at_a_time(self, tiny_model,
+                                                         kv_dtype):
+        """Sampled rows (fixed seeds) mixed with greedy ones, eight
+        requests through three slots with answers of 3 to 14 tokens —
+        admitted and finishing on different steps, so rows are restaged
+        around every survivor — give, each, the tokens it gives alone."""
+        eng = make_engine(tiny_model, slots=3, kv_dtype=kv_dtype)
+        try:
+            prompts = mixed_prompts(8, seed=31)
+            asks = [3, 14, 7, 9, 4, 12, 5, 10]
+            samps = [dict(SAMP, seed=100 + i) if i % 3 else {}
+                     for i in range(8)]
+            alone = [eng.generate(p, n, timeout=60, **sp)["tokens"]
+                     for p, n, sp in zip(prompts, asks, samps)]
+            handles = [eng.submit(p, n, **sp)
+                       for p, n, sp in zip(prompts, asks, samps)]
+            assert [h.result(60)["tokens"] for h in handles] == alone
+            snap = eng.metrics.snapshot()
+            assert snap["max_slot_occupancy"] == 3
+            assert snap["steps_ahead_total"] > 0
+            # every emitted token is a prefill's or a real row's of a step
+            assert snap["tokens_out_total"] == \
+                snap["prefills_total"] + snap["step_rows_total"]
+        finally:
+            eng.shutdown()
+
+    def test_sampled_row_migrated_mid_stream_continues_identically(
+            self, tiny_model, plain_engine):
+        """A sampled stream taken out of the loop mid-decode (a migrating
+        drain) with a step launched ahead of what its client has seen:
+        the export carries the state of the last step READ and settled,
+        and the importer continues the same chain — head + tail is the
+        uninterrupted sequence."""
+        import threading
+
+        from paddle_tpu.inference.fabric import handoff
+
+        prompt = mixed_prompts(1, seed=33)[0]
+        want = plain_engine.generate(prompt, 14, timeout=60,
+                                     **SAMP)["tokens"]
+        eng = make_engine(tiny_model, slots=2)
+        try:
+            chaos.add_rule("serving.decode_step", "delay", 0.02)
+            h = eng.submit(prompt, 14, **SAMP)
+            head, payload, dt = [], [], None
+            for kind, val in h.events():
+                if kind == "tok":
+                    head.append(int(val))
+                    if len(head) == 4:
+                        assert eng.metrics.steps_ahead_total >= 1
+                        dt = threading.Thread(
+                            target=eng.shutdown, name="test-migrate",
+                            kwargs={"drain": True, "migrate": True})
+                        dt.start()
+                elif kind == "handoff":
+                    payload.append(val)
+            dt.join(60)
+            chaos.reset()
+            assert payload and payload[0]["streamed"] == len(head)
+            raw = handoff.from_b64(payload[0]["handoff"])
+            meta, arrays = handoff.decode(raw)
+            # the state that left is the state the client has seen
+            assert meta["tokens"] == head == want[:len(head)]
+            assert meta["length"] == len(prompt) + len(head) - 1
+            tail = list(plain_engine.import_handoff(raw))
+            assert head + tail == want, (head, tail, want)
+        finally:
+            chaos.reset()
+            eng.shutdown(drain=False)
+
+
 class TestSpeculative:
     def test_greedy_bitwise_equal_with_spec_on(self, plain_engine,
                                                spec_engine):

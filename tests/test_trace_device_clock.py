@@ -86,6 +86,9 @@ def test_tracing_off_records_nothing_in_the_profile(tmp_path):
     from harness import common
 
     assert not trace.enabled()
+    # switching the tracer off keeps what it recorded (trace.reconfigure),
+    # and a neighbour in this process may have recorded: nothing is ADDED
+    before = len(trace.spans())
     common.start_profile(str(tmp_path / "prof"))
     try:
         h = trace.span("clock.off", "test", {"iter": 1})
@@ -95,7 +98,7 @@ def test_tracing_off_records_nothing_in_the_profile(tmp_path):
     finally:
         jax.profiler.stop_trace()
     assert "clock.off" not in host_events(str(tmp_path / "prof"))
-    assert trace.spans() == []
+    assert len(trace.spans()) == before
 
 
 # ------------------------------------------- (b) the worker's partition --
@@ -156,6 +159,33 @@ def test_worker_spans_exist_and_carry_iter_and_rid(served_spans):
         assert [k["name"].rsplit(".", 1)[1] for k in kids] == [
             "stage", "launch", "wait"]
         assert len({k["args"]["iter"] for k in kids}) == 1
+
+
+def test_step_spans_say_whether_they_ran_ahead_and_what_they_staged(
+        served_spans):
+    """`ahead`: launched while an earlier step was unread; `staged`: host
+    arrays put for it — seven when the row set changed, none on a step
+    ahead (whose `.stage` brackets nothing). A `.wait` is the read of the
+    OLDEST unread step: the one launched a pass before, under the step
+    launched in this pass — or a bare `.wait`, child of no step, where a
+    pass only reads."""
+    steps = sorted((e for e in served_spans
+                    if e["name"] == "generate.decode_step"),
+                   key=lambda e: e["ts"])
+    assert {e["args"]["ahead"] for e in steps} == {0, 1}
+    assert {e["args"]["staged"] for e in steps} == {0, 7}
+    assert steps[0]["args"]["ahead"] == 0 and steps[0]["args"]["staged"] == 7
+    for e in steps:
+        # the row set changed <=> everything was read first <=> not ahead
+        assert (e["args"]["staged"] == 0) == (e["args"]["ahead"] == 1)
+    # six requests of ten tokens through two slots: most steps run ahead
+    assert sum(e["args"]["ahead"] for e in steps) >= 0.6 * len(steps)
+    step_ids = {e["args"]["span"] for e in steps}
+    waits = [e for e in served_spans
+             if e["name"] == "generate.decode_step.wait"]
+    bare = [e for e in waits if e["args"].get("parent") not in step_ids]
+    assert len(waits) == len(steps) + len(bare)
+    assert all(e["args"]["iter"] >= 1 for e in bare)
 
 
 def test_worker_spans_partition_the_threads_time(served_spans):
@@ -221,8 +251,12 @@ def test_span_readers_on_the_engines_own_spans(served_spans):
 
 def test_tracing_off_new_sites_build_no_args(small_model, monkeypatch):
     """With FLAGS_trace_dir unset every site gets the shared no-op and
-    none builds an args dictionary (nor emits a measured span)."""
+    none builds an args dictionary (nor emits a measured span). The
+    ring keeps what a neighbour of this process recorded while ITS
+    tracing was on (switching off keeps the capture), so "records
+    nothing" is read as "adds nothing"."""
     assert not trace.enabled()
+    before = len(trace.spans())
     seen = []
 
     class Recorder:
@@ -247,7 +281,8 @@ def test_tracing_off_new_sites_build_no_args(small_model, monkeypatch):
         eng.shutdown()
     assert set(WORKER_SPANS) - {"generate.idle"} <= {n for n, _ in seen}
     assert all(args is None for _, args in seen)
-    assert trace.span("x") is trace.span("y") and trace.spans() == []
+    assert trace.span("x") is trace.span("y")
+    assert len(trace.spans()) == before
 
 
 # ------------------------------------------ (c) names on the device side --
