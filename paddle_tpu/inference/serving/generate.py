@@ -123,7 +123,11 @@ same program inventory and slot pool:
   is independent of batch composition). Same seed => token-identical
   output across the batched, sequential, streaming and HTTP paths,
   and across a requeue re-prefill (the chain replays from the seed).
-  temperature == 0 keeps the argmax path bitwise-unchanged.
+  temperature == 0 keeps the argmax path bitwise-unchanged. Top-k and
+  top-p mask by VALUE, so the two thresholds (the k-th largest value,
+  the nucleus cut-off) are found by a search over values, the rows of a
+  step together (`_sample_token`); a batch skips what none of its rows
+  asks for — the top-k search, or everything but the argmax.
 
 - **Speculative multi-token decode.** With a ``draft=`` model, each
   scheduler iteration runs ONE fused k-step draft burst
@@ -171,6 +175,7 @@ inventory:
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import math
 import threading
@@ -218,27 +223,111 @@ def _prefix_hash(prompt: np.ndarray, n: int) -> str:
 # pure program bodies (jitted per bucket; params is a dict of stacked
 # per-layer arrays — one lax.scan body instead of L unrolled blocks)
 # ===================================================================
-def _sample_token(logits, temp, topk, topp, key):
-    """One row's next token from its logits [V]: argmax when temp == 0,
-    else temperature/top-k/top-p with `key` (raw uint32[2] PRNG key).
-    Both branches are computed (cheap at serving vocab sizes) so every
-    program has ONE shape regardless of the batch's sampling mix — and
-    the greedy value stays bitwise what the argmax-only program made."""
-    greedy = jnp.argmax(logits).astype(jnp.int32)
-    V = logits.shape[-1]
-    scaled = logits / jnp.maximum(temp, 1e-6)
-    srt = jnp.sort(scaled)[::-1]                      # descending
-    kth = srt[jnp.clip(topk - 1, 0, V - 1)]
-    masked_srt = jnp.where(srt < kth, _NEG_INF, srt)
-    # nucleus over the top-k survivors: keep the smallest sorted prefix
-    # reaching mass topp (the head token always survives)
-    sp = jax.nn.softmax(masked_srt)
-    keep = (jnp.cumsum(sp) - sp) < topp
-    cutoff = jnp.min(jnp.where(keep, masked_srt, jnp.inf))
-    scaled = jnp.where(scaled < jnp.maximum(kth, cutoff), _NEG_INF,
-                       scaled)
-    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
-    return jnp.where(temp > 0.0, sampled, greedy)
+_INT_MIN = np.int32(-2 ** 31)
+
+
+def _order_flip(bits):
+    """Between a float32's bits (as int32) and its ORDER IMAGE, the int32
+    whose signed order is the floats' own: a negative float's low 31 bits
+    are flipped. Its own inverse. (-0.0 lands one under +0.0; the values a
+    search returns are compared as floats again, where the two are equal.)"""
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+# Bits of a threshold that one pass over the rows settles, from 2**bits - 1
+# candidates. Inside a `while` a pass reads the rows from HBM, so fewer,
+# wider passes win until the compares outweigh the read: on a v5e 1 / 2 / 4
+# / 8 bits take 521 / 330 / 326 / 3,278 us at [32, 65,536] and 142 / 95 /
+# 114 / 705 at [8, 50,304] (PERF.md §6, PR 34).
+_SEARCH_BITS = 2
+
+
+def _largest_reaching(keys, weights, target):
+    """Each row's largest int32 t with sum(weights[keys >= t]) >= target
+    (`weights` None: the count of keys >= t). keys [b, V] order images,
+    target [b] -> [b]. The sum falls as t rises, so t is built from its
+    top bits down, `_SEARCH_BITS` a pass, in the unsigned image
+    (t ^ INT_MIN) where "set a bit" is "go up": a pass weighs the
+    candidates t | j << shift, rising with j, in one compare-and-reduce
+    over V, and the j that still reach the target are a prefix. Where some
+    key satisfies it the result is one of the row's keys; where none does
+    it is INT_MIN."""
+    js = jnp.arange(1, 1 << _SEARCH_BITS, dtype=jnp.int32)
+
+    def step(i, t):
+        shift = (32 - _SEARCH_BITS * (i + 1)).astype(jnp.int32)
+        cand = t[:, None] | jnp.left_shift(js, shift)[None, :]   # [b, C]
+        at = keys[:, None, :] >= (cand ^ _INT_MIN)[:, :, None]
+        if weights is None:
+            got = jnp.sum(at, axis=-1, dtype=jnp.int32)
+        else:
+            got = jnp.sum(jnp.where(at, weights[:, None, :], 0.0), axis=-1)
+        reached = jnp.sum(got >= target[:, None], axis=-1, dtype=jnp.int32)
+        return t | jnp.left_shift(reached, shift)
+
+    t = jax.lax.fori_loop(0, 32 // _SEARCH_BITS, step,
+                          jnp.zeros(keys.shape[:1], jnp.int32))
+    return t ^ _INT_MIN
+
+
+def _threshold_keys(scaled, k, topp, *, with_topk):
+    """The order image [b] of the value under which a row's scaled logits
+    [b, V] are cut: the larger of its k-th largest value (`with_topk`; an
+    exact count) and its nucleus cut-off — the largest value v among the
+    top-k survivors whose mass at or above v, sum(exp(scaled - max)),
+    reaches topp of the survivors' (so the mass strictly above it does
+    not: the smallest set reaching topp, ties at the cut-off all kept)."""
+    keys = _order_flip(jax.lax.bitcast_convert_type(scaled, jnp.int32))
+    if with_topk:
+        kth = _largest_reaching(keys, None, k)
+    else:
+        kth = jnp.full(k.shape, _INT_MIN, jnp.int32)
+    e = jnp.exp(scaled - jnp.max(scaled, axis=-1, keepdims=True))
+    total = jnp.sum(jnp.where(keys >= kth[:, None], e, 0.0), axis=-1)
+    return jnp.maximum(kth, _largest_reaching(keys, e, topp * total))
+
+
+def _sample_thresholds(scaled, topks, topps, sampled):
+    """[b] float32: the value a row keeps its scaled logits [b, V] at or
+    above — top-k, then top-p over the survivors. `sampled` [b] marks the
+    rows whose threshold will be used: where none of them asks for fewer
+    than V, the top-k search is not run (the whole batch's, decided from
+    its inputs: a branch per row under vmap would be a select)."""
+    V = scaled.shape[-1]
+    key = jax.lax.cond(
+        jnp.any(sampled & (topks < V)),
+        functools.partial(_threshold_keys, with_topk=True),
+        functools.partial(_threshold_keys, with_topk=False),
+        scaled, jnp.clip(topks, 1, V), topps)
+    return jax.lax.bitcast_convert_type(_order_flip(key), jnp.float32)
+
+
+def _sample_token(logits, temps, topks, topps, keys):
+    """The rows' next tokens [b] from their logits [b, V]: a row's argmax
+    where its temp == 0, else temperature / top-k / top-p with its raw
+    uint32[2] PRNG key — one definition for every program body (a
+    single-row body passes b = 1), ONE shape whatever the batch's
+    sampling mix. The two thresholds are found by a search over values
+    (`_sample_thresholds`), never by a sort: the mask is by VALUE, so the
+    permutation was never used. The draw is `categorical` over the
+    masked UNSORTED logits, a row at a time under its own key. The
+    greedy value is computed first, from the logits as they came, and is
+    what a temp == 0 row returns whichever branch the batch takes: it
+    stays bitwise what the argmax-only program made. A batch with no
+    sampled row runs the argmax alone."""
+    with jax.named_scope("generate.sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        sampled = temps > 0.0
+
+        def draw():
+            scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(
+                jnp.float32)
+            thr = _sample_thresholds(scaled, topks, topps, sampled)
+            masked = jnp.where(scaled < thr[:, None], _NEG_INF, scaled)
+            tok = jax.vmap(jax.random.categorical)(keys, masked)
+            return jnp.where(sampled, tok.astype(jnp.int32), greedy)
+
+        return jax.lax.cond(jnp.any(sampled), draw, lambda: greedy)
 
 
 def _split_keys(keys):
@@ -343,7 +432,8 @@ def _prefill_body(p, buf_k, buf_v, slot, ids, length, temp, topk, topp,
     h_last, buf_k, buf_v, rec = model.prefill(p, buf_k, buf_v, rec, slot,
                                               ids, length)
     key, sub = jax.random.split(key)
-    tok = _sample_token(model.head(p, h_last), temp, topk, topp, sub)
+    tok = _sample_token(model.head(p, h_last)[None], temp[None], topk[None],
+                        topp[None], sub[None])[0]
     return tok, key, buf_k, buf_v, rec
 
 
@@ -517,7 +607,7 @@ def _decode_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
         p, buf_k, buf_v, rec, slots, tokens, lengths, scratch)
     logits = model.head(p, h)
     keys, subs = _split_keys(keys)
-    nxt = jax.vmap(_sample_token)(logits, temps, topks, topps, subs)
+    nxt = _sample_token(logits, temps, topks, topps, subs)
     return nxt, keys, lengths + 1, buf_k, buf_v, rec, aux
 
 
@@ -557,14 +647,18 @@ def _verify_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
     h, buf_k, buf_v = _pool_pass(p, buf_k, buf_v, slots, tokens, pos,
                                  scratch, num_heads, eps)
     logits = _gpt.lm_head(p, h)                        # [b, k, V]
-    outs, hist = [], []
+    subs, hist = [], []
     cur = keys
-    for i in range(kk):
-        cur, subs = _split_keys(cur)
-        outs.append(jax.vmap(_sample_token)(logits[:, i], temps, topks,
-                                            topps, subs))
+    for _ in range(kk):
+        cur, sub = _split_keys(cur)
+        subs.append(sub)
         hist.append(cur)
-    ys = jnp.stack(outs, axis=1)                       # [b, k]
+    b = tokens.shape[0]
+    # the b * k positions are rows of ONE sampler call, row-major [b, k]
+    ys = _sample_token(
+        logits.reshape(b * kk, -1), jnp.repeat(temps, kk),
+        jnp.repeat(topks, kk), jnp.repeat(topps, kk),
+        jnp.stack(subs, axis=1).reshape(b * kk, 2)).reshape(b, kk)
     khist = jnp.stack(hist, axis=1)                    # [b, k, 2]
     return ys, khist, buf_k, buf_v
 
@@ -586,7 +680,8 @@ def _extend_body(p, buf_k, buf_v, slot, ids, start, length, temp, topk,
     h_last = jax.lax.dynamic_index_in_dim(h[0], length - 1 - start,
                                           axis=0, keepdims=False)
     key, sub = jax.random.split(key)
-    tok = _sample_token(_gpt.lm_head(p, h_last), temp, topk, topp, sub)
+    tok = _sample_token(_gpt.lm_head(p, h_last)[None], temp[None],
+                        topk[None], topp[None], sub[None])[0]
     return tok, key, buf_k, buf_v
 
 

@@ -523,27 +523,29 @@ def test_scopes_are_noted_by_program_and_the_step_says_its_experts(
 
 # ------------------------------------- the GPT programs, where they were
 # sha256 of the lowered text of the engine's GPT programs at gpt3-tiny
-# (4 slots, pools donated), as the PARENT of PR 33 (c426191) lowers them
-# on the CPU with this container's jax: PR 33 put a model-supplied step,
-# a state beside the K/V rows and grouped queries through the same bodies,
-# and moved none of these.
+# (4 slots, pools donated), on the CPU with this container's jax: PR 33
+# put a model-supplied step, a state beside the K/V rows and grouped
+# queries through the same bodies, and moved none of these. PR 34 moved
+# all eight, as it set out to (the sampling head every body ends in: a
+# search over values in the sort's place), and they are pinned again as
+# that PR lowers them.
 GPT_LOWERED = {
     ("decode", "f32"):
-        "a658845e4166fad34c1a86e543f528f1ac64431d2fe6b52db4ba05b39367100a",
+        "1c971dfd0830cd6449870b597e8516e512425abfb6ea521ab694699ee02babe4",
     ("prefill", "f32"):
-        "387968fc91b92e6b610dc96f5b9093088241939a70f1074d61fba5991b3c38e8",
+        "f4920ff7d217c0c1037a980df2893d6d7f6dc7682ed503714bf194debfadb077",
     ("extend", "f32"):
-        "5af9da14fb9877dcbf72b7f7e17c6a2cd3ee6e2bd69e2a8d875de7b8f5fdde5b",
+        "76658609e8ef97947b5e0d584874586b200376b27617f0252e9610682a1023e6",
     ("verify", "f32"):
-        "6352c5db3d93b5215379905f28db8e70325c61b5b557cc6a22850760e9ca4410",
+        "61707d76a9dec8984dc172b511d4376a840ec9d5795d504a51cf481463dda7b8",
     ("decode", "int8"):
-        "89dd9e8797e1458892be1a6a41f7f85bdcef13ea3869a972c996fe58140707f3",
+        "a32b7ac11fdaf9faeeefd2046f619e3e88d917929090b4bd4d922ae0318d9b50",
     ("prefill", "int8"):
-        "1a7249f3905df2d7c7c6c4487a7f2d0ca525ad85c04a6620f2da6f4d97eacbfd",
+        "7db11911e704a6e28f393e4a001c04f2378ddc7d3a65dc32a76e57598692f21d",
     ("extend", "int8"):
-        "a7c43e1deffaaa83a99ea5c0baac55880d946b90295f38bd8e9621efcb441f22",
+        "26964344064c2f60e4041f8a957c0a7bc6f925125535b9d27ee5e9913710026e",
     ("verify", "int8"):
-        "7a569cdb791ca92c1dd41a6520dfa789201b7e42ac62d686cad8df49a2faa5c7",
+        "714d4555f6a821996cbc7f6028021ec1c8fc5fe2828b5c1d998bde9b7220fe9d",
 }
 
 
