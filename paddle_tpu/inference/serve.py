@@ -165,41 +165,46 @@ def run_worker(prefix: str, runner=None, predictor=None) -> int:
 
 
 def generate_presets() -> dict:
-    """name -> the module whose PRESETS holds it: every model
-    `--generate` serves, each kind from its own module's table."""
-    from ..models import gpt, lfm2
+    """name -> the module whose PRESETS holds it: every model `--generate`
+    serves, each kind from its own module's table (models.gpt through the
+    engine's own `GPTPasses`; models.lfm2 and models.brumby through the
+    step their configuration supplies, `cfg.serving_passes()`). A module of
+    another kind joins by the same rule: a `PRESETS` table and, beside it,
+    `init_params(cfg)`."""
+    from ..models import brumby, gpt, lfm2
 
-    return {name: module for module in (gpt, lfm2)
+    return {name: module for module in (gpt, lfm2, brumby)
             for name in module.PRESETS}
 
 
 def build_generator(preset: str, state_dict: str | None = None,
                     draft: str | None = None, **engine_kw):
     """The GenerativeEngine `--generate PRESET` serves: a preset of
-    models.gpt or models.lfm2 with seeded demo weights (or, for a GPT,
-    `state_dict` loaded into it), optionally a `draft` preset for
-    speculative decode; `engine_kw` goes to the engine. Returned warmed
+    models.gpt, models.lfm2 or models.brumby with seeded demo weights (or,
+    for a GPT, `state_dict` loaded into it), optionally a `draft` preset
+    for speculative decode; `engine_kw` goes to the engine. Returned warmed
     and started."""
     import paddle_tpu as paddle
-    from ..models import lfm2
-    from ..models.gpt import PRESETS, GPTForCausalLM
+    from ..models import gpt
     from .serving import GenerativeEngine
     from .serving.generate import stack_gpt_params
 
     def stacked(name, state=None):
         """(params, cfg) as the engine's programs take them. A GPT's are
         copied out of a model that dies here, before the engine warms up:
-        its weights are not held a second time beside the pools. An
-        LFM2's are drawn on the device, an array at a time, in the type it
-        is served in: no model object, no float32 copy."""
+        its weights are not held a second time beside the pools. A model
+        of another kind draws its own on the device, an array at a time,
+        in the type it is served in: no model object, no float32 copy."""
         paddle.seed(0)
-        if name in lfm2.PRESETS:
+        module = generate_presets()[name]
+        if module is not gpt:
             if state:
                 raise ValueError(
-                    f"{name}: no checkpoint format for models.lfm2 yet; "
-                    f"its weights are seeded")
-            return lfm2.init_params(lfm2.PRESETS[name]), lfm2.PRESETS[name]
-        model = GPTForCausalLM(PRESETS[name])
+                    f"{name}: no checkpoint format for {module.__name__} "
+                    f"yet; its weights are seeded")
+            return module.init_params(module.PRESETS[name]), \
+                module.PRESETS[name]
+        model = gpt.GPTForCausalLM(gpt.PRESETS[name])
         model.eval()
         if state:
             model.set_state_dict(paddle.load(state))
@@ -233,8 +238,9 @@ def main(argv=None) -> int:
     ap.add_argument("--max-queue-depth", type=int, default=None)
     ap.add_argument("--generate", metavar="PRESET", default=None,
                     help="also serve streaming generation (/generate) "
-                         "from a PRESET of models.gpt or models.lfm2 "
-                         "(e.g. gpt3-tiny, lfm2-tiny; "
+                         "from a PRESET of models.gpt, models.lfm2 or "
+                         "models.brumby (e.g. gpt3-tiny, lfm2-tiny, "
+                         "brumby-tiny; "
                          "seeded demo weights, or --state-dict to load "
                          "trained ones); requires --http")
     ap.add_argument("--state-dict", default=None,
@@ -255,7 +261,8 @@ def main(argv=None) -> int:
     ap.add_argument("--kv-dtype", choices=("f32", "int8"), default=None,
                     help="--generate KV-cache pool precision (default: "
                          "the model's own — f32 for a GPT, bfloat16 for "
-                         "an LFM2, which takes no other): int8 "
+                         "an LFM2, which takes no other; a Brumby has no "
+                         "K/V pool): int8 "
                          "stores quantized rows with per-(row, layer) "
                          "absmax scales — half the pool bytes, double "
                          "the slots per byte (DESIGN.md Quantized serving)")

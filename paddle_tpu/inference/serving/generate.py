@@ -87,9 +87,13 @@ pool, re-cut for the XLA compilation contract):
   state rides `_ClassState.rec` — allocated, donated, carried and dropped
   with the pools; grouped-query attention through the same
   `pool_attention`; routed experts whose per-step counts come back with
-  the tokens). The GPT family's is `GPTPasses` below, over models/gpt.py's
-  two halves. The worker loop, admission, launch-ahead, sampling, streaming
-  and metrics are one code for every model.
+  the tokens. models/brumby.py::ServingPasses: NO attention layer — the
+  state, of the model's own type, is the whole cache; no K/V pool is
+  allocated, the pools ride the programs' signatures as None, and a class's
+  `cap` only limits positions). The GPT family's is `GPTPasses` below,
+  over models/gpt.py's two halves. The worker loop, admission,
+  launch-ahead, sampling, streaming and metrics are one code for every
+  model.
 
 - **Streaming.** Tokens are emitted per step onto each request's
   stream queue (GenerateHandle iterates them; server.py chunks them
@@ -355,6 +359,7 @@ class GPTPasses:
     # a name is part of a program's text and so of its compile-cache key
     program_prefix = None
     refuses: dict = {}
+    state_dtype = None
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -368,6 +373,9 @@ class GPTPasses:
 
     def state_shape(self, rows: int):
         return None
+
+    def state_step_bytes(self, real_rows: int, bucket: int) -> int:
+        return 0
 
     def head(self, p, h):
         return _gpt.lm_head(p, h)
@@ -898,8 +906,10 @@ class _ClassState:
     `dev` is the plain-decode loop's device-held rows (None until its
     first step, and always with a draft model). `rec` is the fixed-size
     state a model keeps beside its K/V rows (`model.state_shape`: one row
-    a slot, the scratch row included), None for a model that has none; it
-    is allocated, donated, carried and dropped with the pools."""
+    a slot, the scratch row included, in the model's `state_dtype`), None
+    for a model that has none; it is allocated, donated, carried and
+    dropped with the pools. A model with no attention layer has no pools:
+    `buf_k` / `buf_v` are None and `rec` is its whole cache."""
 
     __slots__ = ("cap", "n_slots", "buf_k", "buf_v", "rec", "free", "rows",
                  "pc_slots", "pcache", "pc_free", "dbuf_k", "dbuf_v",
@@ -978,7 +988,8 @@ _REGISTRY = _sm.EngineRegistry("generative", aggregate_snapshot)
                "prefix_tokens_reused_total", "handoffs_out_total",
                "handoffs_in_total", "migrations_out_total",
                "handoff_bytes_total", "kv_positions_read_total",
-               "kv_positions_capacity_total", "moe_assignments_total",
+               "kv_positions_capacity_total", "state_bytes_moved_total",
+               "moe_assignments_total",
                "moe_expert_tokens", "moe_distinct_experts_total",
                "moe_layer_steps_total")
 class GenerativeMetrics:
@@ -1006,6 +1017,7 @@ class GenerativeMetrics:
         self.step_padded_rows_total = 0   # pad rows added by batch bucket
         self.kv_positions_read_total = 0      # positions the steps read
         self.kv_positions_capacity_total = 0  # real rows x class cap
+        self.state_bytes_moved_total = 0  # fixed-size state, read + written
         self.draft_steps_total = 0        # fused k-step draft bursts
         self.spec_steps_total = 0         # target verify passes
         self.spec_proposed_total = 0      # draft tokens offered (k-1/row)
@@ -1062,11 +1074,13 @@ class GenerativeMetrics:
             self.prompt_tokens_total += prompt_tokens
 
     def on_step(self, rows: int, bucket: int, kv_read: int = 0,
-                kv_capacity: int = 0, ahead: bool = False):
+                kv_capacity: int = 0, ahead: bool = False,
+                state_bytes: int = 0):
         """One decode step of `rows` real rows in a batch bucket;
         `kv_read` of the rows' `kv_capacity` (rows x class cap) pool
-        positions were read by the step's attention. `ahead`: it was
-        launched while an earlier step was still unread."""
+        positions were read by the step's attention, and `state_bytes` of
+        the model's fixed-size state were read and written. `ahead`: it
+        was launched while an earlier step was still unread."""
         with self._lock:
             self.steps_total += 1
             self.steps_ahead_total += bool(ahead)
@@ -1074,6 +1088,7 @@ class GenerativeMetrics:
             self.step_padded_rows_total += max(bucket - rows, 0)
             self.kv_positions_read_total += int(kv_read)
             self.kv_positions_capacity_total += int(kv_capacity)
+            self.state_bytes_moved_total += int(state_bytes)
             self.occupancy_hist[rows] = \
                 self.occupancy_hist.get(rows, 0) + 1
 
@@ -1203,6 +1218,7 @@ class GenerativeMetrics:
                 "kv_read_share": _sm.rate(
                     self.kv_positions_read_total,
                     self.kv_positions_capacity_total),
+                "state_bytes_moved_total": self.state_bytes_moved_total,
                 "draft_steps_total": self.draft_steps_total,
                 "spec_steps_total": self.spec_steps_total,
                 "spec_proposed_total": self.spec_proposed_total,
@@ -1239,6 +1255,11 @@ class GenerativeMetrics:
         out["kv_pool"] = dict(self.kv_util_fn())
         tot = out["kv_pool"].get("positions_total") or 0
         used = out["kv_pool"].get("positions_used") or 0
+        if not tot:
+            # a cache with no positions (a fixed-size state a slot): what
+            # fills it is its slots
+            tot = out["kv_pool"].get("slots_total") or 0
+            used = out["kv_pool"].get("slots_used") or 0
         out["kv_pool"]["utilization"] = round(used / tot, 4) if tot else 0.0
         out["ttft_ms"] = {k: round(v * 1e3, 3) for k, v in ttft.items()}
         out["latency_ms"] = {k: round(v * 1e3, 3) for k, v in lat.items()}
@@ -1282,7 +1303,8 @@ class GenerativeMetrics:
                "tokens/sec over the sliding window")
         metric("paddle_generate_kv_pool_utilization", "gauge",
                s["kv_pool"]["utilization"],
-               "fraction of KV-pool positions holding live sequences")
+               "fraction of the cache holding live sequences: K/V "
+               "positions, or slots where the cache has no positions")
         metric("paddle_generate_kv_positions_read_total", "counter",
                s["kv_positions_read_total"],
                "KV-pool positions the decode steps' attention read")
@@ -1292,9 +1314,13 @@ class GenerativeMetrics:
         metric("paddle_generate_kv_read_share", "gauge",
                s["kv_read_share"],
                "positions read / capacity over the decode steps (lifetime)")
+        metric("paddle_generate_state_bytes_moved_total", "counter",
+               s["state_bytes_moved_total"],
+               "bytes of fixed-size state the decode steps read and wrote")
         metric("paddle_generate_kv_pool_bytes", "gauge",
                s["kv_pool"].get("pool_bytes", 0),
-               "bytes the KV pools allocate across active replicas")
+               "cache bytes across active replicas: K/V pools, a fixed-size "
+               "state, or both")
         metric("paddle_generate_quant_kv_enabled", "gauge",
                s["quant_kv_enabled"],
                "1 when the engine's KV pool is int8-quantized")
@@ -1716,8 +1742,10 @@ class GenerativeEngine:
     def _alloc_class(self, cap: int, device) -> _ClassState:
         # rows: [0, slots) live, [slots] scratch (pad/overflow sink),
         # [slots+1, slots+1+pc) prefix-cache entries
-        zk = _kvq.alloc(self._pool_shape(cap), device, self._kv_dtype)
-        zv = _kvq.alloc(self._pool_shape(cap), device, self._kv_dtype)
+        zk = zv = None      # a model with no attention layer has no pool
+        if self._L:
+            zk = _kvq.alloc(self._pool_shape(cap), device, self._kv_dtype)
+            zv = _kvq.alloc(self._pool_shape(cap), device, self._kv_dtype)
         dk = dv = None
         if self._spec:
             dk = _kvq.alloc(self._draft_pool_shape(cap), device,
@@ -1726,7 +1754,7 @@ class GenerativeEngine:
                             self._kv_dtype)
         rec = self._state_shape()
         if rec is not None:
-            rec = _kvq.alloc(rec, device, self._kv_dtype)
+            rec = _kvq.alloc(rec, device, self._model.state_dtype)
         return _ClassState(cap, self._slots, zk, zv, self._pc_slots,
                            dk, dv, rec)
 
@@ -1761,23 +1789,26 @@ class GenerativeEngine:
             H, Dh, Hq = self._dH, self._dDh, self._dH
         else:
             return None
-        if not _lowers_for_tpu(self._device_pool[0]):
-            return None
+        if not self._L or not _lowers_for_tpu(self._device_pool[0]):
+            return None         # no pool to read, or not lowered for a TPU
         return kernel_plan(cap, H, Dh, self._kv_dtype, Hq)
 
     def kv_pool_bytes(self) -> int:
-        """Bytes ONE worker's KV pools allocate (all capacity classes,
-        K+V, target + draft geometry, scratch and prefix-cache rows
-        included) — the density denominator serve_bench's quantized
-        gate divides by; int8 halves-and-halves-again the f32 figure
-        (int8 data + the small per-(row, layer) scale tensor)."""
+        """Bytes ONE worker's cache allocates, of either kind: the K/V
+        pools (all capacity classes, K+V, target + draft geometry, scratch
+        and prefix-cache rows included) and the model's fixed-size state
+        beside them — or the state alone, for a model with no attention
+        layer. The density denominator serve_bench's quantized gate
+        divides by; int8 halves-and-halves-again the f32 figure (int8
+        data + the small per-(row, layer) scale tensor)."""
         total = 0
         for cap in self._caps:
-            total += 2 * _kvq.pool_nbytes(self._pool_shape(cap),
-                                          self._kv_dtype)
+            if self._L:
+                total += 2 * _kvq.pool_nbytes(self._pool_shape(cap),
+                                              self._kv_dtype)
             if self._state_shape() is not None:
                 total += _kvq.pool_nbytes(self._state_shape(),
-                                          self._kv_dtype)
+                                          self._model.state_dtype)
             if self._spec:
                 total += 2 * _kvq.pool_nbytes(
                     self._draft_pool_shape(cap), self._kv_dtype)
@@ -1800,18 +1831,21 @@ class GenerativeEngine:
             # the read each pool-attending program was built with
             "kv_read": {
                 name: "kernel" if self._kv_plan(kind, cap) else "gather"
-                for name, kind, cap in named if kind in _POOL_READERS},
+                for name, kind, cap in named
+                if kind in _POOL_READERS and self._L},
             "prefill_buckets": [b for b in self._prompt_boundaries],
             "decode_batch_buckets": list(self._batch_buckets),
             "kv_classes": list(self._caps),
             "kv_dtype": self._kv_dtype,
-            # the cache by layer type: a class's K/V pool (K and V each)
-            # and the fixed-size state beside it (None: the model has none)
+            # the cache by layer type: a class's K/V pool (K and V each;
+            # none for a model with no attention layer) and the fixed-size
+            # state (None: the model has none), in `state_dtype`
             "cache": {"model": self._model.name,
                       "kv_pool": {cap: list(self._pool_shape(cap))
-                                  for cap in self._caps},
+                                  for cap in self._caps if self._L},
                       "state": None if self._state_shape() is None
                       else list(self._state_shape())},
+            "state_dtype": self._model.state_dtype,
             "quantize_weights": self._quant_w,
             "programs": progs,
             "warmed": warmed,
@@ -1841,11 +1875,13 @@ class GenerativeEngine:
                 "output_bytes": int(mem.output_size_in_bytes)}
 
     def _cache_avals(self, cap: int) -> tuple:
-        """(K pool, V pool, state) of class `cap` as shapes."""
-        pool = _kvq.aval(self._pool_shape(cap), self._kv_dtype)
+        """(K pool, V pool, state) of class `cap` as shapes; None what
+        the model has none of."""
+        pool = _kvq.aval(self._pool_shape(cap), self._kv_dtype) \
+            if self._L else None
         rec = self._state_shape()
         return pool, pool, None if rec is None else _kvq.aval(
-            rec, self._kv_dtype)
+            rec, self._model.state_dtype)
 
     # ----------------------------------------------------------- workers --
     def _new_worker(self, device=None) -> ReplicaSlot:
@@ -1874,21 +1910,24 @@ class GenerativeEngine:
             return [w.state_row(now) for w in self._workers]
 
     def _kv_utilization(self) -> dict:
-        """Pool gauge across workers: live slots/positions over the
-        ACTUAL allocated pool — every started worker carries one buffer
+        """Cache gauge across workers: live slots/positions over the
+        ACTUAL allocated cache — every started worker carries one buffer
         pair per capacity class whether or not it has admitted yet, so
         the denominator comes from the worker count, not from which
-        (rid, cap) keys happen to exist in the _live_rows mirror."""
+        (rid, cap) keys happen to exist in the _live_rows mirror. A model
+        with no attention layer caches no positions: both position counts
+        are nought, and the gauge is slots used of slots."""
         with self._cv:
             pools = sum(1 for w in self._workers
                         if w.state in ("active", "draining"))
             snap = [dict(rows) for rows in self._live_rows.values()]
         slots_total = pools * self._slots * len(self._caps)
-        positions_total = pools * self._slots * sum(self._caps)
+        positions_total = pools * self._slots * sum(self._caps) \
+            if self._L else 0
         slots_used = positions_used = 0
         for rows in snap:
             slots_used += len(rows)
-            positions_used += sum(rows.values())
+            positions_used += sum(rows.values()) if self._L else 0
         return {"slots_used": slots_used, "slots_total": slots_total,
                 "positions_used": positions_used,
                 "positions_total": positions_total,
@@ -2912,12 +2951,14 @@ class GenerativeEngine:
         # pool positions the read copies for each row: whole blocks up to
         # its position under the kernel, every position under the gather
         plan = self._kv_plan("decode", cs.cap)
-        kv_reads = [cs.cap if plan is None else
+        kv_reads = [0 if not self._L else cs.cap if plan is None else
                     plan.positions_read(x, cs.cap) for x in positions]
         args = None
         if _tr.enabled():
             args = {"replica": w.rid, "rows": n, "bucket": bucket,
-                    "cap": cs.cap, "kv_read": sum(kv_reads), "spec_k": 0,
+                    "cap": cs.cap, "kv_read": sum(kv_reads),
+                    "state_bytes": self._model.state_step_bytes(n, bucket),
+                    "spec_k": 0,
                     "ahead": int(behind > 0),
                     "staged": 0 if fresh is None else len(fresh),
                     "traces": [r.req.ctx.trace_id for r in rows
@@ -3167,9 +3208,13 @@ class GenerativeEngine:
                 rows[i].key = np.array(keys[i], np.uint32)
             self._update_liveness_locked(w, cs)
         if live:
+            # the state moved is the launched rows': one that had left its
+            # slot by the read was stepped all the same
             self.metrics.on_step(len(live), bucket,
                                  sum(kv_reads[i] for i in live),
-                                 len(live) * cs.cap, ahead)
+                                 len(live) * cs.cap if self._L else 0, ahead,
+                                 self._model.state_step_bytes(len(rows),
+                                                              bucket))
         if experts is not None:
             # the program counted the rows it was launched with; a row
             # that had left its slot by then was one of them

@@ -466,6 +466,19 @@ class ServingPasses:
         return (rows, len(self.cfg.conv_layers), self.cfg.conv_L_cache - 1,
                 self.cfg.hidden_size)
 
+    @property
+    def state_dtype(self) -> str:
+        """The state is held in the K/V pool's type."""
+        return self.kv_dtype
+
+    def state_step_bytes(self, real_rows: int, bucket: int) -> int:
+        """State bytes one decode step moves: every row of the batch
+        bucket, padding rows too, is gathered and scattered back once a
+        convolution layer."""
+        del real_rows
+        return 2 * bucket * math.prod(self.state_shape(1)) \
+            * jnp.dtype(self.cfg.dtype).itemsize
+
     def head(self, p, h):
         return lm_head(p, h)
 

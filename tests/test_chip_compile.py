@@ -252,3 +252,66 @@ def test_lfm2_programs_compile_for_a_v5e_at_the_cells_size(
     assert not copies, copies
     print(kind, bucket, "temp", mem.temp_size_in_bytes, "args",
           mem.argument_size_in_bytes, "alias", mem.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("kind, bucket", [("decode", 16), ("prefill", 128)])
+def test_brumby_programs_compile_for_a_v5e_at_the_cells_size(
+        one_chip, no_persistent_cache, kind, bucket):
+    """`brumby-14b-base` as the benchmark's cell serves it (16 slots, one
+    class of 1,024 positions, NO K/V pool): the decode program of all slots
+    and the prefill of the traffic's longest prompt bucket, from shapes
+    alone. The decode program takes the `retention_step` kernel, one a
+    layer, over the float32 state where it lies: the state (4.92 GB)
+    aliases its input, and the program's temporaries hold nothing of its
+    size — under 100 MB, where one state-sized copy would not fit the chip
+    beside 8.4 GB of weights."""
+    from paddle_tpu.inference.serving import GenerativeEngine
+    from paddle_tpu.models import brumby
+
+    cfg = brumby.PRESETS["brumby-14b-base"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {n: sds(shape, jnp.float32 if what == "gate" else jnp.bfloat16)
+              for n, (shape, what) in brumby.param_shapes(cfg).items()}
+    eng = GenerativeEngine(params=({}, cfg), slots=16, warmup=False,
+                           auto_start=False, donate=True)
+    try:
+        cap = eng._caps[-1]
+        rec = sds(eng._state_shape(), jnp.float32)
+        assert rec.shape == (17, 8, 8, 8832, 128)
+        assert eng._cache_avals(cap)[:2] == (None, None)
+        if kind == "decode":
+            b = bucket
+            rows = (sds((b,), jnp.int32), sds((b,), jnp.int32),
+                    sds((b,), jnp.int32), sds((b,), jnp.float32),
+                    sds((b,), jnp.int32), sds((b,), jnp.float32),
+                    sds((b, 2), jnp.uint32))
+        else:
+            rows = (sds((), jnp.int32), sds((1, bucket), jnp.int32),
+                    sds((), jnp.int32), sds((), jnp.float32),
+                    sds((), jnp.int32), sds((), jnp.float32),
+                    sds((2,), jnp.uint32))
+        compiled = eng._program(kind, cap, bucket).lower(
+            params, None, None, *rows, rec).compile()
+        state_bytes = eng.kv_pool_bytes()
+    finally:
+        eng.shutdown(drain=False)
+    mem = compiled.memory_analysis()
+    assert state_bytes == 4 * int(np.prod(rec.shape)) == 4_919_918_592
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.argument_size_in_bytes > 2 * brumby.n_params(cfg) + state_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 0.85 * 16.9e9
+    text = compiled.as_text()
+    assert f"jit_brumby_{kind}_c1024_b{bucket}" in text
+    if kind == "decode":
+        assert mem.temp_size_in_bytes < 100e6
+        assert "tpu_custom_call" in text and "retention_step" in text \
+            and "retention.step" in text
+    else:
+        assert mem.temp_size_in_bytes < 2**30
+        assert "retention.prefill" in text
+    print(kind, bucket, "temp", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes, "alias", mem.alias_size_in_bytes)
