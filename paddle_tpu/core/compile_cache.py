@@ -146,6 +146,49 @@ def reconfigure(path: str | None) -> bool:
     return ok
 
 
+def _plan_path(key: str):
+    import hashlib
+
+    d = _STATS["dir"]
+    if not (_STATS["enabled"] and d):
+        return None
+    return os.path.join(
+        d, hashlib.sha256(key.encode()).hexdigest() + "-plan.json")
+
+
+def plan_lookup(key: str):
+    """What `plan_store` kept under `key` beside the cache's entries, or
+    None: a decision that cost compiles to reach (TrainStep's remat
+    plan, keyed by the lowered text it was reached for), so that a warm
+    start compiles only the program it chose."""
+    import json
+
+    path = _plan_path(key)
+    if path is None:
+        return None
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except (OSError, ValueError):
+        return None
+
+
+def plan_store(key: str, plan) -> None:
+    """Keep `plan` (JSON) under `key` for `plan_lookup`; nothing is kept
+    where the cache is off or its directory cannot be written."""
+    import json
+
+    path = _plan_path(key)
+    if path is None:
+        return
+    try:
+        with open(path + ".tmp", "w") as f:
+            json.dump(plan, f)
+        os.replace(path + ".tmp", path)
+    except OSError:
+        pass
+
+
 @contextlib.contextmanager
 def measure():
     """Count persistent-cache hits/misses across a code region.

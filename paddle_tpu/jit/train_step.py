@@ -12,6 +12,7 @@ chosen by XLA over ICI).
 """
 from __future__ import annotations
 
+import sys
 from typing import Callable, Optional
 
 import jax
@@ -24,6 +25,31 @@ from ..core import state as _st
 from .. import profiler as _prof
 from ..observability import trace as _tracer
 from ..testing import chaos as _chaos
+
+
+def _device_budget(mesh):
+    """(bytes of memory on the step's least roomy device, its row of
+    published peaks), or None where either is unknown — the CPU, a device
+    that is not in the table: no remat plan is made there."""
+    from ..profiler.stats.flops import device_peaks
+
+    devices = [d for d in (jax.local_devices()[:1] if mesh is None
+                           else mesh.devices.flat)
+               if d.process_index == jax.process_index()]
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    try:
+        return (min(limits), device_peaks(devices[0].device_kind)) \
+            if limits and all(limits) else None
+    except LookupError:
+        return None
+
+
+def _need_bytes(mem) -> int:
+    """What a compiled program holds on one device while it runs, from
+    its memory_analysis(): donated arguments are counted once."""
+    return int(mem.argument_size_in_bytes + mem.output_size_in_bytes
+               - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+               + mem.generated_code_size_in_bytes)
 
 
 def _mp_put(value, sharding, full: bool = True):
@@ -41,6 +67,14 @@ class TrainStep:
 
     loss_fn(model, *batch) -> scalar loss Tensor. If None, the model itself
     must return the loss. Batch elements may be Tensors or arrays.
+
+    A model whose scanned block names values worth keeping for the
+    backward offers `remat_candidates(ids_shape, mesh, param_specs,
+    batch_spec, peaks)` and reads `remat_save` when it is traced
+    (models/gpt.py): before its first compile the step fits a plan to the
+    device's memory by the compiler's own report (`jit/remat_plan.py`,
+    `_plan_remat`) and says what it kept in `compile_report` /
+    `compiled_memory_report()` under `remat_saved`.
     """
 
     def __init__(self, model, optimizer, loss_fn: Optional[Callable] = None,
@@ -156,6 +190,9 @@ class TrainStep:
         # call — {first_call_s, persistent_hits, persistent_misses}; a warm
         # FLAGS_compile_cache_dir shows hits>0 and a fast first call
         self.compile_report = None
+        # what the remat plan kept (jit/remat_plan.py::saved_report), once
+        # the first call or lowering has made it
+        self._remat_saved = None
         # batch-shape signatures already compiled: the donated-program
         # cache guard (compile_cache.suspend_if) costs ~50 µs, so it
         # wraps only calls that can trigger a compile
@@ -501,6 +538,7 @@ class TrainStep:
                 compiled = self.lowered(*batch).compile()
         except Exception as e:  # noqa: BLE001
             return {"error": repr(e)}
+        out["remat_saved"] = self._remat_saved
         if _tracer.enabled():
             # with the compiled program in hand: which scope each of its
             # instructions was traced under, for readers of a device trace
@@ -583,12 +621,89 @@ class TrainStep:
         return out
 
     def _call_impl(self, *batch):
+        if self._remat_saved is None:
+            self._plan_remat(batch)
         # dispatch span: child of the fit loop's train.step root (same
         # thread), so the step trace reads data_wait -> dispatch ->
         # ckpt.snapshot -> (writer thread) ckpt.write. No-op when off.
         with _tracer.span("train.dispatch", "train",
-                          {"step": self._host_step + 1}):
+                          {"step": self._host_step + 1,
+                           "remat_saved_bytes": self._remat_saved["bytes"]}):
             return self._dispatch_impl(*batch)
+
+    # ------------------------------------------------------ remat plan --
+    def _batch_avals(self, batch) -> tuple:
+        """The batch as the step's program takes it: shapes and dtypes,
+        laid out by `batch_sharding` over the mesh."""
+        from jax.sharding import NamedSharding
+
+        vals = [b._data if isinstance(b, Tensor) else jnp.asarray(b)
+                for b in batch]
+        specs = [None] * len(vals)
+        if self.mesh is not None and self._batch_sharding is not None:
+            specs = [NamedSharding(self.mesh, s)
+                     for s in self._batch_sharding]
+        return tuple(jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=s)
+                     for v, s in zip(vals, specs))
+
+    def _lower(self, avals):
+        """The monolithic step lowered for `avals`; consumes no key of
+        the random stream."""
+        if self._step_fn is None:
+            self._build()
+        return self._step_fn.lower(
+            self._params, self._buffers, self._opt_state,
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.int32), jax.random.key(0), avals)
+
+    def _plan_remat(self, batch):
+        """Choose what the model's scanned block keeps of its forward
+        (the class docstring's protocol), once, before the first compile.
+        An empty plan leaves the program the policy-free one. The plan is
+        kept beside the compile cache under the policy-free program's
+        text, so a warm start lowers, looks up and compiles once."""
+        from ..core import compile_cache as _cc
+        from . import remat_plan as _rp
+
+        self._remat_saved = _rp.saved_report(())
+        candidates_of = getattr(self.model, "remat_candidates", None)
+        budget = _device_budget(self.mesh)
+        if candidates_of is None or budget is None or self._acc_steps > 1:
+            return
+        device_bytes, peaks = budget
+        avals = self._batch_avals(batch)
+        candidates = candidates_of(
+            avals[0].shape, self.mesh, self._param_specs,
+            (self._batch_sharding or (None,))[0], peaks)
+        if not candidates:
+            return
+        limit = device_bytes - _rp.SPARE_BYTES
+
+        def lowered_under(names):
+            self.model.remat_save = tuple(names)
+            self._build()
+            return self._lower(avals)
+
+        policy_free = lowered_under(())
+
+        def need_of(names):
+            lowered = lowered_under(names) if names else policy_free
+            with _cc.donated_cpu_guard(self._donate):
+                return _need_bytes(lowered.compile().memory_analysis())
+
+        key = f"{policy_free.as_text()}\n{limit}\n{candidates}"
+        report = _cc.plan_lookup(key)
+        if report is None:
+            report = _rp.fit_remat_plan(candidates, limit, need_of)
+            _cc.plan_store(key, report)
+        if tuple(report["names"]) != self.model.remat_save:
+            self.model.remat_save = tuple(report["names"])
+            self._build()
+        self._remat_saved = report
+        print(f"TrainStep: remat keeps {report['names'] or 'nothing'} "
+              f"({report['bytes'] / 2**30:.2f} GiB by shape; the step needs "
+              f"{(report['need_bytes'] or 0) / 2**30:.2f} of "
+              f"{limit / 2**30:.2f} GiB)", file=sys.stderr, flush=True)
 
     def _dispatch_impl(self, *batch):
         if self._step_fn is None:
@@ -734,6 +849,7 @@ class TrainStep:
                 "first_call_s": round(_time.perf_counter() - t0, 3),
                 "persistent_hits": post["hits"] - pre["hits"],
                 "persistent_misses": post["misses"] - pre["misses"],
+                "remat_saved": self._remat_saved,
             }
 
         return finish
@@ -746,15 +862,9 @@ class TrainStep:
         Note: callers that .compile() this on CPU should hold
         core.compile_cache.donated_cpu_guard(self._donate) — see
         compile_cache.suspend_if."""
-        if self._step_fn is None:
-            self._build()
-        vals = tuple(b._data if isinstance(b, Tensor) else jnp.asarray(b)
-                     for b in batch)
-        lr = jnp.asarray(0.0, jnp.float32)
-        si = jnp.asarray(1, jnp.int32)
-        key = _rng.next_key()
-        return self._step_fn.lower(self._params, self._buffers,
-                                   self._opt_state, lr, si, key, vals)
+        if self._remat_saved is None:
+            self._plan_remat(batch)
+        return self._lower(self._batch_avals(batch))
 
     def lower_hlo(self, *batch):
         """Return the StableHLO text of the compiled step (debug/inspection)."""

@@ -18,6 +18,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 import paddle_tpu as paddle
 from .. import nn
@@ -297,19 +298,33 @@ def layer_norm(h, w, b, eps):
     return (h - mu) / jnp.sqrt(var + eps) * w + b
 
 
-def block_qkv(h, lp: GPTLayer, num_heads, eps):
+# Values of the block that a remat policy may keep for the backward, by
+# the name each carries. A caller that wants them named passes
+# `named=jax.ad_checkpoint.checkpoint_name` (an identity that leaves no
+# instruction; only `jax.checkpoint(..., policy=save_only_these_names)`
+# reads it); every other caller's trace is what it was without names.
+SAVE_QKV = "gpt.qkv"                # the fused QKV projection, unreshaped
+SAVE_ATTN_PROJ = "gpt.attn_proj"    # att @ out_w: over tp, AFTER its all-reduce
+
+
+def block_qkv(h, lp: GPTLayer, num_heads, eps, named=None):
     """First half: norm and fused QKV projection of the residual stream
     h [..., D]; returns q, k, v, each [..., H, Dh]."""
     qkv = layer_norm(h, lp.ln1_w, lp.ln1_b, eps) @ lp.qkv_w + lp.qkv_b
+    if named is not None:
+        qkv = named(qkv, SAVE_QKV)
     H = int(num_heads)
     qkv = qkv.reshape(h.shape[:-1] + (3, H, h.shape[-1] // H))
     return qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
 
 
-def block_out(h, att, lp: GPTLayer, eps):
+def block_out(h, att, lp: GPTLayer, eps, named=None):
     """Second half: the attention's output att [..., D] through the output
     projection, second norm and GELU MLP, onto the residual stream."""
-    h = h + att @ lp.out_w + lp.out_b
+    proj = att @ lp.out_w
+    if named is not None:
+        proj = named(proj, SAVE_ATTN_PROJ)
+    h = h + proj + lp.out_b
     y = layer_norm(h, lp.ln2_w, lp.ln2_b, eps)
     y = jax.nn.gelu(y @ lp.fc1_w + lp.fc1_b, approximate=True) \
         @ lp.fc2_w + lp.fc2_b
@@ -343,14 +358,16 @@ def _stacked_items(emb, layers, ln_f, head):
 
 @defop("gpt_scan_blocks")
 def _gpt_scan_blocks_p(x, *layers, num_heads=8, eps=1e-5, remat=False,
-                       attn_shard=None):
+                       attn_shard=None, remat_save=()):
     """The whole transformer stack as ONE lax.scan over stacked per-layer
     params (`layers`: the LAYER_PARAMS, [L, ...] leading axis) — XLA sees
     one block body instead of L unrolled copies, so compile time drops
     ~L-fold (same math as the unrolled GPTBlock list; dropout-free path).
     remat=True checkpoints each scan iteration (activation memory ~1
-    block). attn_shard = (mesh, spec of the [B, L, H, hd] q/k/v) runs
-    attention per shard (GPTForCausalLMScan.shard_attention)."""
+    block) and keeps of it only the values named in `remat_save` (the
+    SAVE_* names; what TrainStep's remat plan chose). attn_shard = (mesh,
+    spec of the [B, L, H, hd] q/k/v) runs attention per shard
+    (GPTForCausalLMScan.shard_attention)."""
     from ..nn.functional import _sdpa_p
 
     sdpa = functools.partial(_sdpa_p._pure_fn, is_causal=True)
@@ -361,12 +378,18 @@ def _gpt_scan_blocks_p(x, *layers, num_heads=8, eps=1e-5, remat=False,
         sdpa = shard_map(sdpa, mesh, in_specs=(spec,) * 3, out_specs=spec,
                          check=False)
 
+    # names only where a policy reads them: an empty `remat_save` traces
+    # the block as it was before it named anything
+    named = checkpoint_name if remat and remat_save else None
+
     def body(h, lp):
-        att = sdpa(*block_qkv(h, lp, num_heads, eps))
-        return block_out(h, att.reshape(h.shape), lp, eps), None
+        att = sdpa(*block_qkv(h, lp, num_heads, eps, named))
+        return block_out(h, att.reshape(h.shape), lp, eps, named), None
 
     if remat:
-        body = jax.checkpoint(body)
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(
+                *remat_save) if remat_save else None)
     out, _ = jax.lax.scan(body, x, GPTLayer(*layers))
     return out
 
@@ -406,6 +429,7 @@ class GPTForCausalLMScan(nn.Layer):
             self.lm_head_w = mk([D, cfg.vocab_size],
                                 default_initializer=xav)
         self.remat = False
+        self.remat_save = ()    # SAVE_* names the remat keeps (TrainStep)
         self._attn_shard = None
 
     def shard_attention(self, mesh, mesh_axes=("dp", "tp")):
@@ -446,6 +470,59 @@ class GPTForCausalLMScan(nn.Layer):
             dest[name].set_value(value)
         return out
 
+    def remat_candidates(self, ids_shape, mesh, param_specs, batch_spec,
+                         peaks) -> list:
+        """What `remat_save` may name, priced for `jit.remat_plan`: each
+        value's bytes on one device over the whole stack, and the seconds
+        of second forward the backward no longer runs when it is kept —
+        its product's FLOPs at the peak, and for a product whose
+        contraction is split over mesh axes (a row-parallel weight, read
+        off `param_specs`) the all-reduce that follows it. `ids_shape`
+        [B, L] laid out by `batch_spec`; `peaks`: the device's
+        `profiler.stats.flops.DEVICE_PEAKS` row. Empty without remat."""
+        from ..jit.remat_plan import RematCandidate
+
+        if not self.remat:
+            return []
+
+        def ways(entry):    # devices one PartitionSpec entry splits over
+            names = entry if isinstance(entry, (tuple, list)) else (entry,)
+            return math.prod(mesh.shape[a] for a in names if a is not None)
+
+        def split(name):    # of a stacked [L, k, n] weight: (k, n) ways
+            if mesh is None:
+                return 1, 1
+            sp = tuple((param_specs or {}).get(name) or ())
+            return tuple(map(ways, (sp + (None,) * 3)[1:3]))
+
+        cfg = self.cfg
+        D, layers = cfg.hidden_size, cfg.num_layers
+        itemsize = self.qkv_w._data.dtype.itemsize
+        batch, seq = ids_shape
+        if mesh is not None and batch_spec:
+            batch //= ways(batch_spec[0])
+        flops_s = peaks["bf16_flops"]
+
+        def product(name, k, n):
+            """One layer's [batch, seq, n] product on a device -> (its
+            bytes, its seconds and those of the all-reduce after it)."""
+            k_split, n_split = split(name)
+            nbytes = batch * seq * (n // n_split) * itemsize
+            seconds = 2 * batch * seq * (k // k_split) * (n // n_split) \
+                / flops_s
+            if k_split > 1:     # a ring sends 2 (w - 1) / w of the value
+                seconds += 2 * (k_split - 1) / k_split * nbytes \
+                    / peaks["ici_link_bytes_per_s"]
+            return nbytes, seconds
+
+        proj_bytes, proj_s = product("out_w", D, D)
+        qkv_bytes, qkv_s = product("qkv_w", D, 3 * D)
+        return [
+            RematCandidate(SAVE_ATTN_PROJ, proj_bytes, layers,
+                           proj_s * layers),
+            RematCandidate(SAVE_QKV, qkv_bytes, layers, qkv_s * layers),
+        ]
+
     def stacked_params(self) -> dict:
         """Copies of the weights as the stacked param dict (see
         GPTForCausalLM.stacked_params)."""
@@ -461,7 +538,8 @@ class GPTForCausalLMScan(nn.Layer):
         h = _gpt_scan_blocks_p(
             x, *(getattr(self, n) for n in LAYER_PARAMS),
             num_heads=self.cfg.num_heads, eps=self.cfg.layer_norm_eps,
-            remat=bool(self.remat), attn_shard=self._attn_shard)
+            remat=bool(self.remat), attn_shard=self._attn_shard,
+            remat_save=tuple(self.remat_save))
         return self.ln_f(h)
 
     def forward(self, input_ids):

@@ -21,10 +21,12 @@ define_flag("device_peak_flops", 0.0,
 # Published per-chip peaks, keyed by jax's ``device_kind``. A device that
 # is not listed is an error where a utilization is asked for, never a
 # default. Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s
-# bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s).
+# bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbps of inter-chip
+# interconnect a chip over four links: 50 GB/s a link and direction).
 DEVICE_PEAKS = {
     "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
-                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9,
+                    "ici_link_bytes_per_s": 50e9},
 }
 
 

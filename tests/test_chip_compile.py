@@ -315,3 +315,152 @@ def test_brumby_programs_compile_for_a_v5e_at_the_cells_size(
         assert "retention.prefill" in text
     print(kind, bucket, "temp", mem.temp_size_in_bytes, "args",
           mem.argument_size_in_bytes, "alias", mem.alias_size_in_bytes)
+
+
+# --------------------------------------------- the train step's remat plan --
+# a v5e's `bytes_limit` as the chip's runtime reports it (memory_stats on
+# the cells' own runs): what TrainStep's plan reads there as the device's
+# memory, since a described device reports none
+V5E_BYTES_LIMIT = 16_909_334_528
+
+
+def _abstract_train_step(topo, monkeypatch, preset, batch, seq, sharded):
+    """`bench.build_train_step`'s program at `preset`'s full shapes for
+    the described chip(s), nothing allocated: the step is built at toy
+    widths on this host's CPU devices, then traced over the full shapes
+    (every size the model reads comes off its arguments; the two it
+    reads off its config are set) with the described devices in the
+    mesh's place. -> (lower(names) -> the step lowered with the block
+    keeping `names`, the model's candidates)."""
+    import contextlib
+
+    import bench
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.models import PRESETS, GPTConfig, gpt
+    from paddle_tpu.profiler.stats.flops import DEVICE_PEAKS
+
+    full = PRESETS[preset]
+    toy = GPTConfig(vocab_size=256, hidden_size=2 * full.num_heads,
+                    num_layers=1, num_heads=full.num_heads,
+                    ffn_hidden=4 * full.num_heads, max_seq_len=seq)
+    monkeypatch.setitem(PRESETS, "_toy", toy)
+    host_mesh = bench.dp_tp_mesh(jax.devices()[:4], tp=2) if sharded \
+        else None
+    with host_mesh or contextlib.nullcontext():
+        step, _, _, _ = bench.build_train_step("_toy", batch, seq,
+                                               mesh=host_mesh)
+    model = step.model
+    if sharded:
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), bench.MESH_AXES)
+        step.mesh = mesh
+        model._attn_shard = (mesh, model._attn_shard[1])
+
+        def place(spec):
+            return NamedSharding(mesh, spec)
+    else:
+        mesh = None
+
+        def place(spec):
+            return SingleDeviceSharding(topo.devices[0])
+
+    grown = {toy.hidden_size: full.hidden_size,
+             3 * toy.hidden_size: 3 * full.hidden_size,
+             toy.ffn_hidden: full.ffn_hidden,
+             toy.vocab_size: full.vocab_size}
+
+    def full_shape(name, shape):
+        layers = [full.num_layers] if name in gpt.LAYER_PARAMS else []
+        return tuple(layers + [grown.get(d, d)
+                               for d in shape[len(layers):]])
+
+    specs = step._param_specs or {}
+    params = {n: jax.ShapeDtypeStruct(full_shape(n, v.shape), v.dtype,
+                                      sharding=place(specs.get(n, P())))
+              for n, v in step._params.items()}
+    opt_specs = step._opt_specs[0] if step._opt_specs else {}
+    opt = ({n: {k: jax.ShapeDtypeStruct(
+        full_shape(n, v.shape) if v.shape == step._params[n].shape
+        else v.shape, v.dtype,
+        sharding=place(opt_specs.get(n, {}).get(k, P())))
+        for k, v in state.items()}
+        for n, state in step._opt_state[0].items()},)
+    buffers = {n: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                       sharding=place(P()))
+               for n, v in step._buffers.items()}
+    batch_specs = step._batch_sharding or (P(), P())
+    ids = tuple(jax.ShapeDtypeStruct((batch, seq), jnp.int64,
+                                     sharding=place(s))
+                for s in batch_specs)
+    monkeypatch.setattr(model.cfg, "hidden_size", full.hidden_size)
+    monkeypatch.setattr(model.cfg, "num_layers", full.num_layers)
+
+    def lower(names):
+        model.remat_save = tuple(names)
+        step._build()
+        return step._step_fn.lower(
+            params, buffers, opt, jax.ShapeDtypeStruct((), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jax.random.key(0).dtype), ids)
+
+    return lower, model.remat_candidates(
+        (batch, seq), mesh, step._param_specs, batch_specs[0],
+        DEVICE_PEAKS["TPU v5 lite"])
+
+
+@pytest.fixture
+def flash_on_this_host():
+    import paddle_tpu as paddle
+
+    paddle.set_flags({"FLAGS_force_flash_attention": True})
+    yield
+    paddle.set_flags({"FLAGS_force_flash_attention": False})
+
+
+@pytest.mark.parametrize("preset, batch, sharded, kept", [
+    ("gpt3-1.3b", 32, True, ["gpt.attn_proj", "gpt.qkv"]),
+    ("gpt3-medium", 64, False, []),
+], ids=["1.3b-dp2tp2", "medium"])
+def test_train_step_remat_plan_by_the_chips_own_compile(
+        topo, no_persistent_cache, flash_on_this_host, monkeypatch, preset,
+        batch, sharded, kept):
+    """Both train cells' whole steps, 24 layers, compiled for the
+    described chip(s) under the plan `fit_remat_plan` reaches from the
+    compiler's reports, as TrainStep does on the chip. 1.3B over dp2 x
+    tp2, 16 x 1,024 tokens a dp shard: the attention projection AFTER its
+    all-reduce and the QKV product fit 14.75 GiB (13.4 needed; 9.6
+    without), the backward's while body holds two all-reduces of the
+    residual where the policy-free one holds three (the forward's two
+    stay), and the temporaries grow by what `remat_saved` counts, within
+    a tenth. gpt3-medium at batch 64 on one chip has 0.5 GiB to spare:
+    an empty plan, no second compile."""
+    from _hlo_text import residual_reduces
+    from paddle_tpu.jit import remat_plan as rp
+    from paddle_tpu.jit import train_step as ts
+
+    lower, candidates = _abstract_train_step(
+        topo, monkeypatch, preset, batch, 1024, sharded)
+    compiled = {}
+
+    def need_of(names):
+        compiled[names] = lower(names).compile()
+        return ts._need_bytes(compiled[names].memory_analysis())
+
+    limit = V5E_BYTES_LIMIT - rp.SPARE_BYTES
+    saved = rp.fit_remat_plan(candidates, limit, need_of)
+    assert saved["names"] == kept
+    assert list(compiled) == ([(), tuple(kept)] if kept else [()])
+    assert saved["need_bytes"] <= limit
+    bare = compiled[()]
+    if not kept:
+        assert 0 <= saved["spare_bytes"] < min(c.bytes for c in candidates)
+        assert "tpu_custom_call" in bare.as_text()
+        return
+    chosen = compiled[tuple(kept)]
+    residual = r"bf16\[16,1024,2048\]"
+    assert residual_reduces(bare.as_text(), residual) == [2, 3]
+    assert residual_reduces(chosen.as_text(), residual) == [2, 2]
+    grown = chosen.memory_analysis().temp_size_in_bytes \
+        - bare.memory_analysis().temp_size_in_bytes
+    assert saved["bytes"] == 24 * (64 + 96) * 2**20
+    assert abs(grown - saved["bytes"]) < 0.1 * saved["bytes"]
+    assert 13 * 2**30 < saved["need_bytes"] < 14.75 * 2**30
