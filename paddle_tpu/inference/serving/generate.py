@@ -179,6 +179,7 @@ inventory:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
 import math
@@ -355,9 +356,10 @@ class GPTPasses:
     and None rides the programs' signatures as an empty pytree."""
 
     name = "gpt"
-    # its programs stay unnamed (`jit__unknown`), as they have always been:
-    # a name is part of a program's text and so of its compile-cache key
-    program_prefix = None
+    # `jit_gpt_<family>_c<cap>_b<bucket>` on the profiler's `XLA Modules`
+    # line and in the compiled text (`_program`); the name is all of the
+    # text that differs from the unnamed programs' (`jit__unknown`)
+    program_prefix = "gpt"
     refuses: dict = {}
     state_dtype = None
 
@@ -723,6 +725,17 @@ def _kvput_body(buf_k, buf_v, slot, kd, ks, vd, vs):
             _kvq.set_row_raw(buf_v, slot, vd, vs))
 
 
+# what a pass that admits nothing runs under: no span, tracing on or off
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _read_args(phase: Optional[dict], step: int) -> Optional[dict]:
+    """The worker loop's span args (None with tracing off) for the `.wait`
+    that reads step `step` and the `generate.emit` that follows it — a
+    dictionary of the pair's own: the emit adds what it measured."""
+    return None if phase is None else {**phase, "read_step": step}
+
+
 def _named(fn, name: str):
     """`fn` under a name jax can give the jitted program."""
     def call(*args):
@@ -851,11 +864,12 @@ class _Launched:
     its rows in launch order, what they produce (device futures) and what
     the step's counters need once the rows are known to be real."""
 
-    __slots__ = ("rows", "prog_key", "kv_reads", "ahead", "nxt", "nkeys",
-                 "aux")
+    __slots__ = ("step", "rows", "prog_key", "kv_reads", "ahead", "nxt",
+                 "nkeys", "aux")
 
-    def __init__(self, rows, prog_key, kv_reads, ahead, nxt, nkeys,
+    def __init__(self, step, rows, prog_key, kv_reads, ahead, nxt, nkeys,
                  aux=None):
+        self.step = step              # the worker's number for it, at launch
         self.rows = rows
         self.prog_key = prog_key      # (device, "decode", cap, bucket)
         self.kv_reads = kv_reads      # per row: pool positions its read copies
@@ -909,11 +923,17 @@ class _ClassState:
     a slot, the scratch row included, in the model's `state_dtype`), None
     for a model that has none; it is allocated, donated, carried and
     dropped with the pools. A model with no attention layer has no pools:
-    `buf_k` / `buf_v` are None and `rec` is its whole cache."""
+    `buf_k` / `buf_v` are None and `rec` is its whole cache.
+
+    `read_at`, `admits`, `restaged` are what `_emit_step` names the gap
+    between two step reads from, the worker thread's own as `dev` is: when
+    the class's last step was read (`time.monotonic()`; None while the
+    class has held no row since), how many prefills / imports ran for it
+    since, and whether its rows were staged anew since."""
 
     __slots__ = ("cap", "n_slots", "buf_k", "buf_v", "rec", "free", "rows",
                  "pc_slots", "pcache", "pc_free", "dbuf_k", "dbuf_v",
-                 "dev")
+                 "dev", "read_at", "admits", "restaged")
 
     def __init__(self, cap: int, n_slots: int, buf_k, buf_v,
                  pc_slots: int = 0, dbuf_k=None, dbuf_v=None, rec=None):
@@ -934,11 +954,30 @@ class _ClassState:
         self.dbuf_k = dbuf_k
         self.dbuf_v = dbuf_v
         self.dev: Optional[_DeviceRows] = None
+        self.read_at: Optional[float] = None
+        self.admits = 0
+        self.restaged = False
+
+    def admitted(self) -> None:
+        """A prefill or an import ran for this class (the worker thread,
+        under the engine lock, before the row goes into `rows`): the next
+        step read follows an admission — or, where the class held no row,
+        follows nothing."""
+        if not self.rows:
+            self.read_at = None
+        self.admits += 1
 
 
 # ===================================================================
 # metrics
 # ===================================================================
+# What the worker did between two reads of a class's steps, as `_emit_step`
+# derives it: launched the step from the last one's outputs and nothing
+# else ("steady"), staged the rows anew because one finished or migrated
+# ("rowset"), or ran at least one prefill or import ("admission").
+STEP_GAP_CAUSES = ("steady", "rowset", "admission")
+
+
 def track_engine(engine) -> None:
     _REGISTRY.track(engine)
 
@@ -989,7 +1028,8 @@ _REGISTRY = _sm.EngineRegistry("generative", aggregate_snapshot)
                "handoffs_in_total", "migrations_out_total",
                "handoff_bytes_total", "kv_positions_read_total",
                "kv_positions_capacity_total", "state_bytes_moved_total",
-               "moe_assignments_total",
+               "step_gaps_total", "step_gap_seconds_total",
+               "step_gap_tokens_total", "moe_assignments_total",
                "moe_expert_tokens", "moe_distinct_experts_total",
                "moe_layer_steps_total")
 class GenerativeMetrics:
@@ -1018,6 +1058,12 @@ class GenerativeMetrics:
         self.kv_positions_read_total = 0      # positions the steps read
         self.kv_positions_capacity_total = 0  # real rows x class cap
         self.state_bytes_moved_total = 0  # fixed-size state, read + written
+        # the gap between two reads of a class's steps, by its cause: every
+        # live row of a step gets its token(s) at the read, so weighted by
+        # tokens this is the engine's own inter-token gap
+        self.step_gaps_total = dict.fromkeys(STEP_GAP_CAUSES, 0)
+        self.step_gap_seconds_total = dict.fromkeys(STEP_GAP_CAUSES, 0.0)
+        self.step_gap_tokens_total = dict.fromkeys(STEP_GAP_CAUSES, 0)
         self.draft_steps_total = 0        # fused k-step draft bursts
         self.spec_steps_total = 0         # target verify passes
         self.spec_proposed_total = 0      # draft tokens offered (k-1/row)
@@ -1075,13 +1121,22 @@ class GenerativeMetrics:
 
     def on_step(self, rows: int, bucket: int, kv_read: int = 0,
                 kv_capacity: int = 0, ahead: bool = False,
-                state_bytes: int = 0):
+                state_bytes: int = 0, cause: Optional[str] = None,
+                gap_s: float = 0.0, tokens: int = 0):
         """One decode step of `rows` real rows in a batch bucket;
         `kv_read` of the rows' `kv_capacity` (rows x class cap) pool
         positions were read by the step's attention, and `state_bytes` of
         the model's fixed-size state were read and written. `ahead`: it
-        was launched while an earlier step was still unread."""
+        was launched while an earlier step was still unread. `cause` (one
+        of STEP_GAP_CAUSES; None for the first read of a class that held
+        no row, which follows no read): what the worker did in the `gap_s`
+        seconds since the class's previous step was read, and `tokens`
+        the rows got at this one."""
         with self._lock:
+            if cause is not None:
+                self.step_gaps_total[cause] += 1
+                self.step_gap_seconds_total[cause] += gap_s
+                self.step_gap_tokens_total[cause] += tokens
             self.steps_total += 1
             self.steps_ahead_total += bool(ahead)
             self.step_rows_total += rows
@@ -1219,6 +1274,14 @@ class GenerativeMetrics:
                     self.kv_positions_read_total,
                     self.kv_positions_capacity_total),
                 "state_bytes_moved_total": self.state_bytes_moved_total,
+                # flat scalars: aggregate_snapshot sums those, and a
+                # reader subtracts two snapshots key by key
+                **{f"step_gaps_{c}_total": n
+                   for c, n in self.step_gaps_total.items()},
+                **{f"step_gap_seconds_{c}_total": n
+                   for c, n in self.step_gap_seconds_total.items()},
+                **{f"step_gap_tokens_{c}_total": n
+                   for c, n in self.step_gap_tokens_total.items()},
                 "draft_steps_total": self.draft_steps_total,
                 "spec_steps_total": self.spec_steps_total,
                 "spec_proposed_total": self.spec_proposed_total,
@@ -1317,6 +1380,19 @@ class GenerativeMetrics:
         metric("paddle_generate_state_bytes_moved_total", "counter",
                s["state_bytes_moved_total"],
                "bytes of fixed-size state the decode steps read and wrote")
+        for name, help_ in (
+                ("step_gaps",
+                 "reads of a decode step that followed another read of its "
+                 "class, by what the worker did between them"),
+                ("step_gap_seconds",
+                 "seconds between two reads of a class's decode steps"),
+                ("step_gap_tokens",
+                 "tokens the rows got at the read that closed the gap")):
+            lines.append(f"# HELP paddle_generate_{name}_total {help_}")
+            lines.append(f"# TYPE paddle_generate_{name}_total counter")
+            for c in STEP_GAP_CAUSES:
+                lines.append(f'paddle_generate_{name}_total{{cause="{c}"}} '
+                             f'{s[f"{name}_{c}_total"]}')
         metric("paddle_generate_kv_pool_bytes", "gauge",
                s["kv_pool"].get("pool_bytes", 0),
                "cache bytes across active replicas: K/V pools, a fixed-size "
@@ -1673,25 +1749,24 @@ class GenerativeEngine:
                 donate = (1, 2, 10)     # the pools and the state beside them
             else:
                 donate = (1, 2)
-            prefix = self._model.program_prefix
-            if prefix and kind in ("prefill", "decode"):
-                # a name of its own on the profiler's `XLA Modules` line
-                # and in the compiled text: instruction names repeat
-                # between programs, and what joins a device operation to
-                # its scope is keyed by the program's name (trace.py)
-                body = _named(body, f"{prefix}_{kind}_c{cap}_b{bucket}")
-            prog = jax.jit(body, donate_argnums=donate)
+            # a name of its own on the profiler's `XLA Modules` line and
+            # in the compiled text: the device's time for a decode step or
+            # a prefill is read by it, instruction names repeat between
+            # programs, and what joins a device operation to its scope is
+            # keyed by the program's name (trace.py)
+            name = f"{self._model.program_prefix}_{kind}_c{cap}_b{bucket}"
+            prog = jax.jit(_named(body, name if k == 1 else f"{name}_k{k}"),
+                           donate_argnums=donate)
             self._programs[key] = prog
         return prog
 
     def _warm_call(self, kind: str, cap: int, bucket: int, args: tuple):
         """A warm-up call of program (kind, cap, bucket) on `args` -> its
-        outputs. With tracing on, and for a model whose programs carry
-        names (instruction names repeat between programs, and an unnamed
-        program's map would be another's), it then keeps which scope each
+        outputs. With tracing on it then keeps which scope each
         instruction was traced under (`observability.trace.note_op_scopes`,
-        as `TrainStep` does): a device trace names an operation by its
-        instruction and nothing else.
+        as `TrainStep` does), by the program's name: a device trace names
+        an operation by its instruction and nothing else, and instruction
+        names repeat between programs.
 
         The text is read AFTER the call, from a lowering of the call's own
         shapes and placements: the call has traced the program as an
@@ -1702,7 +1777,7 @@ class GenerativeEngine:
         under, so every program that holds one would get a cache key that
         only traced runs use (read on the chip, PR 33: six decode programs
         and `program_memory`'s compiled anew, 184 s of set-up for 46)."""
-        noting = _tr.enabled() and self._model.program_prefix
+        noting = _tr.enabled()
         shapes = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=a.sharding),
@@ -2718,7 +2793,8 @@ class GenerativeEngine:
             rows[slot] = row.length
 
     def _prefill_one(self, w: ReplicaSlot, gen: int, cs: _ClassState,
-                     slot: int, req: _GenRequest) -> None:
+                     slot: int, req: _GenRequest,
+                     phase: Optional[dict] = None) -> None:
         P = int(req.prompt.size)
         bounds = [b for b in self._prompt_boundaries if b <= cs.cap]
         S = bucket_for(P, bounds)
@@ -2763,7 +2839,8 @@ class GenerativeEngine:
         args = None
         if _tr.enabled():
             args = {"replica": w.rid, "bucket": S, "prompt_tokens": P,
-                    "cap": cs.cap, "prefix_hit": hitF or 0}
+                    "cap": cs.cap, "prefix_hit": hitF or 0,
+                    **(phase or {})}
         if not self._busy(w, gen, prog_keys):
             return
         try:
@@ -2847,6 +2924,7 @@ class GenerativeEngine:
         with self._cv:
             if w.generation != gen:
                 return
+            cs.admitted()
             cs.rows[slot] = _Row(req, slot, P, key=kcar)
             self._update_liveness_locked(w, cs)
         if status == "done":
@@ -2911,7 +2989,10 @@ class GenerativeEngine:
         restage's device_puts: nothing on a step ahead), `.launch` (the
         program call until it returns) and `.wait` (the blocking read of
         the oldest unread step, where one is due), then `generate.emit`.
-        A read with no launch is a bare `.wait` + `generate.emit`."""
+        A read with no launch is a bare `.wait` + `generate.emit`. The
+        launched step's span carries its number (`step`); the `.wait`
+        that reads a step and the `generate.emit` after it carry that
+        step's (`read_step`): a launch and its read pair by number."""
         with self._cv:
             if w.generation != gen:
                 return
@@ -2953,9 +3034,18 @@ class GenerativeEngine:
         plan = self._kv_plan("decode", cs.cap)
         kv_reads = [0 if not self._L else cs.cap if plan is None else
                     plan.positions_read(x, cs.cap) for x in positions]
-        args = None
+        if not self._busy(w, gen, [prog_key]):
+            return
+        w.launched += 1
+        # the step this pass reads, where one is due: the oldest unread
+        # once this pass's own is on the queue
+        oldest = dev.unread[0] if behind >= _STEPS_AHEAD else None
+        args, read_args = None, phase
         if _tr.enabled():
-            args = {"replica": w.rid, "rows": n, "bucket": bucket,
+            if oldest is not None:
+                read_args = _read_args(phase, oldest.step)
+            args = {"replica": w.rid, "step": w.launched, "rows": n,
+                    "bucket": bucket,
                     "cap": cs.cap, "kv_read": sum(kv_reads),
                     "state_bytes": self._model.state_step_bytes(n, bucket),
                     "spec_k": 0,
@@ -2963,8 +3053,6 @@ class GenerativeEngine:
                     "staged": 0 if fresh is None else len(fresh),
                     "traces": [r.req.ctx.trace_id for r in rows
                                if r.req.ctx is not None]}
-        if not self._busy(w, gen, [prog_key]):
-            return
         read = None
         try:
             # hang/raise injection for the watchdog + requeue ladder:
@@ -2984,6 +3072,7 @@ class GenerativeEngine:
                         cs.dev = dev = _DeviceRows(
                             rows, (slots, temps, topks, topps), toks,
                             lens, keys)
+                        cs.restaged = True
                 with _tr.span("generate.decode_step.launch", "serving",
                               phase):
                     slots, temps, topks, topps = dev.fixed
@@ -2998,12 +3087,12 @@ class GenerativeEngine:
                     for out in jax.tree.leaves((nxt, nkeys, aux)):
                         out.copy_to_host_async()
                     dev.unread.append(_Launched(
-                        rows, prog_key, kv_reads, behind > 0, nxt, nkeys,
-                        aux))
+                        w.launched, rows, prog_key, kv_reads, behind > 0,
+                        nxt, nkeys, aux))
                     del stale
                 with _tr.span("generate.decode_step.wait", "serving",
-                              phase):
-                    if len(dev.unread) > _STEPS_AHEAD:
+                              read_args):
+                    if oldest is not None:
                         read = self._wait_oldest(dev)
                         if read[3] is not None:
                             # of the step this pass READ: the launched
@@ -3012,8 +3101,8 @@ class GenerativeEngine:
         finally:
             self._idle(w, gen, batches=1)
         if read is not None:
-            with _tr.span("generate.emit", "serving", phase):
-                self._emit_read(w, gen, cs, *read)
+            with _tr.span("generate.emit", "serving", read_args) as emit:
+                self._emit_read(w, gen, cs, emit, *read)
 
     @staticmethod
     def _row_arrays(rows: list, bucket: int, scratch: int) -> list:
@@ -3049,25 +3138,27 @@ class GenerativeEngine:
                 tuple(np.asarray(a) for a in step.aux))
 
     def _emit_read(self, w: ReplicaSlot, gen: int, cs: _ClassState,
-                   step: _Launched, nxt, nkeys, experts=None) -> bool:
+                   span, step: _Launched, nxt, nkeys, experts=None) -> bool:
         return self._emit_step(
             w, gen, cs, step.rows, [step.prog_key], step.prog_key[3],
             step.kv_reads, [[int(t)] for t in nxt[:len(step.rows)]],
-            nkeys, step.ahead, experts)
+            nkeys, span, step.ahead, experts)
 
     def _read_oldest(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                      phase: Optional[dict]) -> bool:
         """Read and emit the oldest launched step with no launch beside
         it. False where the worker was superseded meanwhile."""
-        if not self._busy(w, gen, [cs.dev.unread[0].prog_key]):
+        oldest = cs.dev.unread[0]
+        if not self._busy(w, gen, [oldest.prog_key]):
             return False
+        args = _read_args(phase, oldest.step)
         try:
-            with _tr.span("generate.decode_step.wait", "serving", phase):
+            with _tr.span("generate.decode_step.wait", "serving", args):
                 read = self._wait_oldest(cs.dev)
         finally:
             self._idle(w, gen)
-        with _tr.span("generate.emit", "serving", phase):
-            return self._emit_read(w, gen, cs, *read)
+        with _tr.span("generate.emit", "serving", args) as emit:
+            return self._emit_read(w, gen, cs, emit, *read)
 
     def _settle(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                 phase: Optional[dict] = None) -> bool:
@@ -3087,8 +3178,9 @@ class GenerativeEngine:
         the host's look at its proposals, one target verify pass. The
         host decides in the middle of every step, so this loop stays
         closed — stage, launch, read, emit, and only then the next step's
-        arrays. Spans as in `_decode_step`, `.stage` / `.launch` /
-        `.wait` twice each."""
+        arrays (every step stages its rows anew: none of its gaps counts
+        as steady). Spans as in `_decode_step`, `.stage` / `.launch` /
+        `.wait` twice each; the verify pass's `.wait` reads the step."""
         with self._cv:
             if w.generation != gen:
                 return
@@ -3112,15 +3204,18 @@ class GenerativeEngine:
                      (devk, "verify", cs.cap, bucket)]
         # verify reads every position of the rows (the gather)
         kv_reads = [cs.cap] * n
-        args = None
+        if not self._busy(w, gen, prog_keys):
+            return
+        w.launched += 1
+        args = read_args = None
         if _tr.enabled():
-            args = {"replica": w.rid, "rows": n, "bucket": bucket,
+            args = {"replica": w.rid, "step": w.launched, "rows": n,
+                    "bucket": bucket,
                     "cap": cs.cap, "kv_read": n * cs.cap, "spec_k": k,
                     "ahead": 0, "staged": len(fresh) + 1,
                     "traces": [r.req.ctx.trace_id for r in rows
                                if r.req.ctx is not None]}
-        if not self._busy(w, gen, prog_keys):
-            return
+            read_args = _read_args(phase, w.launched)
         try:
             _chaos.hit("serving.decode_step", replica=w.rid,
                        generation=gen)
@@ -3130,6 +3225,7 @@ class GenerativeEngine:
                 with _tr.span("generate.decode_step.stage", "serving",
                               phase):
                     staged = [put(a) for a in fresh]
+                    cs.restaged = True
                 # `staged` is dropped inside the last launch: its device
                 # buffers are freed while the program runs, not between
                 # two steps.
@@ -3158,12 +3254,12 @@ class GenerativeEngine:
                             cs.buf_k, cs.buf_v, *staged)
                     del staged
                 with _tr.span("generate.decode_step.wait", "serving",
-                              phase):
+                              read_args):
                     ys = np.asarray(ys)            # [bucket, k]
                     khist = np.asarray(khist)      # [bucket, k, 2]
         finally:
             self._idle(w, gen, batches=1)
-        with _tr.span("generate.emit", "serving", phase):
+        with _tr.span("generate.emit", "serving", read_args) as emit:
             # accept the longest agreed prefix per row: ys[i, j] is
             # the target's OWN token at position j (same key chain as
             # plain decode), valid while every earlier draft proposal
@@ -3181,11 +3277,11 @@ class GenerativeEngine:
             self._emit_step(
                 w, gen, cs, rows, prog_keys, bucket, kv_reads,
                 [[int(t) for t in ys[i, :m]] for i, m in enumerate(ms)],
-                [khist[i, m - 1] for i, m in enumerate(ms)])
+                [khist[i, m - 1] for i, m in enumerate(ms)], emit)
 
     def _emit_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                    rows: list, prog_keys: list, bucket: int,
-                   kv_reads: list, toks: list, keys,
+                   kv_reads: list, toks: list, keys, span,
                    ahead: bool = False, experts=None) -> bool:
         """What follows a decode step's read on the worker thread: row i
         takes the tokens toks[i] (one, or a speculative burst's accepted
@@ -3195,7 +3291,16 @@ class GenerativeEngine:
         step was read (it ended on an earlier step's EOS while this one
         was on the queue) was launched in vain: its token is discarded
         and the step's counters leave it out. False where the worker was
-        superseded."""
+        superseded.
+
+        This is where a row is handed its next token, so the gap between
+        a row's tokens is measured here: the time since the class's
+        previous step was read, with its cause as the class state holds
+        it (STEP_GAP_CAUSES) — to the counters with the step, and with
+        the live rows onto `span`, the `generate.emit` this runs under.
+        A step that emits to no row hands nothing over and closes no gap;
+        neither does the first read of a class that had held no row."""
+        now = time.monotonic()
         with self._cv:
             for pk in prog_keys:
                 self._warmed.add(pk)
@@ -3208,13 +3313,25 @@ class GenerativeEngine:
                 rows[i].key = np.array(keys[i], np.uint32)
             self._update_liveness_locked(w, cs)
         if live:
+            cause, gap = None, 0.0
+            if cs.read_at is not None:
+                gap = now - cs.read_at
+                cause = "admission" if cs.admits else \
+                    "rowset" if cs.restaged else "steady"
+            cs.read_at, cs.admits, cs.restaged = now, 0, False
             # the state moved is the launched rows': one that had left its
             # slot by the read was stepped all the same
             self.metrics.on_step(len(live), bucket,
                                  sum(kv_reads[i] for i in live),
                                  len(live) * cs.cap if self._L else 0, ahead,
                                  self._model.state_step_bytes(len(rows),
-                                                              bucket))
+                                                              bucket),
+                                 cause=cause, gap_s=gap,
+                                 tokens=sum(len(toks[i]) for i in live))
+            if _tr.enabled():
+                span.set(rows=len(live))
+                if cause is not None:
+                    span.set(cause=cause, gap_ms=gap * 1e3)
         if experts is not None:
             # the program counted the rows it was launched with; a row
             # that had left its slot by then was one of them
@@ -3262,37 +3379,36 @@ class GenerativeEngine:
             key = np.array(row.key, np.uint32, copy=True)
             tokens = [int(t) for t in req.tokens]
             sent = int(req.streamed if streamed is None else streamed)
-        with _tr.span("generate.kv_export", "serving", parent=req.ctx):
-            with _cc.donated_cpu_guard(self._donate):
-                kd, ks, vd, vs = self._program("kvget", cs.cap, 1)(
-                    cs.buf_k, cs.buf_v,
-                    jax.device_put(np.int32(slot), w.device))
-            # the pool stores heads folded; the wire keeps
-            # [L, cap, H, Dh] (a view, host-side)
-            wire = (self._L, int(cs.cap), self._H, self._Dh)
-            arrays = {"prompt": np.asarray(req.prompt, np.int32),
-                      "key": key, "k": np.asarray(kd).reshape(wire),
-                      "v": np.asarray(vd).reshape(wire)}
-            if ks is not None:
-                arrays["k_scale"] = np.asarray(ks)
-                arrays["v_scale"] = np.asarray(vs)
-            P = int(req.prompt.size)
-            lineage = []
-            for F in reversed([b for b in self._prompt_boundaries
-                               if b <= cs.cap]):
-                if F < P:
-                    lineage.append([int(F), _prefix_hash(req.prompt, F)])
-                    break
-            meta = {"cap": int(cs.cap), "kv_dtype": self._kv_dtype,
-                    "shape": list(wire),
-                    "length": length, "tokens": tokens,
-                    "streamed": sent, "max_new": int(req.max_new),
-                    "eos": None if req.eos is None else int(req.eos),
-                    "temperature": float(req.temperature),
-                    "top_k": int(req.top_k),
-                    "top_p": float(req.top_p), "seed": int(req.seed),
-                    "requeues": int(req.requeues), "lineage": lineage}
-            return _ho.encode(meta, arrays)
+        with _cc.donated_cpu_guard(self._donate):
+            kd, ks, vd, vs = self._program("kvget", cs.cap, 1)(
+                cs.buf_k, cs.buf_v,
+                jax.device_put(np.int32(slot), w.device))
+        # the pool stores heads folded; the wire keeps
+        # [L, cap, H, Dh] (a view, host-side)
+        wire = (self._L, int(cs.cap), self._H, self._Dh)
+        arrays = {"prompt": np.asarray(req.prompt, np.int32),
+                  "key": key, "k": np.asarray(kd).reshape(wire),
+                  "v": np.asarray(vd).reshape(wire)}
+        if ks is not None:
+            arrays["k_scale"] = np.asarray(ks)
+            arrays["v_scale"] = np.asarray(vs)
+        P = int(req.prompt.size)
+        lineage = []
+        for F in reversed([b for b in self._prompt_boundaries
+                           if b <= cs.cap]):
+            if F < P:
+                lineage.append([int(F), _prefix_hash(req.prompt, F)])
+                break
+        meta = {"cap": int(cs.cap), "kv_dtype": self._kv_dtype,
+                "shape": list(wire),
+                "length": length, "tokens": tokens,
+                "streamed": sent, "max_new": int(req.max_new),
+                "eos": None if req.eos is None else int(req.eos),
+                "temperature": float(req.temperature),
+                "top_k": int(req.top_k),
+                "top_p": float(req.top_p), "seed": int(req.seed),
+                "requeues": int(req.requeues), "lineage": lineage}
+        return _ho.encode(meta, arrays)
 
     def _refuse_handoff(self, call: str) -> None:
         """409 where the model's cache cannot ride the handoff wire: a row
@@ -3467,69 +3583,63 @@ class GenerativeEngine:
             prog_keys.append((devk, "dprefill", cs.cap, S))
         if admitF is not None:
             prog_keys.append((devk, "pcopy", cs.cap, 1))
-        args = None
-        if _tr.enabled():
-            args = {"replica": w.rid, "cap": cs.cap, "length": length,
-                    "tokens": len(toks)}
         if not self._busy(w, gen, prog_keys):
             return
         try:
-            with _tr.span("generate.kv_import", "serving", args,
-                          parent=req.ctx):
-                with _cc.donated_cpu_guard(self._donate):
-                    # the wire's [L, cap, H, Dh] row, heads folded as
-                    # the pool stores them (a view, host-side)
-                    row = self._pool_shape(cs.cap)[1:]
-                    kd = put(arrays["k"].reshape(row))
-                    vd = put(arrays["v"].reshape(row))
-                    if self._kv_dtype == "int8":
-                        kparts = (kd, put(arrays["k_scale"]),
-                                  vd, put(arrays["v_scale"]))
-                    else:
-                        kparts = (kd, None, vd, None)
-                    cs.buf_k, cs.buf_v = self._program(
-                        "kvput", cs.cap, 1)(
-                            cs.buf_k, cs.buf_v, put(np.int32(slot)),
-                            *kparts)
-                    if self._spec:
-                        # the draft never ships: rebuild its pool from
-                        # the generated history (prompt + all tokens
-                        # but the pending one) — dprefill at this
-                        # bucket is always in the warmed inventory
-                        hist = np.zeros((1, S), np.int32)
-                        hist[0, :P] = req.prompt
-                        if len(toks) > 1:
-                            hist[0, P:length] = np.asarray(
-                                toks[:-1], np.int32)
-                        _dt, _dk, cs.dbuf_k, cs.dbuf_v, _ = self._program(
-                            "dprefill", cs.cap, S)(
-                                self._draft_params_for(w.device),
-                                cs.dbuf_k, cs.dbuf_v,
-                                put(np.int32(slot)), put(hist),
-                                put(np.int32(length)),
-                                put(np.float32(0.0)), put(np.int32(1)),
-                                put(np.float32(1.0)),
-                                put(np.zeros(2, np.uint32)))
-                    if admitF is not None:
-                        with self._cv:
-                            idx = self._pc_index.setdefault(
-                                (w.rid, cs.cap), set())
-                            evict = not cs.pc_free
-                            if evict:
-                                (evF, evh), crow = cs.pcache.popitem(
-                                    last=False)
-                                idx.discard(f"{evF}:{evh[:8]}")
-                            else:
-                                crow = cs.pc_free.pop()
-                            cs.pcache[(admitF, admit_h)] = crow
-                            idx.add(f"{admitF}:{admit_h[:8]}")
-                        cs.buf_k, cs.buf_v = self._program(
-                            "pcopy", cs.cap, 1)(
-                                cs.buf_k, cs.buf_v,
-                                put(np.int32(slot)),
-                                put(np.int32(crow)))
+            with _cc.donated_cpu_guard(self._donate):
+                # the wire's [L, cap, H, Dh] row, heads folded as
+                # the pool stores them (a view, host-side)
+                row = self._pool_shape(cs.cap)[1:]
+                kd = put(arrays["k"].reshape(row))
+                vd = put(arrays["v"].reshape(row))
+                if self._kv_dtype == "int8":
+                    kparts = (kd, put(arrays["k_scale"]),
+                              vd, put(arrays["v_scale"]))
+                else:
+                    kparts = (kd, None, vd, None)
+                cs.buf_k, cs.buf_v = self._program(
+                    "kvput", cs.cap, 1)(
+                        cs.buf_k, cs.buf_v, put(np.int32(slot)),
+                        *kparts)
+                if self._spec:
+                    # the draft never ships: rebuild its pool from
+                    # the generated history (prompt + all tokens
+                    # but the pending one) — dprefill at this
+                    # bucket is always in the warmed inventory
+                    hist = np.zeros((1, S), np.int32)
+                    hist[0, :P] = req.prompt
+                    if len(toks) > 1:
+                        hist[0, P:length] = np.asarray(
+                            toks[:-1], np.int32)
+                    _dt, _dk, cs.dbuf_k, cs.dbuf_v, _ = self._program(
+                        "dprefill", cs.cap, S)(
+                            self._draft_params_for(w.device),
+                            cs.dbuf_k, cs.dbuf_v,
+                            put(np.int32(slot)), put(hist),
+                            put(np.int32(length)),
+                            put(np.float32(0.0)), put(np.int32(1)),
+                            put(np.float32(1.0)),
+                            put(np.zeros(2, np.uint32)))
+                if admitF is not None:
+                    with self._cv:
+                        idx = self._pc_index.setdefault(
+                            (w.rid, cs.cap), set())
+                        evict = not cs.pc_free
                         if evict:
-                            self.metrics.on_prefix_evict()
+                            (evF, evh), crow = cs.pcache.popitem(
+                                last=False)
+                            idx.discard(f"{evF}:{evh[:8]}")
+                        else:
+                            crow = cs.pc_free.pop()
+                        cs.pcache[(admitF, admit_h)] = crow
+                        idx.add(f"{admitF}:{admit_h[:8]}")
+                    cs.buf_k, cs.buf_v = self._program(
+                        "pcopy", cs.cap, 1)(
+                            cs.buf_k, cs.buf_v,
+                            put(np.int32(slot)),
+                            put(np.int32(crow)))
+                    if evict:
+                        self.metrics.on_prefix_evict()
         finally:
             self._idle(w, gen)
         with self._cv:
@@ -3539,6 +3649,7 @@ class GenerativeEngine:
                     req.future.done():
                 return
             req.handoff = None
+            cs.admitted()
             # re-emit everything past the exporter's delivered count
             # through the normal _emit path (a prefill handoff records
             # streamed=0 — the client saw nothing yet; a migration
@@ -3621,11 +3732,6 @@ class GenerativeEngine:
                             (done - req.t_enqueue) * 1e3, 3)}
                 if req.future.set_result(info):
                     req.stream.put(("handoff", obj))
-                if _tr.enabled():
-                    now_ns = time.perf_counter_ns()
-                    _tr.emit_span("generate.migrate", req.t_enq_ns,
-                                  now_ns, parent=req.ctx, cat="serving",
-                                  args={"n_tokens": len(req.tokens)})
 
     def _worker_loop(self, w: ReplicaSlot, gen: int) -> None:
         # per-GENERATION device state: a revived worker starts from
@@ -3637,8 +3743,13 @@ class GenerativeEngine:
         # idle, and a bare decode_step.wait where a pass reads a step and
         # launches none); those of one pass carry the same `iter`, so a
         # reader adds up a pass's phases without guessing from times. A
-        # class's launched, unread steps live in its state (`cs.dev`) from
-        # one pass to the next and die with it
+        # pass that admits runs, from the admit's end on, under ONE span,
+        # `generate.admission`: its self time is the worker's own code
+        # between the others, and an idle gap of the device during an
+        # admission has a name even where this thread was not the one
+        # running. A steady pass has no such bracket. A class's launched,
+        # unread steps live in its state (`cs.dev`) from one pass to the
+        # next and die with it
         it = 0
         while True:
             it += 1
@@ -3651,52 +3762,26 @@ class GenerativeEngine:
                 admitted = self._admit_locked(w, gen, state) \
                     if admit_ok else []
                 depth = len(self._queue)
-            if phase is not None:
-                now_ns = time.perf_counter_ns()
-                for req, _cs, _slot in admitted:
-                    _tr.emit_span(
-                        "generate.queue_wait", req.t_enq_ns, now_ns,
-                        parent=req.ctx, cat="serving",
-                        args={"prompt_tokens": int(req.prompt.size),
-                              "queue_depth": depth, **phase})
+            admission = _NO_SPAN
+            if admitted:
+                args = None
+                if phase is not None:
+                    now_ns = time.perf_counter_ns()
+                    for req, _cs, _slot in admitted:
+                        _tr.emit_span(
+                            "generate.queue_wait", req.t_enq_ns, now_ns,
+                            parent=req.ctx, cat="serving",
+                            args={"prompt_tokens": int(req.prompt.size),
+                                  "queue_depth": depth, **phase})
+                    args = {**phase, "admitted": len(admitted),
+                            "prompt_tokens": sum(
+                                int(req.prompt.size)
+                                for req, _cs, _slot in admitted)}
+                admission = _tr.span("generate.admission", "serving", args)
             try:
-                for req, cs, slot in admitted:
-                    if req.handoff is not None:
-                        self._import_one(w, gen, cs, slot, req)
-                    else:
-                        self._prefill_one(w, gen, cs, slot, req)
-                with self._cv:
-                    migrating = self._migrate_streams and \
-                        w.generation == gen
-                if migrating:
-                    self._migrate_rows(w, gen, state, phase)
-                active = sum(len(cs.rows) for cs in state.values())
-                if active == 0:
-                    with self._cv:
-                        if w.generation != gen:
-                            return
-                        queue_live = bool(self._queue) and not self._abort
-                        if w.state in ("draining", "retired") or \
-                                (self._closing and not queue_live):
-                            w.state = "retired"
-                            self._cv.notify_all()
-                            return
-                        if not queue_live:
-                            with _tr.span("generate.idle", "serving",
-                                          phase):
-                                self._cv.wait(0.05)
-                    continue
-                with self._cv:
-                    aborting = self._abort
-                if aborting:
-                    self._fail_rows(
-                        w, gen, state,
-                        ServingError(503, "server shutting down"))
-                    continue
-                step = self._spec_step if self._spec else self._decode_step
-                for cs in state.values():
-                    if cs.rows:
-                        step(w, gen, cs, phase)
+                with admission:
+                    if not self._pass(w, gen, state, phase, admitted):
+                        return
             except Exception as e:  # noqa: BLE001 — last line of
                 # defense: the worker thread must NEVER die (its slots
                 # would leak and the queue would starve); requeue the
@@ -3705,6 +3790,47 @@ class GenerativeEngine:
                     owned = w.generation == gen
                 if owned:
                     self._fail_rows(w, gen, state, e)
+
+    def _pass(self, w: ReplicaSlot, gen: int, state: Dict[int, _ClassState],
+              phase: Optional[dict], admitted: List[tuple]) -> bool:
+        """The rest of one pass of the worker loop once `admitted` is
+        known: the admitted requests' prefills / imports, a drain's
+        migrations, then one step of every class that holds rows. False
+        where the worker is to end (superseded, retired)."""
+        for req, cs, slot in admitted:
+            if req.handoff is not None:
+                self._import_one(w, gen, cs, slot, req)
+            else:
+                self._prefill_one(w, gen, cs, slot, req, phase)
+        with self._cv:
+            migrating = self._migrate_streams and w.generation == gen
+        if migrating:
+            self._migrate_rows(w, gen, state, phase)
+        if not any(cs.rows for cs in state.values()):
+            with self._cv:
+                if w.generation != gen:
+                    return False
+                queue_live = bool(self._queue) and not self._abort
+                if w.state in ("draining", "retired") or \
+                        (self._closing and not queue_live):
+                    w.state = "retired"
+                    self._cv.notify_all()
+                    return False
+                if not queue_live:
+                    with _tr.span("generate.idle", "serving", phase):
+                        self._cv.wait(0.05)
+            return True
+        with self._cv:
+            aborting = self._abort
+        if aborting:
+            self._fail_rows(w, gen, state,
+                            ServingError(503, "server shutting down"))
+            return True
+        step = self._spec_step if self._spec else self._decode_step
+        for cs in state.values():
+            if cs.rows:
+                step(w, gen, cs, phase)
+        return True
 
 
 __all__ = ["GenerativeEngine", "GenerateHandle", "GenerativeMetrics",

@@ -90,7 +90,7 @@ class ReplicaSlot:
 
     __slots__ = ("rid", "device", "q", "thread", "state", "generation",
                  "last_beat", "busy_since", "inflight", "batches",
-                 "compiling")
+                 "compiling", "launched")
 
     def __init__(self, rid: int, device, queue_depth: int = 2):
         self.rid = rid
@@ -107,6 +107,10 @@ class ReplicaSlot:
         # executable (key not warmed): the watchdog must not read a
         # legitimate XLA compile as a hang
         self.compiling = False
+        # decode steps this worker has launched: a step's number from its
+        # launch to its read (the generation engine's spans); the worker
+        # thread's own
+        self.launched = 0
 
     def state_row(self, now: Optional[float] = None) -> dict:
         """Watchdog's view: one row with monotonic ages (the
