@@ -9,13 +9,19 @@ pool, re-cut for the XLA compilation contract):
 
 - **Prefill/decode split.** Each request is exactly one prefill call
   (prompt padded to its pow2 seq bucket via io/bucketing, batch dim 1)
-  plus repeated fixed-shape decode steps. Two program families total:
+  plus repeated fixed-shape decode steps. Three program families:
 
-    prefill[S]  (params, pool_k, pool_v, slot, ids[1,S], len) ->
-                (first_token, pool_k', pool_v')
+    prefill[S]  (params, pool_k, pool_v, ids[1,S], row[7], firsts) ->
+                (first_token, key, pool_k', pool_v', firsts')
     decode[b]   (params, pool_k, pool_v, slots[b], tokens[b],
-                 lengths[b]) -> (next_tokens[b], lengths[b] + 1,
-                                 pool_k', pool_v')
+                 lengths[b], ...) -> (next_tokens[b], keys[b],
+                                      lengths[b] + 1, pool_k', pool_v')
+    join[b]     (tokens[b], lengths[b], keys[b], firsts, plan[b, 9]) ->
+                the seven per-row arrays of the next decode[b]
+
+  (`row` is one admitted row's slot, length and sampling packed in one
+  vector; `firsts` the class's first-token table, row s the token and
+  key of slot s's last prefill.)
 
   Every program is memoized per (family, bucket) and pre-compiled
   through the persistent compile cache (core/compile_cache), so a warm
@@ -66,16 +72,23 @@ pool, re-cut for the XLA compilation contract):
   (`_DeviceRows`): in the steady state a pass of the worker is LAUNCH the
   next step, BLOCK on the oldest unread one, EMIT it (`_STEPS_AHEAD`), so
   the device goes from one step into the next while the host reads and
-  streams the one before. What only the host knows — slots, temperature,
-  top-k, top-p — is staged when the row set changes and not otherwise; a
-  row-set change reads everything launched first and stages the seven
-  arrays once. The host's row.length / row.key / req.tokens are current
-  as of the last step READ; whoever takes a row elsewhere (export,
-  migration) settles the launched steps first, and a requeue replays
-  from the tokens emitted. An EOS is learned one step late: the
-  overshoot step wrote past the row's end in its own slot, its token is
-  discarded and it counts as no row. The speculative loop, whose host
-  decides in the middle of every step, stays closed (`_spec_step`).
+  streams the one before. A row-set change that keeps the batch bucket
+  (a row admitted, ended or moved) is made on the device too: a prefill
+  leaves its first token and key in the class's first-token table and
+  does not wait for them, one small plan goes over, and the join program
+  (`_join_body`) builds the next step's seven arrays from the last
+  launched step's outputs and that table — the step is launched ahead
+  like any other, and the first token is read and emitted just before
+  the first step the row is in. Only the class's first fill and a change
+  of bucket read everything launched and stage the seven arrays from the
+  host. The host's row.length / row.key / req.tokens are current as of
+  the last step READ; whoever takes a row elsewhere (export, migration)
+  settles the launched steps and the first tokens first, and a requeue
+  replays from the tokens emitted. An EOS is learned one step late — a
+  first token's too: the overshoot step wrote past the row's end in its
+  own slot, its token is discarded and it counts as no row. The
+  speculative loop, whose host decides in the middle of every step, stays
+  closed (`_spec_step`).
 
 - **The model supplies the step.** What a prefill and a pass over rows
   of the cache COMPUTE is the model's: its configuration's
@@ -430,21 +443,54 @@ def _gpt_prefill(p, buf_k, buf_v, slot, ids, length, num_heads, eps):
     return h_last, buf_k, buf_v
 
 
-def _prefill_body(p, buf_k, buf_v, slot, ids, length, temp, topk, topp,
-                  key, rec=None, *, model):
+def _pack_row(slot, length, temp, topk, topp, key) -> np.ndarray:
+    """One admitted row's host inputs as ONE int32 vector — slot, length,
+    temperature, top-k, top-p, the two words of its key — so that a
+    prefill takes one transfer and not seven (`_unpack_row`)."""
+    out = np.empty(7, np.int32)
+    out[0], out[1], out[3] = slot, length, topk
+    out[2] = np.float32(temp).view(np.int32)
+    out[4] = np.float32(topp).view(np.int32)
+    out[5:7] = np.asarray(key, np.uint32).view(np.int32)
+    return out
+
+
+def _unpack_row(row):
+    """`_pack_row`'s vector in-program -> (slot, length, temperature,
+    top-k, top-p, key): the bits it was packed with."""
+    return (row[0], row[1], jax.lax.bitcast_convert_type(row[2], jnp.float32),
+            row[3], jax.lax.bitcast_convert_type(row[4], jnp.float32),
+            jax.lax.bitcast_convert_type(row[5:7], jnp.uint32))
+
+
+def _keep_first(firsts, slot, tok, key):
+    """Row `slot` of the class's first-token table becomes (token, key):
+    what the join reads for a row that has not run a step yet. None (a
+    draft's prefill) keeps none."""
+    if firsts is None:
+        return None
+    entry = jnp.concatenate([tok.astype(jnp.int32)[None],
+                             jax.lax.bitcast_convert_type(key, jnp.int32)])
+    return firsts.at[slot].set(entry)
+
+
+def _prefill_body(p, buf_k, buf_v, ids, row, firsts, rec=None, *, model):
     """One full-prompt pass, the model's own (`model.prefill`): the
-    prompt's cache into slot `slot` — K/V rows and, where the model keeps
-    one, the fixed-size state `rec` (last, so that a model without one
-    has the signature it always had) — then the first token sampled (or
-    argmax'd) from the logits at position length-1, one key split
-    consumed. ids [1, S] int32."""
+    prompt's cache into the row's slot — K/V rows and, where the model
+    keeps one, the fixed-size state `rec` (last, so that a model without
+    one has the signature it always had) — then the first token sampled
+    (or argmax'd) from the logits at position length-1, one key split
+    consumed. ids [1, S] int32; `row` is `_pack_row`'s vector. The token
+    and the key stay on the device in `firsts` too (`_keep_first`), where
+    the next step's rows are joined from."""
     p = _kvq.dequant_params(p)
+    slot, length, temp, topk, topp, key = _unpack_row(row)
     h_last, buf_k, buf_v, rec = model.prefill(p, buf_k, buf_v, rec, slot,
                                               ids, length)
     key, sub = jax.random.split(key)
     tok = _sample_token(model.head(p, h_last)[None], temp[None], topk[None],
                         topp[None], sub[None])[0]
-    return tok, key, buf_k, buf_v, rec
+    return tok, key, buf_k, buf_v, _keep_first(firsts, slot, tok, key), rec
 
 
 def _kernel_read(q, buf_k, buf_v, layer, slots, pos, interpret=False):
@@ -673,16 +719,20 @@ def _verify_body(p, buf_k, buf_v, slots, tokens, lengths, temps, topks,
     return ys, khist, buf_k, buf_v
 
 
-def _extend_body(p, buf_k, buf_v, slot, ids, start, length, temp, topk,
-                 topp, key, scratch, num_heads, eps):
+def _extend_body(p, buf_k, buf_v, ids, row, firsts, scratch, num_heads,
+                 eps):
     """Prefix-cache tail prefill: slot already holds valid K/V for
     positions [0, start); the T-token tail block ids [1, T] goes through
     the pool pass as one row's T queries at [start, start+T), and the
     first token comes from the hidden state at absolute position
-    length-1. An int8 pool KEEPS the row's scale (set by the cached
-    prefix's original prefill): tail positions quantize with it, clip
-    semantics — the scale-granularity error source DESIGN.md documents."""
+    length-1. `row` is `_pack_row`'s vector with `start` after it; the
+    token and key are kept in `firsts` as a prefill keeps them. An int8
+    pool KEEPS the row's scale (set by the cached prefix's original
+    prefill): tail positions quantize with it, clip semantics — the
+    scale-granularity error source DESIGN.md documents."""
     p = _kvq.dequant_params(p)
+    slot, length, temp, topk, topp, key = _unpack_row(row)
+    start = row[7]
     pos = start + jnp.arange(ids.shape[1], dtype=jnp.int32)   # absolute
     h, buf_k, buf_v = _pool_pass(
         p, buf_k, buf_v, slot.astype(jnp.int32)[None], ids, pos[None],
@@ -692,7 +742,44 @@ def _extend_body(p, buf_k, buf_v, slot, ids, start, length, temp, topk,
     key, sub = jax.random.split(key)
     tok = _sample_token(_gpt.lm_head(p, h_last)[None], temp[None],
                         topk[None], topp[None], sub[None])[0]
-    return tok, key, buf_k, buf_v
+    return tok, key, buf_k, buf_v, _keep_first(firsts, slot, tok, key)
+
+
+# the columns of a join's plan, one row of it an index of the batch bucket
+(_J_SRC, _J_SLOT, _J_LEN, _J_TOK, _J_KEY, _J_TEMP, _J_TOPK,
+ _J_TOPP) = 0, 1, 2, 3, 4, 6, 7, 8
+_J_COLS = 9
+
+
+def _join_body(toks, lens, keys, firsts, plan):
+    """A row-set change that keeps the batch bucket, on the device: the
+    seven arrays of the next decode step (its own order) from the last
+    launched step's rows and the host's `plan` [b, _J_COLS] int32. Index i
+    takes, by its `_J_SRC`: in [0, b), index src of that step's pending
+    token, length and key — a row that goes on; b, the token and key its
+    slot's prefill left in `firsts` and the plan's length — a row that
+    joins before its first step; b + 1, the plan's own token, key and
+    length — a row whose state the host holds (an import) or a padding
+    row. Slot, temperature, top-k and top-p are the plan's for every
+    index. Nothing is computed: greedy and sampled rows are where they
+    would be after a restage, bit for bit."""
+    b = toks.shape[0]
+    src = plan[:, _J_SRC]
+    going = src < b
+    at = jnp.clip(src, 0, b - 1)
+    slots = plan[:, _J_SLOT]
+    first = firsts[slots]                              # [b, 3]
+    tabled = (src == b)[:, None]
+    tok = jnp.where(tabled[:, 0], first[:, 0], plan[:, _J_TOK])
+    key = jax.lax.bitcast_convert_type(
+        jnp.where(tabled, first[:, 1:], plan[:, _J_KEY:_J_KEY + 2]),
+        jnp.uint32)
+    return (slots, jnp.where(going, toks[at], tok),
+            jnp.where(going, lens[at], plan[:, _J_LEN]),
+            jax.lax.bitcast_convert_type(plan[:, _J_TEMP], jnp.float32),
+            plan[:, _J_TOPK],
+            jax.lax.bitcast_convert_type(plan[:, _J_TOPP], jnp.float32),
+            jnp.where(going[:, None], keys[at], key))
 
 
 def _copy_row_body(buf_k, buf_v, src, dst):
@@ -837,16 +924,20 @@ class GenerateHandle:
 
 
 class _Row:
-    __slots__ = ("req", "slot", "length", "key")
+    __slots__ = ("req", "slot", "length", "key", "first")
 
     def __init__(self, req: _GenRequest, slot: int, length: int,
-                 key: Optional[np.ndarray] = None):
+                 key: Optional[np.ndarray] = None, first=None):
         self.req = req
         self.slot = slot
         self.length = length   # cached positions; pending tok = tokens[-1]
         # the row's CURRENT raw uint32[2] PRNG key — advanced one split
         # per emitted token (prefill consumed the first split)
         self.key = key if key is not None else np.zeros(2, np.uint32)
+        # (token, key) of its prefill, on their way to the host, until
+        # the worker emits that token (`_take_firsts`); while it is set the
+        # pending token is not in req.tokens and `key` is not the row's
+        self.first = first
 
 
 # Decode steps the worker launches beyond the oldest one it has not read.
@@ -861,14 +952,17 @@ _STEPS_AHEAD = 1
 
 class _Launched:
     """One decode step on the device's queue that the host has not read:
-    its rows in launch order, what they produce (device futures) and what
-    the step's counters need once the rows are known to be real."""
+    its rows in launch order — its own launch's, so a row that has left
+    its slot by the read is known for one launched in vain — what they
+    produce (device futures), and what the step's counters need once the
+    rows are known to be real. `cause` is fixed at the launch, from what
+    the device's queue held in front of the step (STEP_GAP_CAUSES)."""
 
     __slots__ = ("step", "rows", "prog_key", "kv_reads", "ahead", "nxt",
-                 "nkeys", "aux")
+                 "nkeys", "aux", "cause")
 
     def __init__(self, step, rows, prog_key, kv_reads, ahead, nxt, nkeys,
-                 aux=None):
+                 aux, cause):
         self.step = step              # the worker's number for it, at launch
         self.rows = rows
         self.prog_key = prog_key      # (device, "decode", cap, bucket)
@@ -877,6 +971,7 @@ class _Launched:
         self.nxt = nxt
         self.nkeys = nkeys
         self.aux = aux                # what the pass counted of itself
+        self.cause = cause
 
 
 class _DeviceRows:
@@ -888,22 +983,37 @@ class _DeviceRows:
     step returned them (futures while it runs). `unread` holds the
     launched steps the host has not read, oldest first: the host's
     row.length / row.key / req.tokens are current as of the last step
-    READ, `len(unread)` steps behind the device. Owned by the worker
-    thread; dies with the class state."""
+    READ, as many steps behind the device as hold the row.
+
+    A row-set change that keeps the bucket builds the next rows from these
+    on the device (`_join_body`): the rows that go on keep their state
+    there, a row that joins takes its prefill's token and key from the
+    class's first-token table, and `unread` carries over. Only a change of
+    bucket (or the class's first fill) restages them from the host. Owned
+    by the worker thread; dies with the class state."""
 
     __slots__ = ("rows", "fixed", "toks", "lens", "keys", "unread")
 
-    def __init__(self, rows, fixed, toks, lens, keys):
+    def __init__(self, rows, fixed, toks, lens, keys, unread=None):
         self.rows = rows
         self.fixed = fixed
         self.toks = toks
         self.lens = lens
         self.keys = keys
-        self.unread: "deque[_Launched]" = deque()
+        self.unread: "deque[_Launched]" = deque() if unread is None \
+            else unread
+
+    @property
+    def bucket(self) -> int:
+        return self.toks.shape[0]
 
     def holds(self, rows) -> bool:
         return len(rows) == len(self.rows) and all(
             a is b for a, b in zip(rows, self.rows))
+
+    def behind(self, row) -> int:
+        """Launched steps the host has not read that hold `row`."""
+        return sum(any(r is row for r in s.rows) for s in self.unread)
 
 
 @_shared_state("free", "rows", "pcache", "pc_free")
@@ -923,25 +1033,30 @@ class _ClassState:
     a slot, the scratch row included, in the model's `state_dtype`), None
     for a model that has none; it is allocated, donated, carried and
     dropped with the pools. A model with no attention layer has no pools:
-    `buf_k` / `buf_v` are None and `rec` is its whole cache.
+    `buf_k` / `buf_v` are None and `rec` is its whole cache. `firsts` is
+    the first-token table, [n_slots + 1, 3] int32: row s holds the token
+    and key (its bits) of slot s's last prefill, written by that prefill
+    and read by the join (`_join_body`), donated like the pools.
 
-    `read_at`, `admits`, `restaged` are what `_emit_step` names the gap
-    between two step reads from, the worker thread's own as `dev` is: when
-    the class's last step was read (`time.monotonic()`; None while the
-    class has held no row since), how many prefills / imports ran for it
-    since, and whether its rows were staged anew since."""
+    `read_at` and `admits` are the worker thread's own, as `dev` is: when
+    the class's last step was read (`time.perf_counter()`; None while the
+    class has held no row since), and how many prefills / imports were
+    queued for it since its last launch — what that launch's cause is
+    named from (`launch_cause`)."""
 
-    __slots__ = ("cap", "n_slots", "buf_k", "buf_v", "rec", "free", "rows",
-                 "pc_slots", "pcache", "pc_free", "dbuf_k", "dbuf_v",
-                 "dev", "read_at", "admits", "restaged")
+    __slots__ = ("cap", "n_slots", "buf_k", "buf_v", "rec", "firsts",
+                 "free", "rows", "pc_slots", "pcache", "pc_free", "dbuf_k",
+                 "dbuf_v", "dev", "read_at", "admits")
 
     def __init__(self, cap: int, n_slots: int, buf_k, buf_v,
-                 pc_slots: int = 0, dbuf_k=None, dbuf_v=None, rec=None):
+                 pc_slots: int = 0, dbuf_k=None, dbuf_v=None, rec=None,
+                 firsts=None):
         self.cap = cap
         self.n_slots = n_slots
         self.buf_k = buf_k
         self.buf_v = buf_v
         self.rec = rec
+        self.firsts = firsts
         self.free: List[int] = list(range(n_slots))
         self.rows: Dict[int, _Row] = {}
         self.pc_slots = int(pc_slots)
@@ -956,25 +1071,34 @@ class _ClassState:
         self.dev: Optional[_DeviceRows] = None
         self.read_at: Optional[float] = None
         self.admits = 0
-        self.restaged = False
 
     def admitted(self) -> None:
         """A prefill or an import ran for this class (the worker thread,
         under the engine lock, before the row goes into `rows`): the next
-        step read follows an admission — or, where the class held no row,
-        follows nothing."""
+        step launched is queued behind it — or, where the class held no
+        row, its read follows nothing."""
         if not self.rows:
             self.read_at = None
         self.admits += 1
+
+    def launch_cause(self, restaged: bool) -> str:
+        """The cause of the step being launched (STEP_GAP_CAUSES), and the
+        admissions it is behind are spent."""
+        cause = "admission" if self.admits else \
+            "rowset" if restaged else "steady"
+        self.admits = 0
+        return cause
 
 
 # ===================================================================
 # metrics
 # ===================================================================
-# What the worker did between two reads of a class's steps, as `_emit_step`
-# derives it: launched the step from the last one's outputs and nothing
-# else ("steady"), staged the rows anew because one finished or migrated
-# ("rowset"), or ran at least one prefill or import ("admission").
+# What was queued on the device in front of a step, as its launch names it
+# and `_emit_step` counts it at the step's read — the gap a row waits for
+# that step's token: at least one prefill or import ("admission"), the
+# rows staged anew from the host because the bucket changed or the class
+# held no device rows ("rowset"), or nothing but the step, its rows taken
+# from the last one's outputs or joined on the device ("steady").
 STEP_GAP_CAUSES = ("steady", "rowset", "admission")
 
 
@@ -1007,6 +1131,9 @@ def aggregate_snapshot() -> Optional[dict]:
                                     out["kv_positions_capacity_total"])
     out["launch_ahead_share"] = _sm.rate(out["steps_ahead_total"],
                                          out["steps_total"])
+    out["rowset_join_share"] = _sm.rate(
+        out["rowset_joins_total"],
+        out["rowset_joins_total"] + out["restages_total"])
     out["engines"] = len(snaps)
     return out
 
@@ -1018,7 +1145,8 @@ _REGISTRY = _sm.EngineRegistry("generative", aggregate_snapshot)
                "shed_total", "rejected_total", "requeues_total",
                "tokens_out_total", "prompt_tokens_total",
                "prefills_total", "steps_total", "steps_ahead_total",
-               "step_rows_total", "step_padded_rows_total",
+               "restages_total", "rowset_joins_total", "step_rows_total",
+               "step_padded_rows_total",
                "occupancy_hist", "_ttft",
                "_latency", "_token_stamps", "draft_steps_total",
                "spec_steps_total", "spec_proposed_total",
@@ -1053,6 +1181,8 @@ class GenerativeMetrics:
         self.prefills_total = 0
         self.steps_total = 0
         self.steps_ahead_total = 0        # launched, an earlier one unread
+        self.restages_total = 0           # launched from rows the host staged
+        self.rowset_joins_total = 0       # row-set changes made on the device
         self.step_rows_total = 0          # real rows over all steps
         self.step_padded_rows_total = 0   # pad rows added by batch bucket
         self.kv_positions_read_total = 0      # positions the steps read
@@ -1146,6 +1276,15 @@ class GenerativeMetrics:
             self.state_bytes_moved_total += int(state_bytes)
             self.occupancy_hist[rows] = \
                 self.occupancy_hist.get(rows, 0) + 1
+
+    def on_rowset(self, joined: bool):
+        """A step launched on a new row set: its rows joined on the device
+        (`_join_body`), or staged anew from the host."""
+        with self._lock:
+            if joined:
+                self.rowset_joins_total += 1
+            else:
+                self.restages_total += 1
 
     def on_experts(self, per_expert, distinct: int, layers: int):
         """One decode step of a routed model: its tokens per expert summed
@@ -1265,6 +1404,11 @@ class GenerativeMetrics:
                 "steps_ahead_total": self.steps_ahead_total,
                 "launch_ahead_share": _sm.rate(self.steps_ahead_total,
                                                self.steps_total),
+                "restages_total": self.restages_total,
+                "rowset_joins_total": self.rowset_joins_total,
+                "rowset_join_share": _sm.rate(
+                    self.rowset_joins_total,
+                    self.rowset_joins_total + self.restages_total),
                 "step_rows_total": self.step_rows_total,
                 "step_padded_rows_total": self.step_padded_rows_total,
                 "kv_positions_read_total": self.kv_positions_read_total,
@@ -1356,6 +1500,15 @@ class GenerativeMetrics:
         metric("paddle_generate_launch_ahead_share", "gauge",
                s["launch_ahead_share"],
                "steps launched ahead / decode steps (lifetime)")
+        metric("paddle_generate_restages_total", "counter",
+               s["restages_total"],
+               "decode steps launched from rows the host staged anew")
+        metric("paddle_generate_rowset_joins_total", "counter",
+               s["rowset_joins_total"],
+               "row-set changes joined on the device, the bucket kept")
+        metric("paddle_generate_rowset_join_share", "gauge",
+               s["rowset_join_share"],
+               "row-set changes joined / all row-set changes (lifetime)")
         metric("paddle_generate_prefills_total", "counter",
                s["prefills_total"], "prefill calls executed")
         metric("paddle_generate_queue_depth", "gauge", s["queue_depth"],
@@ -1691,7 +1844,10 @@ class GenerativeEngine:
         dprefill / dpropose run draft geometry; verify is the target's
         k-position speculative pass (k > 1 only for dpropose/verify);
         kvget / kvput are the KV-slot handoff read/write (raw row pair
-        in the stored dtype — the disaggregated-serving plane).
+        in the stored dtype — the disaggregated-serving plane); join
+        builds a decode step's rows on the device when the row set changes
+        within a bucket (`_join_body`: the class's cap names it, nothing
+        in it depends on the cap).
         kv_dtype is a family dimension too (engine-wide, but it changes
         the traced pool pytree, so it belongs in the key and the
         program_report inventory)."""
@@ -1736,16 +1892,23 @@ class GenerativeEngine:
                 body = _kvget_body
             elif kind == "kvput":
                 body = _kvput_body
+            elif kind == "join":
+                body = _join_body
             else:
                 raise ValueError(f"unknown program family {kind!r}")
             # kvget reads the pool without consuming it — never donate
-            # its inputs; kvput/pcopy update the pool pair in place
-            if not self._donate or kind == "kvget":
+            # its inputs; kvput/pcopy update the pool pair in place; the
+            # join's inputs are a launched step's outputs the host reads
+            if not self._donate or kind in ("kvget", "join"):
                 donate = ()
             elif kind in ("pcopy", "kvput"):
                 donate = (0, 1)
-            elif kind in ("prefill", "decode") and \
-                    self._state_shape() is not None:
+            elif kind in ("prefill", "extend"):
+                # the pools, the first-token table and the state beside them
+                donate = (1, 2, 5) + ((6,) if kind == "prefill" and
+                                      self._state_shape() is not None
+                                      else ())
+            elif kind == "decode" and self._state_shape() is not None:
                 donate = (1, 2, 10)     # the pools and the state beside them
             else:
                 donate = (1, 2)
@@ -1830,8 +1993,10 @@ class GenerativeEngine:
         rec = self._state_shape()
         if rec is not None:
             rec = _kvq.alloc(rec, device, self._model.state_dtype)
+        firsts = jax.device_put(np.zeros((self._slots + 1, 3), np.int32),
+                                device)
         return _ClassState(cap, self._slots, zk, zv, self._pc_slots,
-                           dk, dv, rec)
+                           dk, dv, rec, firsts)
 
     def _state_shape(self):
         """The model's fixed-size state beside its K/V rows, one row a
@@ -2161,8 +2326,8 @@ class GenerativeEngine:
     def _warm_device(self, device) -> int:
         """Pre-compile the full program inventory on `device`: every
         (class, prompt-bucket) prefill and every (class, batch-bucket)
-        decode step — after this, steady-state generation never sees
-        an XLA compile. Inputs are committed to `device` EXACTLY like
+        decode step and join — after this, steady-state generation never
+        sees an XLA compile. Inputs are committed to `device` EXACTLY like
         the execution path's (an uncommitted warm input would compile a
         sibling executable and leave the real first call cold)."""
         def put(a):
@@ -2172,17 +2337,16 @@ class GenerativeEngine:
         n = 0
         devk = self._device_key(device)
         scratch = self._slots
+        packed = _pack_row(scratch, 1, 0.0, 1, 1.0, np.zeros(2, np.uint32))
         for cap in self._caps:
             cs = self._alloc_class(cap, device)
             bounds = [s for s in self._prompt_boundaries if s <= cap]
             for s in bounds:
-                args = (p, cs.buf_k, cs.buf_v, put(np.int32(scratch)),
-                        put(np.zeros((1, s), np.int32)), put(np.int32(1)),
-                        put(np.float32(0.0)), put(np.int32(1)),
-                        put(np.float32(1.0)), put(np.zeros(2, np.uint32)),
-                        cs.rec)
-                tok, _, cs.buf_k, cs.buf_v, cs.rec = self._warm_call(
-                    "prefill", cap, s, args)
+                args = (p, cs.buf_k, cs.buf_v,
+                        *put([np.zeros((1, s), np.int32), packed]),
+                        cs.firsts, cs.rec)
+                tok, _, cs.buf_k, cs.buf_v, cs.firsts, cs.rec = \
+                    self._warm_call("prefill", cap, s, args)
                 del args
                 tok.block_until_ready()
                 with self._cv:
@@ -2197,13 +2361,20 @@ class GenerativeEngine:
                         put(np.ones((b,), np.int32)),
                         put(np.ones((b,), np.float32)),
                         put(np.zeros((b, 2), np.uint32)), cs.rec)
-                nxt, _, _, cs.buf_k, cs.buf_v, cs.rec, _ = \
+                nxt, nkeys, lens, cs.buf_k, cs.buf_v, cs.rec, _ = \
                     self._warm_call("decode", cap, b, args)
                 del args
-                nxt.block_until_ready()
+                # the join takes a step's outputs as the worker hands them
+                # over, and a plan that pads every index
+                plan, _ = self._join_plan([], [], b, scratch)
+                joined = self._warm_call(
+                    "join", cap, b, (nxt, lens, nkeys, cs.firsts, put(plan)))
+                joined[0].block_until_ready()
+                del joined
                 with self._cv:
                     self._warmed.add((devk, "decode", cap, b))
-                n += 1
+                    self._warmed.add((devk, "join", cap, b))
+                n += 2
             if "handoff" in self._model.refuses:
                 continue        # no row of this model leaves its pool
             # KV-handoff plane: the export read + import write over the
@@ -2241,15 +2412,12 @@ class GenerativeEngine:
                 n += 1
                 for s in bounds:
                     with _cc.donated_cpu_guard(self._donate):
-                        tok, _, cs.buf_k, cs.buf_v = self._program(
-                            "extend", cap, s)(
+                        tok, _, cs.buf_k, cs.buf_v, cs.firsts = \
+                            self._program("extend", cap, s)(
                                 p, cs.buf_k, cs.buf_v,
-                                put(np.int32(scratch)),
-                                put(np.zeros((1, s), np.int32)),
-                                put(np.int32(0)), put(np.int32(1)),
-                                put(np.float32(0.0)), put(np.int32(1)),
-                                put(np.float32(1.0)),
-                                put(np.zeros(2, np.uint32)))
+                                *put([np.zeros((1, s), np.int32),
+                                      np.append(packed, np.int32(0))]),
+                                cs.firsts)
                     tok.block_until_ready()
                     with self._cv:
                         self._warmed.add((devk, "extend", cap, s))
@@ -2259,15 +2427,11 @@ class GenerativeEngine:
                 k = self._spec_k
                 for s in bounds:
                     with _cc.donated_cpu_guard(self._donate):
-                        tok, _, cs.dbuf_k, cs.dbuf_v, _ = self._program(
+                        tok, _, cs.dbuf_k, cs.dbuf_v, _, _ = self._program(
                             "dprefill", cap, s)(
                                 dp, cs.dbuf_k, cs.dbuf_v,
-                                put(np.int32(scratch)),
-                                put(np.zeros((1, s), np.int32)),
-                                put(np.int32(1)),
-                                put(np.float32(0.0)), put(np.int32(1)),
-                                put(np.float32(1.0)),
-                                put(np.zeros(2, np.uint32)))
+                                *put([np.zeros((1, s), np.int32), packed]),
+                                None)
                     tok.block_until_ready()
                     with self._cv:
                         self._warmed.add((devk, "dprefill", cap, s))
@@ -2781,7 +2945,7 @@ class GenerativeEngine:
             except Exception:  # noqa: BLE001
                 pass
             old.dev = None
-            old.buf_k = old.buf_v = old.rec = None
+            old.buf_k = old.buf_v = old.rec = old.firsts = None
             old.dbuf_k = old.dbuf_v = None
             state[cap] = self._alloc_class(cap, w.device)
         self._requeue(stuck)
@@ -2803,9 +2967,8 @@ class GenerativeEngine:
         def put(a):
             return jax.device_put(a, w.device)
 
-        samp = (np.float32(req.temperature), np.int32(req.top_k),
-                np.float32(req.top_p))
-        key0 = _seed_key(req.seed)
+        packed = _pack_row(slot, P, req.temperature, req.top_k, req.top_p,
+                           _seed_key(req.seed))
 
         # ---- prefix-cache probe: longest cached boundary wins; the
         # longest UNcached boundary longer than the hit is admitted on
@@ -2836,6 +2999,12 @@ class GenerativeEngine:
             prog_keys.append((devk, "prefill", cs.cap, S))
         if self._spec:
             prog_keys.append((devk, "dprefill", cs.cap, S))
+        # a row that decodes here, in plain decode, joins the running rows
+        # from the device; one done after its prefill (one token asked, or
+        # a prefill for another host) and a draft's row, whose loop stages
+        # from the host every step, have their token read here
+        joins = not (self._spec or req.prefill_only or
+                     req.max_new <= len(req.tokens) + 1)
         args = None
         if _tr.enabled():
             args = {"replica": w.rid, "bucket": S, "prompt_tokens": P,
@@ -2857,37 +3026,31 @@ class GenerativeEngine:
                         T = bucket_for(P - hitF, bounds)
                         ids = np.zeros((1, T), np.int32)
                         ids[0, :P - hitF] = req.prompt[hitF:]
-                        tok, kcar, cs.buf_k, cs.buf_v = self._program(
-                            "extend", cs.cap, T)(
+                        tok, kcar, cs.buf_k, cs.buf_v, cs.firsts = \
+                            self._program("extend", cs.cap, T)(
                                 p, cs.buf_k, cs.buf_v,
-                                put(np.int32(slot)), put(ids),
-                                put(np.int32(hitF)), put(np.int32(P)),
-                                put(samp[0]), put(samp[1]),
-                                put(samp[2]), put(key0))
+                                *put([ids,
+                                      np.append(packed, np.int32(hitF))]),
+                                cs.firsts)
                     else:
                         ids = np.zeros((1, S), np.int32)
                         ids[0, :P] = req.prompt
-                        tok, kcar, cs.buf_k, cs.buf_v, cs.rec = \
+                        tok, kcar, cs.buf_k, cs.buf_v, cs.firsts, cs.rec = \
                             self._program("prefill", cs.cap, S)(
-                                p, cs.buf_k, cs.buf_v,
-                                put(np.int32(slot)), put(ids),
-                                put(np.int32(P)),
-                                put(samp[0]), put(samp[1]),
-                                put(samp[2]), put(key0), cs.rec)
+                                p, cs.buf_k, cs.buf_v, *put([ids, packed]),
+                                cs.firsts, cs.rec)
                     if self._spec:
                         # the draft has no prefix cache: it always
                         # prefills the full prompt into its own pool
                         dids = np.zeros((1, S), np.int32)
                         dids[0, :P] = req.prompt
-                        _dt, _dk, cs.dbuf_k, cs.dbuf_v, _ = self._program(
+                        _dt, _dk, cs.dbuf_k, cs.dbuf_v, _, _ = self._program(
                             "dprefill", cs.cap, S)(
                                 self._draft_params_for(w.device),
                                 cs.dbuf_k, cs.dbuf_v,
-                                put(np.int32(slot)), put(dids),
-                                put(np.int32(P)),
-                                put(np.float32(0.0)), put(np.int32(1)),
-                                put(np.float32(1.0)),
-                                put(np.zeros(2, np.uint32)))
+                                *put([dids, _pack_row(
+                                    slot, P, 0.0, 1, 1.0,
+                                    np.zeros(2, np.uint32))]), None)
                     if admitF is not None:
                         with self._cv:
                             idx = self._pc_index.setdefault(
@@ -2908,8 +3071,10 @@ class GenerativeEngine:
                         if evict:
                             self.metrics.on_prefix_evict()
                 kcar.copy_to_host_async()
-                tok = int(tok)
-                kcar = np.asarray(kcar)
+                tok.copy_to_host_async()
+                if not joins:
+                    tok = int(tok)
+                    kcar = np.asarray(kcar)
         finally:
             self._idle(w, gen)
         with self._cv:
@@ -2918,20 +3083,21 @@ class GenerativeEngine:
         self.metrics.on_prefill(P if hitF is None else P - hitF)
         if cs.pc_slots:
             self.metrics.on_prefix(hitF is not None, hitF or 0)
-        status = self._emit(w, gen, req, tok)
-        if status == "dead":
-            return
+        # a row that joins leaves its token and key on the device: the
+        # next step takes them from there, and the token is emitted before
+        # that step's read (`_take_firsts`); any other is emitted now
         with self._cv:
-            if w.generation != gen:
+            if w.generation != gen or req.owner != (w.rid, gen) or \
+                    req.future.done():
                 return
             cs.admitted()
-            cs.rows[slot] = _Row(req, slot, P, key=kcar)
+            cs.rows[slot] = row = _Row(req, slot, P, first=(tok, kcar))
             self._update_liveness_locked(w, cs)
-        if status == "done":
-            self._finish(w, gen, cs, slot, req, "eos"
-                         if req.eos is not None and tok == req.eos
-                         else "length")
+        if joins or not self._take_firsts(w, gen, cs, [row]):
             return
+        with self._cv:
+            if cs.rows.get(slot) is not row:
+                return          # done at its first token
         if req.prefill_only:
             # prefill/decode specialization: the slot is filled and the
             # first token sampled — export it for a decode host instead
@@ -2973,11 +3139,17 @@ class GenerativeEngine:
         emit it. The step-to-step dependency stays on the device
         (`_DeviceRows`): while the row set is what the last step ran, the
         launch takes each row's token, key and length from that step's
-        outputs and stages nothing; when it changed (an admission, a
-        finished or migrated row) every launched step is read first and
-        the seven arrays are staged anew from the rows — the one restage
-        a row-set change costs. The host learns of an EOS one step late:
-        that row's overshoot step wrote past its end in its own slot (the
+        outputs and stages nothing. When the row set changed (a row
+        admitted, finished or migrated) and the batch bucket did not, it
+        is changed on the device: one small plan goes over and the join
+        program (`_join_body`) builds the seven arrays from the last
+        launched step's outputs and the first-token table the admitted
+        rows' prefills wrote — no step is read for it, and the step is
+        launched ahead like any other. Only a change of bucket, or a class
+        that holds no device rows yet, reads every launched step first
+        (`_settle`) and stages the seven arrays anew from the host. The
+        host learns of an EOS one step late — a first token's too: that
+        row's overshoot step wrote past its end in its own slot (the
         slot's next owner is prefilled behind it on the device's queue),
         its token is discarded and it counts as no row. A finish by
         length is known ahead: where every row's last token is already on
@@ -2986,13 +3158,15 @@ class GenerativeEngine:
         `phase` is the worker loop's {"iter", "rid"} (None with tracing
         off): the args of the spans that partition this thread's time —
         one `generate.decode_step` a launched step with `.stage` (the
-        restage's device_puts: nothing on a step ahead), `.launch` (the
-        program call until it returns) and `.wait` (the blocking read of
-        the oldest unread step, where one is due), then `generate.emit`.
-        A read with no launch is a bare `.wait` + `generate.emit`. The
-        launched step's span carries its number (`step`); the `.wait`
-        that reads a step and the `generate.emit` after it carry that
-        step's (`read_step`): a launch and its read pair by number."""
+        restage's device_puts, or the join's plan and program: nothing on
+        a steady step), `.launch` (the program call until it returns) and
+        `.wait` (the blocking read of the oldest unread step, where one is
+        due, and of the first tokens of the rows it holds), then
+        `generate.emit`. A read with no launch is a bare `.wait` +
+        `generate.emit`. The launched step's span carries its number
+        (`step`) and the rows joined at it (`joined`); the `.wait` that
+        reads a step and the `generate.emit` after it carry that step's
+        (`read_step`): a launch and its read pair by number."""
         with self._cv:
             if w.generation != gen:
                 return
@@ -3001,7 +3175,10 @@ class GenerativeEngine:
             return
         devk = self._device_key(w.device)
         dev = stale = cs.dev
-        if dev is not None and not dev.holds(rows):
+        if dev is None or not dev.holds(rows) and \
+                dev.bucket != bucket_for(len(rows), self._batch_buckets):
+            # the host stages the rows: every launched step is read, and
+            # every first token, before their state is taken from the host
             if not self._settle(w, gen, cs, phase):
                 return
             # `stale` keeps the old rows' device arrays until the new
@@ -3015,28 +3192,39 @@ class GenerativeEngine:
         n = len(rows)
         bucket = bucket_for(n, self._batch_buckets)
         prog_key = (devk, "decode", cs.cap, bucket)
+        prog_keys = [prog_key]
         behind = len(dev.unread) if dev is not None else 0
+        # each row is as many steps ahead of the host as unread steps hold
+        # it: none for a row that joins at this launch
+        owed = [0] * n if dev is None else [dev.behind(r) for r in rows]
 
         # the rows are read here, before the hang-injection point below: a
         # worker that unwedges must not read rows its replacement owns
+        fresh = plan = None
+        joined = 0
         with self._cv:
-            due = [len(r.req.tokens) + behind < r.req.max_new
-                   for r in rows]
-            fresh = None if dev is not None else self._row_arrays(
-                rows, bucket, cs.n_slots)
-            positions = [r.length + behind for r in rows]
+            due = [len(r.req.tokens) + (r.first is not None) + k
+                   < r.req.max_new for r, k in zip(rows, owed)]
+            if dev is None:
+                fresh = self._row_arrays(rows, bucket, cs.n_slots)
+            elif not dev.holds(rows):
+                plan, joined = self._join_plan(dev.rows, rows, bucket,
+                                               cs.n_slots)
+                prog_keys.append((devk, "join", cs.cap, bucket))
+            positions = [r.length + k for r, k in zip(rows, owed)]
         if not any(due):
             # every row's last token is on the queue already
             self._read_oldest(w, gen, cs, phase)
             return
         # pool positions the read copies for each row: whole blocks up to
         # its position under the kernel, every position under the gather
-        plan = self._kv_plan("decode", cs.cap)
-        kv_reads = [0 if not self._L else cs.cap if plan is None else
-                    plan.positions_read(x, cs.cap) for x in positions]
-        if not self._busy(w, gen, [prog_key]):
+        kv_plan = self._kv_plan("decode", cs.cap)
+        kv_reads = [0 if not self._L else cs.cap if kv_plan is None else
+                    kv_plan.positions_read(x, cs.cap) for x in positions]
+        if not self._busy(w, gen, prog_keys):
             return
         w.launched += 1
+        cause = cs.launch_cause(restaged=fresh is not None)
         # the step this pass reads, where one is due: the oldest unread
         # once this pass's own is on the queue
         oldest = dev.unread[0] if behind >= _STEPS_AHEAD else None
@@ -3050,7 +3238,9 @@ class GenerativeEngine:
                     "state_bytes": self._model.state_step_bytes(n, bucket),
                     "spec_k": 0,
                     "ahead": int(behind > 0),
-                    "staged": 0 if fresh is None else len(fresh),
+                    "staged": len(fresh) if fresh is not None else
+                    int(plan is not None),
+                    "joined": joined,
                     "traces": [r.req.ctx.trace_id for r in rows
                                if r.req.ctx is not None]}
         read = None
@@ -3067,12 +3257,18 @@ class GenerativeEngine:
                 with _tr.span("generate.decode_step.stage", "serving",
                               phase):
                     if fresh is not None:
-                        slots, toks, lens, temps, topks, topps, keys = \
-                            jax.device_put(fresh, w.device)
+                        staged = jax.device_put(fresh, w.device)
+                    elif plan is not None:
+                        staged = self._program("join", cs.cap, bucket)(
+                            dev.toks, dev.lens, dev.keys, cs.firsts,
+                            jax.device_put(plan, w.device))
+                    if fresh is not None or plan is not None:
+                        slots, toks, lens, temps, topks, topps, keys = staged
                         cs.dev = dev = _DeviceRows(
-                            rows, (slots, temps, topks, topps), toks,
-                            lens, keys)
-                        cs.restaged = True
+                            rows, (slots, temps, topks, topps), toks, lens,
+                            keys, None if dev is None else dev.unread)
+                        del staged
+                        self.metrics.on_rowset(joined=plan is not None)
                 with _tr.span("generate.decode_step.launch", "serving",
                               phase):
                     slots, temps, topks, topps = dev.fixed
@@ -3088,12 +3284,12 @@ class GenerativeEngine:
                         out.copy_to_host_async()
                     dev.unread.append(_Launched(
                         w.launched, rows, prog_key, kv_reads, behind > 0,
-                        nxt, nkeys, aux))
+                        nxt, nkeys, aux, cause))
                     del stale
                 with _tr.span("generate.decode_step.wait", "serving",
                               read_args):
                     if oldest is not None:
-                        read = self._wait_oldest(dev)
+                        read = self._wait_oldest(w, gen, cs)
                         if read[3] is not None:
                             # of the step this pass READ: the launched
                             # one's are a step away
@@ -3103,6 +3299,42 @@ class GenerativeEngine:
         if read is not None:
             with _tr.span("generate.emit", "serving", read_args) as emit:
                 self._emit_read(w, gen, cs, emit, *read)
+
+    @staticmethod
+    def _join_plan(going: list, rows: list, bucket: int,
+                   scratch: int) -> Tuple[np.ndarray, int]:
+        """The join's plan (`_join_body`) from the rows the last launched
+        step held (`going`) to `rows`, padded to `bucket` with rows that
+        target the scratch slot at length 0 as `_row_arrays` pads -> (the
+        plan, how many rows join). A row of `going` keeps its device
+        state; a row whose prefill's token is still on the device takes
+        it from the first-token table; any other row takes what the host
+        holds (caller holds _cv)."""
+        plan = np.zeros((bucket, _J_COLS), np.int32)
+        plan[:, _J_SRC] = bucket + 1
+        plan[:, _J_SLOT] = scratch
+        plan[:, _J_TOPK] = 1
+        plan[:, _J_TOPP] = np.float32(1.0).view(np.int32)
+        at = {id(r): i for i, r in enumerate(going)}
+        joined = 0
+        for i, row in enumerate(rows):
+            req = row.req
+            plan[i, _J_SLOT] = row.slot
+            plan[i, _J_TEMP] = np.float32(req.temperature).view(np.int32)
+            plan[i, _J_TOPK] = req.top_k
+            plan[i, _J_TOPP] = np.float32(req.top_p).view(np.int32)
+            if id(row) in at:
+                plan[i, _J_SRC] = at[id(row)]
+                continue
+            joined += 1
+            plan[i, _J_LEN] = row.length
+            if row.first is not None:
+                plan[i, _J_SRC] = bucket
+            else:
+                plan[i, _J_TOK] = req.tokens[-1]
+                plan[i, _J_KEY:_J_KEY + 2] = np.asarray(
+                    row.key, np.uint32).view(np.int32)
+        return plan, joined
 
     @staticmethod
     def _row_arrays(rows: list, bucket: int, scratch: int) -> list:
@@ -3127,22 +3359,53 @@ class GenerativeEngine:
             keys[i] = row.key
         return [slots, toks, lens, temps, topks, topps, keys]
 
-    @staticmethod
-    def _wait_oldest(dev: _DeviceRows) -> tuple:
-        """Block on the oldest launched step: (step, tokens, keys, what
-        the pass counted of itself — (tokens per expert, distinct experts
-        hit) of a routed model, None of a dense one)."""
-        step = dev.unread.popleft()
+    def _wait_oldest(self, w: ReplicaSlot, gen: int,
+                     cs: _ClassState) -> tuple:
+        """Block on the oldest launched step, the first tokens of the rows
+        it holds emitted before (they were ready before it): (step,
+        tokens, keys, what the pass counted of itself — (tokens per
+        expert, distinct experts hit) of a routed model, None of a dense
+        one)."""
+        self._take_firsts(w, gen, cs, cs.dev.unread[0].rows)
+        step = cs.dev.unread.popleft()
         return (step, np.asarray(step.nxt), np.asarray(step.nkeys),
                 None if step.aux is None else
                 tuple(np.asarray(a) for a in step.aux))
+
+    def _take_firsts(self, w: ReplicaSlot, gen: int, cs: _ClassState,
+                     rows: list) -> bool:
+        """Emit the first token of each row of `rows` whose prefill left
+        it on the device (`_Row.first`), blocking until it is on the host:
+        before the read of the first step the row is in, and before the
+        host stages the row or moves it elsewhere. A first token that ends
+        the request (EOS) ends the row here, a step late like any EOS: the
+        step launched with it discards its token at the read. False where
+        the worker was superseded."""
+        for row in rows:
+            if row.first is None:
+                continue
+            tok, key = row.first
+            row.first = None
+            tok, key = int(tok), np.asarray(key)
+            with self._cv:
+                if cs.rows.get(row.slot) is not row:
+                    continue
+                row.key = key
+            status = self._emit(w, gen, row.req, tok)
+            if status == "dead":
+                return False
+            if status == "done":
+                self._finish(w, gen, cs, row.slot, row.req, "eos"
+                             if row.req.eos is not None and
+                             tok == row.req.eos else "length")
+        return True
 
     def _emit_read(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                    span, step: _Launched, nxt, nkeys, experts=None) -> bool:
         return self._emit_step(
             w, gen, cs, step.rows, [step.prog_key], step.prog_key[3],
             step.kv_reads, [[int(t)] for t in nxt[:len(step.rows)]],
-            nkeys, span, step.ahead, experts)
+            nkeys, span, step.cause, step.ahead, experts)
 
     def _read_oldest(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                      phase: Optional[dict]) -> bool:
@@ -3154,7 +3417,7 @@ class GenerativeEngine:
         args = _read_args(phase, oldest.step)
         try:
             with _tr.span("generate.decode_step.wait", "serving", args):
-                read = self._wait_oldest(cs.dev)
+                read = self._wait_oldest(w, gen, cs)
         finally:
             self._idle(w, gen)
         with _tr.span("generate.emit", "serving", args) as emit:
@@ -3162,15 +3425,17 @@ class GenerativeEngine:
 
     def _settle(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                 phase: Optional[dict] = None) -> bool:
-        """Read and emit every launched step of class `cs`, oldest first:
-        after it row.length / row.key / req.tokens are what the device
-        holds. Whoever reads a row's host state to move it elsewhere, or
-        stages the rows anew, settles first. False where the worker was
-        superseded meanwhile."""
+        """Read and emit every launched step of class `cs`, oldest first,
+        and every first token still on the device: after it row.length /
+        row.key / req.tokens are what the device holds. Whoever reads a
+        row's host state to move it elsewhere, or stages the rows anew,
+        settles first. False where the worker was superseded meanwhile."""
         while cs.dev is not None and cs.dev.unread:
             if not self._read_oldest(w, gen, cs, phase):
                 return False
-        return True
+        with self._cv:
+            rows = [cs.rows[s] for s in sorted(cs.rows)]
+        return self._take_firsts(w, gen, cs, rows)
 
     def _spec_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                    phase: Optional[dict] = None) -> None:
@@ -3207,12 +3472,13 @@ class GenerativeEngine:
         if not self._busy(w, gen, prog_keys):
             return
         w.launched += 1
+        cause = cs.launch_cause(restaged=True)
         args = read_args = None
         if _tr.enabled():
             args = {"replica": w.rid, "step": w.launched, "rows": n,
                     "bucket": bucket,
                     "cap": cs.cap, "kv_read": n * cs.cap, "spec_k": k,
-                    "ahead": 0, "staged": len(fresh) + 1,
+                    "ahead": 0, "staged": len(fresh) + 1, "joined": 0,
                     "traces": [r.req.ctx.trace_id for r in rows
                                if r.req.ctx is not None]}
             read_args = _read_args(phase, w.launched)
@@ -3225,7 +3491,7 @@ class GenerativeEngine:
                 with _tr.span("generate.decode_step.stage", "serving",
                               phase):
                     staged = [put(a) for a in fresh]
-                    cs.restaged = True
+                    self.metrics.on_rowset(joined=False)
                 # `staged` is dropped inside the last launch: its device
                 # buffers are freed while the program runs, not between
                 # two steps.
@@ -3277,11 +3543,11 @@ class GenerativeEngine:
             self._emit_step(
                 w, gen, cs, rows, prog_keys, bucket, kv_reads,
                 [[int(t) for t in ys[i, :m]] for i, m in enumerate(ms)],
-                [khist[i, m - 1] for i, m in enumerate(ms)], emit)
+                [khist[i, m - 1] for i, m in enumerate(ms)], emit, cause)
 
     def _emit_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                    rows: list, prog_keys: list, bucket: int,
-                   kv_reads: list, toks: list, keys, span,
+                   kv_reads: list, toks: list, keys, span, cause: str,
                    ahead: bool = False, experts=None) -> bool:
         """What follows a decode step's read on the worker thread: row i
         takes the tokens toks[i] (one, or a speculative burst's accepted
@@ -3295,12 +3561,15 @@ class GenerativeEngine:
 
         This is where a row is handed its next token, so the gap between
         a row's tokens is measured here: the time since the class's
-        previous step was read, with its cause as the class state holds
-        it (STEP_GAP_CAUSES) — to the counters with the step, and with
+        previous step was read, with the `cause` the step's launch named
+        (STEP_GAP_CAUSES) — to the counters with the step, and with
         the live rows onto `span`, the `generate.emit` this runs under.
         A step that emits to no row hands nothing over and closes no gap;
-        neither does the first read of a class that had held no row."""
-        now = time.monotonic()
+        neither does the first read of a class that had held no row. The
+        read's time is the live span's own opening stamp, so a trace's
+        emits lie exactly `gap_ms` apart."""
+        now = (getattr(span, "begin_ns", 0)
+               or time.perf_counter_ns()) * 1e-9
         with self._cv:
             for pk in prog_keys:
                 self._warmed.add(pk)
@@ -3313,12 +3582,12 @@ class GenerativeEngine:
                 rows[i].key = np.array(keys[i], np.uint32)
             self._update_liveness_locked(w, cs)
         if live:
-            cause, gap = None, 0.0
-            if cs.read_at is not None:
+            gap = 0.0
+            if cs.read_at is None:
+                cause = None
+            else:
                 gap = now - cs.read_at
-                cause = "admission" if cs.admits else \
-                    "rowset" if cs.restaged else "steady"
-            cs.read_at, cs.admits, cs.restaged = now, 0, False
+            cs.read_at = now
             # the state moved is the launched rows': one that had left its
             # slot by the read was stepped all the same
             self.metrics.on_step(len(live), bucket,
@@ -3611,15 +3880,13 @@ class GenerativeEngine:
                     if len(toks) > 1:
                         hist[0, P:length] = np.asarray(
                             toks[:-1], np.int32)
-                    _dt, _dk, cs.dbuf_k, cs.dbuf_v, _ = self._program(
+                    _dt, _dk, cs.dbuf_k, cs.dbuf_v, _, _ = self._program(
                         "dprefill", cs.cap, S)(
                             self._draft_params_for(w.device),
                             cs.dbuf_k, cs.dbuf_v,
-                            put(np.int32(slot)), put(hist),
-                            put(np.int32(length)),
-                            put(np.float32(0.0)), put(np.int32(1)),
-                            put(np.float32(1.0)),
-                            put(np.zeros(2, np.uint32)))
+                            *put([hist, _pack_row(
+                                slot, length, 0.0, 1, 1.0,
+                                np.zeros(2, np.uint32))]), None)
                 if admitF is not None:
                     with self._cv:
                         idx = self._pc_index.setdefault(

@@ -223,6 +223,11 @@ class Span:
         self.args.update(kwargs)
         return self
 
+    @property
+    def begin_ns(self) -> int:
+        """When the span opened (its `ts`), on `time.perf_counter_ns`."""
+        return self._begin_ns
+
     def __enter__(self):
         parent = self._parent if self._parent is not None \
             else getattr(_TLS, "ctx", None)

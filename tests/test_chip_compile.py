@@ -225,10 +225,9 @@ def test_lfm2_programs_compile_for_a_v5e_at_the_cells_size(
                     sds((b,), jnp.int32), sds((b,), jnp.float32),
                     sds((b, 2), jnp.uint32))
         else:
-            rows = (sds((), jnp.int32), sds((1, bucket), jnp.int32),
-                    sds((), jnp.int32), sds((), jnp.float32),
-                    sds((), jnp.int32), sds((), jnp.float32),
-                    sds((2,), jnp.uint32))
+            # the ids, the packed row, the first-token table
+            rows = (sds((1, bucket), jnp.int32), sds((7,), jnp.int32),
+                    sds((33, 3), jnp.int32))
         compiled = eng._program(kind, cap, bucket).lower(
             params, pool, pool, *rows, rec).compile()
     finally:
@@ -289,10 +288,9 @@ def test_brumby_programs_compile_for_a_v5e_at_the_cells_size(
                     sds((b,), jnp.int32), sds((b,), jnp.float32),
                     sds((b, 2), jnp.uint32))
         else:
-            rows = (sds((), jnp.int32), sds((1, bucket), jnp.int32),
-                    sds((), jnp.int32), sds((), jnp.float32),
-                    sds((), jnp.int32), sds((), jnp.float32),
-                    sds((2,), jnp.uint32))
+            # the ids, the packed row, the first-token table
+            rows = (sds((1, bucket), jnp.int32), sds((7,), jnp.int32),
+                    sds((17, 3), jnp.int32))
         compiled = eng._program(kind, cap, bucket).lower(
             params, None, None, *rows, rec).compile()
         state_bytes = eng.kv_pool_bytes()

@@ -60,6 +60,24 @@ def _chaos_reset():
     chaos.reset()
 
 
+@pytest.fixture(autouse=True)
+def _release_shut_engines():
+    """The race detector keeps every engine it watched alive to the end
+    of the module (an id on record must not be reused), and a program
+    loaded on the CPU holds about a hundred memory maps: a few dozen
+    shut-down engines' programs reach the kernel's limit on maps and the
+    next load faults. After each test a shut-down engine drops its
+    programs and keeps their names for `program_report`."""
+    from paddle_tpu.testing import racecheck
+
+    yield
+    with racecheck._REG:
+        kept = list(racecheck._KEEP.values())
+    for eng in kept:
+        if isinstance(eng, GenerativeEngine) and eng._shut:
+            eng._programs = dict.fromkeys(eng._programs)
+
+
 @pytest.fixture(scope="module")
 def tiny_model():
     paddle.seed(0)
@@ -269,8 +287,8 @@ class TestProgramInventory:
         """Compile-shape discipline: after warmup, a full mixed-length
         concurrent workload triggers ZERO persistent-cache lookups —
         everything runs on the warmed prefill bucket ladder + one
-        decode-step program per batch bucket (plus the per-class
-        kvget/kvput KV-handoff pair, warmed so a mid-workload
+        decode-step program and one join per batch bucket (plus the
+        per-class kvget/kvput KV-handoff pair, warmed so a mid-workload
         export/import never compiles)."""
         eng = make_engine(tiny_model)
         try:
@@ -283,7 +301,8 @@ class TestProgramInventory:
             rep = eng.program_report()
             expect = {f"prefill[cap=64,b={b}]"
                       for b in (8, 16, 32, 64)} | \
-                     {f"decode[cap=64,b={b}]" for b in (1, 2, 4)} | \
+                     {f"{kind}[cap=64,b={b}]" for b in (1, 2, 4)
+                      for kind in ("decode", "join")} | \
                      {"kvget[cap=64,b=1]", "kvput[cap=64,b=1]"}
             assert set(rep["programs"]) == expect, rep
         finally:
@@ -458,49 +477,70 @@ class TestElasticity:
 
 class TestLaunchAhead:
     """Plain decode keeps its step-to-step state on the device and
-    launches step n+1 before it reads step n (PR 32): what the host may
-    no longer do between two steps, and what a late-learned end, a raise
-    and a hang must still leave intact."""
+    launches step n+1 before it reads step n (PR 32), and a row-set change
+    within a bucket is made there too (PR 38): what the host may no longer
+    do between two steps, and what a late-learned end, a raise and a hang
+    must still leave intact."""
 
     @staticmethod
-    def _fixed_pair(model, max_new, monkeypatch):
-        """Two requests admitted in one pass, ending on one step: the row
-        set never changes while they decode. -> (snapshot, device_puts of
-        the worker thread, the Prometheus text)."""
+    def _served(model, asks, monkeypatch, slots=2):
+        """`asks` [max_new] queued before the worker starts, so what each
+        pass admits is fixed. -> (snapshot, the worker thread's
+        device_puts by call — `table` (a class's first-token table),
+        `prefill` (ids and packed row), `restage` (the seven row arrays),
+        `plan` (a join's) — whether each prefill left its token on the
+        device, the Prometheus text)."""
         import jax
+        from paddle_tpu.inference.serving.generate import GenerativeEngine
 
-        eng = make_engine(model, slots=2, max_new_tokens_cap=32,
+        eng = make_engine(model, slots=slots, max_new_tokens_cap=32,
                           auto_start=False)
-        puts = []
+        puts, pending = [], []
         real = jax.device_put
+        real_prefill = GenerativeEngine._prefill_one
+
+        def name(shapes):
+            if len(shapes) == 7:
+                return "restage"
+            if len(shapes) == 2 and shapes[1] == (7,):
+                return "prefill"
+            return {3: "table", 9: "plan"}[shapes[0][-1]]
 
         def counting(x, *a, **k):
-            # the worker's own puts by array (a restage puts its seven as
-            # one list), its two zeroed pools left out
+            # the worker's own puts by call, its zeroed pools left out
+            leaves = jax.tree.leaves(x)
             if threading.current_thread().name.startswith(
-                    "generate-worker"):
-                puts.extend(np.shape(leaf) for leaf in jax.tree.leaves(x)
-                            if np.ndim(leaf) <= 2)
+                    "generate-worker") and all(np.ndim(leaf) <= 2
+                                               for leaf in leaves):
+                puts.append(name([np.shape(leaf) for leaf in leaves]))
             return real(x, *a, **k)
 
+        def prefill(self, w, gen, cs, slot, req, phase=None):
+            real_prefill(self, w, gen, cs, slot, req, phase)
+            pending.append(cs.rows[slot].first is not None)
+
         monkeypatch.setattr(jax, "device_put", counting)
+        monkeypatch.setattr(GenerativeEngine, "_prefill_one", prefill)
         try:
-            handles = [eng.submit(p, max_new)
-                       for p in mixed_prompts(2, seed=21)]
+            handles = [eng.submit(p, n) for p, n in
+                       zip(mixed_prompts(len(asks), seed=21), asks)]
             eng.start()
-            for h in handles:
-                assert len(h.result(60)["tokens"]) == max_new
-            return (eng.metrics.snapshot(), puts,
+            for h, n in zip(handles, asks):
+                assert len(h.result(60)["tokens"]) == n
+            return (eng.metrics.snapshot(), puts, pending,
                     eng.metrics.prometheus_text())
         finally:
             monkeypatch.setattr(jax, "device_put", real)
+            monkeypatch.setattr(GenerativeEngine, "_prefill_one",
+                                real_prefill)
             eng.shutdown()
 
     def test_fixed_row_set_is_launched_ahead_and_stages_nothing(
             self, tiny_model, monkeypatch):
-        short, puts_short, _ = self._fixed_pair(tiny_model, 6,
-                                                monkeypatch)
-        snap, puts, text = self._fixed_pair(tiny_model, 24, monkeypatch)
+        short, puts_short, _, _ = self._served(tiny_model, [6, 6],
+                                               monkeypatch)
+        snap, puts, _, text = self._served(tiny_model, [24, 24],
+                                           monkeypatch)
         n = snap["steps_total"]
         assert n == 23 and snap["step_rows_total"] == 2 * n
         # every step but the first was launched with its predecessor
@@ -511,12 +551,38 @@ class TestLaunchAhead:
                 f"{snap['launch_ahead_share']}") in text
         assert (f"paddle_generate_steps_ahead_total "
                 f"{snap['steps_ahead_total']}") in text
-        # the host staged two prefills (seven arrays each) and ONE set of
-        # seven row arrays, however many steps followed: 18 more steps
-        # put nothing
+        # the host put the class's table, two prefills (one transfer
+        # each) and ONE set of seven row arrays, however many steps
+        # followed: 18 more steps put nothing
         assert short["steps_total"] == 5
-        assert len(puts) == len(puts_short) == 2 * 7 + 7
-        assert puts == puts_short
+        assert puts == puts_short == ["table", "prefill", "prefill",
+                                      "restage"]
+        assert snap["restages_total"] == 1
+        assert snap["rowset_joins_total"] == 0
+
+    def test_an_admission_into_running_rows_puts_two_and_reads_nothing(
+            self, tiny_model, monkeypatch):
+        """Two slots: B ends, C is admitted into its slot while A decodes
+        — one transfer for C's prefill and one for the join's plan, the
+        step after it launched ahead (nothing was read for it: a read of
+        the launched steps would have left none unread); C ends before A,
+        and one row is bucket 1: that change restages."""
+        snap, puts, pending, text = self._served(tiny_model, [24, 6, 8],
+                                                 monkeypatch)
+        assert puts == ["table", "prefill", "prefill", "restage",
+                        "prefill", "plan", "restage"]
+        # no prefill read its token: the first fill's and the last one's
+        # were read before the restage, C's with its first step
+        assert pending == [True, True, True]
+        # the two restaged launches are the only steps not launched ahead
+        assert snap["steps_total"] - snap["steps_ahead_total"] == 2
+        assert snap["restages_total"] == 2
+        assert snap["rowset_joins_total"] == 1
+        assert snap["rowset_join_share"] == round(1 / 3, 4)
+        for name, value in (("restages_total", 2),
+                            ("rowset_joins_total", 1),
+                            ("rowset_join_share", round(1 / 3, 4))):
+            assert f"paddle_generate_{name} {value}\n" in text
 
     def test_eos_learned_a_step_late_emits_nothing_after_it(
             self, tiny_model):
@@ -619,6 +685,190 @@ class TestLaunchAhead:
                 t.join(60)
             assert collected == ref    # exact: no dup, no corruption
             assert eng.metrics.failed_total == 0
+        finally:
+            chaos.reset()
+            eng.shutdown()
+
+
+class TestJoin:
+    """A row-set change that keeps the batch bucket is made on the device
+    (PR 38): the rows that go on keep their state there, a new row takes
+    its prefill's token and key from the class's first-token table, a
+    finished row with no successor becomes a padding row — and every
+    request gets the tokens it gets alone."""
+
+    @staticmethod
+    def _plans(monkeypatch):
+        """Every join's plan as `_join_plan` made it: [(plan, rows joined,
+        real rows, scratch slot)]."""
+        from paddle_tpu.inference.serving.generate import GenerativeEngine
+
+        made = []
+        real = GenerativeEngine._join_plan
+
+        def recording(going, rows, bucket, scratch):
+            plan, joined = real(going, rows, bucket, scratch)
+            made.append((plan.copy(), joined, len(rows), scratch))
+            return plan, joined
+
+        monkeypatch.setattr(GenerativeEngine, "_join_plan",
+                            staticmethod(recording))
+        return made
+
+    @pytest.mark.parametrize("sampling", [
+        {}, {"temperature": 0.8, "top_k": 20, "top_p": 0.9}],
+        ids=["greedy", "seeded"])
+    def test_joined_rows_decode_what_they_decode_alone(
+            self, tiny_model, monkeypatch, sampling):
+        """Four slots, seven requests queued before the worker starts: the
+        two that end on one step are replaced in ONE pass (two rows join
+        at one launch), the next one alone, and once the queue is empty a
+        row ends with no successor (a padding row, bucket 4 kept) before
+        the bucket shrinks (a restage)."""
+        from paddle_tpu.inference.serving.generate import (_J_LEN, _J_SLOT,
+                                                           _J_SRC)
+
+        asks = [12, 5, 5, 9, 7, 6, 10]
+        prompts = mixed_prompts(len(asks), seed=31)
+        alone = make_engine(tiny_model, slots=1)
+        try:
+            want = [alone.generate(p, n, seed=i, timeout=60, **sampling)
+                    ["tokens"] for i, (p, n) in enumerate(zip(prompts, asks))]
+        finally:
+            alone.shutdown()
+        eng = make_engine(tiny_model, slots=4, auto_start=False)
+        plans = self._plans(monkeypatch)        # the warm-up's left out
+        try:
+            handles = [eng.submit(p, n, seed=i, **sampling)
+                       for i, (p, n) in enumerate(zip(prompts, asks))]
+            eng.start()
+            got = [h.result(60)["tokens"] for h in handles]
+            snap = eng.metrics.snapshot()
+        finally:
+            eng.shutdown()
+        assert got == want
+        joined = [j for _, j, _, _ in plans]
+        assert 2 in joined and 1 in joined
+        assert snap["rowset_joins_total"] == len(plans)
+        # a finish with no successor: three rows in bucket 4, the fourth
+        # index a padding row on the scratch slot at length 0
+        pads = [(plan, n, scratch) for plan, j, n, scratch in plans
+                if j == 0]
+        assert pads
+        for plan, n, scratch in pads:
+            assert n == 3 and plan.shape[0] == 4
+            assert plan[3, _J_SRC] == 5 and plan[3, _J_SLOT] == scratch
+            assert plan[3, _J_LEN] == 0
+            assert (plan[:3, _J_SRC] < 4).all()      # the rows go on
+
+    @pytest.mark.parametrize("case", ["eos_first", "one_token"])
+    def test_a_request_done_at_its_first_token(self, tiny_model,
+                                               monkeypatch, case):
+        """A first token that is EOS is learned a step late like any EOS:
+        the row joins, rides one step in its own slot, that step's token
+        for it is discarded — its stream holds the one token. A request of
+        one token is done at its prefill and never joins. Either way the
+        rows decoding beside it, and the request admitted after it into
+        the same slot, get what they get alone."""
+        pa, pb, pc = mixed_prompts(3, seed=41)
+        alone = make_engine(tiny_model, slots=1)
+        try:
+            want_a = alone.generate(pa, 16, timeout=60)["tokens"]
+            first_b = alone.generate(pb, 1, timeout=60)["tokens"]
+            want_c = alone.generate(pc, 6, timeout=60)["tokens"]
+        finally:
+            alone.shutdown()
+        eos, new_b = (first_b[0], 8) if case == "eos_first" else (None, 1)
+        eng = make_engine(tiny_model, slots=2, auto_start=False)
+        plans = self._plans(monkeypatch)
+        try:
+            ha = eng.submit(pa, 16)
+            h0 = eng.submit(mixed_prompts(1, seed=42)[0], 3)
+            hb = eng.submit(pb, new_b, eos_token_id=eos)
+            hc = eng.submit(pc, 6)
+            eng.start()
+            events = list(hb.events())
+            assert [v for kind, v in events if kind == "tok"] == first_b
+            assert events[-1][1]["finish_reason"] == (
+                "eos" if case == "eos_first" else "length")
+            assert ha.result(60)["tokens"] == want_a
+            assert hc.result(60)["tokens"] == want_c
+            assert len(h0.result(60)["tokens"]) == 3
+            snap = eng.metrics.snapshot()
+        finally:
+            eng.shutdown()
+        # B joined, and C into its slot after its EOS was read; a request
+        # of one token took no slot past its prefill: A was alone, bucket
+        # 1, and C came in with a restage
+        assert len(plans) == snap["rowset_joins_total"]
+        assert sum(j for _, j, _, _ in plans) == (
+            2 if case == "eos_first" else 0)
+        assert snap["tokens_out_total"] == 16 + 3 + 1 + 6
+
+    @pytest.mark.parametrize("fault", ["raise", "hang"])
+    def test_a_fault_at_a_join_requeues_and_leaks_no_slot(
+            self, tiny_model, monkeypatch, fault):
+        """The chaos site fires at the launch that joins a row: a raise
+        requeues the worker's rows, a hang is revived — every stream ends
+        as it does alone, with no token twice, and every slot is free
+        again and used again."""
+        from paddle_tpu.inference.serving.generate import GenerativeEngine
+
+        prompts = mixed_prompts(5, seed=51)
+        asks = [10, 4, 8, 6, 7]
+        eng = make_engine(tiny_model, slots=2, max_new_tokens_cap=32)
+        try:
+            want = [eng.generate(p, n, timeout=60)["tokens"]
+                    for p, n in zip(prompts, asks)]
+            w0 = eng._workers[0]
+            gen0 = w0.generation
+            real = GenerativeEngine._join_plan
+            armed = []
+
+            def arming(going, rows, bucket, scratch):
+                plan, joined = real(going, rows, bucket, scratch)
+                if joined and not armed:
+                    armed.append(joined)
+                    if fault == "raise":
+                        chaos.add_rule("serving.decode_step", "raise_n", 1)
+                    else:
+                        chaos.add_rule(
+                            "serving.decode_step", "delay", 8.0,
+                            match={"replica": w0.rid, "generation": gen0})
+                return plan, joined
+
+            monkeypatch.setattr(GenerativeEngine, "_join_plan",
+                                staticmethod(arming))
+            handles = [eng.submit(p, n) for p, n in zip(prompts, asks)]
+            collected = [[] for _ in prompts]
+
+            def consume(i, h):
+                for tok in h:
+                    collected[i].append(tok)
+
+            threads = [threading.Thread(target=consume, args=(i, h),
+                                        name=f"consume-{i}")
+                       for i, h in enumerate(handles)]
+            for t in threads:
+                t.start()
+            if fault == "hang":
+                deadline = time.monotonic() + 20
+                while time.monotonic() < deadline and not (
+                        armed and {r["rid"]: r for r in
+                                   eng.replica_states()}[w0.rid]["busy_s"]
+                        > 0.3):
+                    time.sleep(0.02)
+                eng.revive_replica(w0.rid)
+            for t in threads:
+                t.join(60)
+            assert armed
+            assert collected == want       # exact: no dup, no hole
+            assert eng.metrics.requeues_total >= 1
+            assert eng.metrics.failed_total == 0
+            assert eng.metrics.snapshot()["kv_pool"]["slots_used"] == 0
+            # both slots serve again, side by side
+            again = [eng.submit(p, n) for p, n in zip(prompts[:2], asks)]
+            assert [h.result(60)["tokens"] for h in again] == want[:2]
         finally:
             chaos.reset()
             eng.shutdown()

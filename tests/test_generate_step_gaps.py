@@ -1,11 +1,13 @@
 """The gap between a row's tokens, measured where the worker hands them
 over (`GenerativeEngine._emit_step`): every read of a decode step is
-counted by what the worker did since the class's previous read — steady,
-a row-set change, an admission — in `GenerativeMetrics` always, and with
-tracing on as args of `generate.emit`; a step carries its number from its
-launch (`generate.decode_step`'s `step`) to its read (`read_step`); the
-pass that admits runs under `generate.admission`; every program carries
-its model's name."""
+counted by what its launch found queued in front of it on the device — a
+prefill or import (admission), rows the host staged anew (rowset), or
+nothing but the step (steady: its rows taken from the last step's outputs
+or joined on the device) — in `GenerativeMetrics` always, and with tracing
+on as args of `generate.emit`; a step carries its number from its launch
+(`generate.decode_step`'s `step`) to its read (`read_step`); the pass that
+admits runs under `generate.admission`; every program carries its model's
+name."""
 import os
 import sys
 
@@ -75,8 +77,9 @@ def test_a_finish_with_no_admission_counts_rowset_and_a_first_read_nowhere(
         tiny_model):
     """Two rows from the start, the short one ends on its fourth token:
     s1 (the class's first read: no gap) | s2 s3 steady, two tokens each |
-    s4 steady, the ended row's token launched in vain | restage | s5
-    rowset | s6-s9 steady. Nothing is admitted while a row decodes."""
+    s4 steady, the ended row's token launched in vain | restage (one row
+    is bucket 1) | s5 rowset | s6-s9 steady. Nothing is admitted while a
+    row decodes."""
     eng = make_engine(tiny_model, auto_start=False)
     try:
         serve_queued(eng, [(5, 4), (9, 10)])
@@ -100,17 +103,26 @@ def test_a_finish_with_no_admission_counts_rowset_and_a_first_read_nowhere(
 
 
 def test_an_admission_while_a_row_decodes_counts_admission(tiny_model):
-    """Two slots, three requests: the third is admitted when the second
-    ends. The read that follows its prefill (the settle's, of the step
-    launched before it: one live row) is the admission's; the first step
-    of the new row set is a rowset; so is the one after the third ends."""
+    """Two slots, three requests (A 12 tokens, B 3, C 4): C is admitted
+    when B ends, joins A on the device, and the step after it is launched
+    ahead — the step that crosses C's prefill is the first one launched
+    behind it, and only its read is the admission's:
+
+        launch  s1 [A B] restaged | s2 | s3 (B's token in vain) |
+                prefill C, join, s4 [A C] | s5 | s6 | s7 (C's in vain) |
+                restage (one row: bucket 1), s8 [A] | s9 ...
+        read    s1 first | s2 steady | s3 steady (A) | s4 admission (A, C:
+                C's first token just before) | s5 s6 steady | s7 steady
+                (A) | s8 rowset (A) | s9 ... steady"""
     eng = make_engine(tiny_model, auto_start=False)
     try:
         serve_queued(eng, [(6, 12), (3, 3), (8, 4)])
         snap = eng.metrics.snapshot()
         by_cause, _ = gaps_of(snap)
-        assert by_cause["admission"] == (1, 1)
-        assert by_cause["rowset"] == (2, 3)
+        assert by_cause["admission"] == (1, 2)
+        assert by_cause["rowset"] == (1, 1)
+        assert snap["rowset_joins_total"] == 1
+        assert snap["restages_total"] == 2
         assert snap["prefills_total"] == 3
         # 11 + 2 + 3 tokens from decode steps, the first read's two aside
         assert sum(t for _, t in by_cause.values()) == 16 - 2
@@ -227,20 +239,23 @@ def test_emit_carries_what_the_counters_took(traced):
     assert by_cause["admission"][0] >= 3
 
 
-def test_the_cause_is_what_the_worker_did_since_the_last_read(traced):
-    """An `admission` gap is a read that followed at least one prefill; a
-    `rowset` one followed a restage (a step that staged arrays) and no
-    prefill; a `steady` one neither."""
+def test_the_cause_is_what_the_launch_found_queued_before_it(traced):
+    """The cause is the read step's, fixed at its launch: `admission`
+    where a prefill was queued since the launch before it; `rowset` where
+    the host staged its seven arrays anew; `steady` otherwise — a join
+    (one plan staged) included."""
     spans, _ = traced
     emits = [s for s in named(spans, "generate.emit") if "rows" in s["args"]]
     prefills = [s["ts"] + s["dur"] for s in named(spans, "generate.prefill")]
-    restages = [s["ts"] for s in named(spans, "generate.decode_step")
-                if s["args"]["staged"]]
+    launched = {s["args"]["step"]: s
+                for s in named(spans, "generate.decode_step")}
+    assert {s["args"]["staged"] for s in launched.values()} == {0, 1, 7}
     for prev, e in zip(emits, emits[1:]):
-        def between(times):
-            return any(prev["ts"] < t <= e["ts"] for t in times)
-        want = "admission" if between(prefills) else \
-            "rowset" if between(restages) else "steady"
+        step = e["args"]["read_step"]
+        since = launched[step - 1]["ts"]
+        want = "admission" if any(
+            since < t <= launched[step]["ts"] for t in prefills) else \
+            "rowset" if launched[step]["args"]["staged"] == 7 else "steady"
         assert e["args"]["cause"] == want, e["args"]
         # the gap is the time between the two reads, on the engine's clock
         assert e["args"]["gap_ms"] == pytest.approx(
@@ -325,11 +340,39 @@ def test_every_models_programs_carry_its_name(prefix, params):
             sds((b,), i32), sds((b,), f32), sds((b,), i32), sds((b,), f32),
             sds((b, 2), np.uint32), rec).as_text()
         assert f"jit_{prefix}_decode_c{cap}_b{b}" in decode
+        firsts = sds((eng._slots + 1, 3), i32)
         prefill = eng._program("prefill", cap, S).lower(
-            p, pool_k, pool_v, sds((), i32), sds((1, S), i32), sds((), i32),
-            sds((), f32), sds((), i32), sds((), f32), sds((2,), np.uint32),
+            p, pool_k, pool_v, sds((1, S), i32), sds((7,), i32), firsts,
             rec).as_text()
         assert f"jit_{prefix}_prefill_c{cap}_b{S}" in prefill
         assert "jit__unknown" not in decode + prefill
     finally:
         eng.shutdown(drain=False)
+
+
+@pytest.mark.parametrize("prefix, params", [
+    ("gpt", lambda: stack_gpt_params(tiny_gpt())),
+    ("lfm2", lambda: seeded_params(lfm2, "lfm2-tiny")),
+    ("brumby", lambda: seeded_params(brumby, "brumby-tiny"))])
+def test_the_join_is_named_as_no_decode_or_prefill_program(prefix, params):
+    """`jit_<prefix>_join_c<cap>_b<bucket>`: the three readers that pick
+    programs by name — the decode and the prefill programs' device time
+    (`engine.decode_program_ms_p50`, `engine.prefill_program_ms_p50`) and
+    a routed model's decode layer-steps (benchmarks/harness/moe_work.py) —
+    select by `_decode_` and `_prefill_`, and must not count the join."""
+    eng = GenerativeEngine(params=params(), slots=2, warmup=False,
+                           auto_start=False, max_context=64)
+    try:
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        cap, b, i32 = eng._caps[-1], eng._batch_buckets[-1], np.int32
+        join = eng._program("join", cap, b).lower(
+            sds((b,), i32), sds((b,), i32), sds((b, 2), np.uint32),
+            sds((eng._slots + 1, 3), i32), sds((b, 9), i32)).as_text()
+    finally:
+        eng.shutdown(drain=False)
+    name = f"jit_{prefix}_join_c{cap}_b{b}"
+    assert name in join and "jit__unknown" not in join
+    assert "_decode_" not in name and "_prefill_" not in name
+    assert "_decode_" not in join and "_prefill_" not in join

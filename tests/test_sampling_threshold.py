@@ -204,14 +204,15 @@ def _lowered(eng, kind):
     state = eng._state_shape()
     rec = () if state is None else (kvq.aval(state, eng._kv_dtype),)
     i32, f32 = np.int32, np.float32
-    row = (_sds((), i32), _sds((1, S), i32))
-    one = (_sds((), f32), _sds((), i32), _sds((), f32), _sds((2,), np.uint32))
+    # a prefill's ids, its packed row (`_pack_row`: extend's with the
+    # cached prefix's length after it) and the first-token table
+    firsts = _sds((eng._slots + 1, 3), i32)
     rows = (_sds((b,), f32), _sds((b,), i32), _sds((b,), f32),
             _sds((b, 2), np.uint32))
     args = {
         "decode": (_sds((b,), i32), _sds((b,), i32), _sds((b,), i32), *rows),
-        "prefill": (*row, _sds((), i32), *one),
-        "extend": (*row, _sds((), i32), _sds((), i32), *one),
+        "prefill": (_sds((1, S), i32), _sds((7,), i32), firsts),
+        "extend": (_sds((1, S), i32), _sds((8,), i32), firsts),
         "verify": (_sds((b,), i32), _sds((b, k), i32), _sds((b,), i32),
                    *rows),
     }[kind]

@@ -164,20 +164,27 @@ def test_worker_spans_exist_and_carry_iter_and_rid(served_spans):
 def test_step_spans_say_whether_they_ran_ahead_and_what_they_staged(
         served_spans):
     """`ahead`: launched while an earlier step was unread; `staged`: host
-    arrays put for it — seven when the row set changed, none on a step
-    ahead (whose `.stage` brackets nothing). A `.wait` is the read of the
-    OLDEST unread step: the one launched a pass before, under the step
-    launched in this pass — or a bare `.wait`, child of no step, where a
-    pass only reads."""
+    arrays put for it — seven where the host staged the rows anew (the
+    class's first fill, a change of bucket), one plan where the row set
+    changed within the bucket and the rows were joined on the device
+    (`joined` of them new), none on a steady step (whose `.stage`
+    brackets nothing). A `.wait` is the read of the OLDEST unread step:
+    the one launched a pass before, under the step launched in this pass
+    — or a bare `.wait`, child of no step, where a pass only reads."""
     steps = sorted((e for e in served_spans
                     if e["name"] == "generate.decode_step"),
                    key=lambda e: e["ts"])
     assert {e["args"]["ahead"] for e in steps} == {0, 1}
-    assert {e["args"]["staged"] for e in steps} == {0, 7}
+    assert {e["args"]["staged"] for e in steps} == {0, 1, 7}
     assert steps[0]["args"]["ahead"] == 0 and steps[0]["args"]["staged"] == 7
     for e in steps:
-        # the row set changed <=> everything was read first <=> not ahead
-        assert (e["args"]["staged"] == 0) == (e["args"]["ahead"] == 1)
+        # staged anew: everything was read first, so not ahead; nothing
+        # staged: a steady step, always launched ahead; a join reads
+        # nothing for it (here both rows end on one step, so nothing is
+        # left unread when the next two join)
+        assert e["args"]["staged"] != 7 or e["args"]["ahead"] == 0
+        assert e["args"]["staged"] != 0 or e["args"]["ahead"] == 1
+        assert e["args"]["joined"] == 0 or e["args"]["staged"] == 1
     # six requests of ten tokens through two slots: most steps run ahead
     assert sum(e["args"]["ahead"] for e in steps) >= 0.6 * len(steps)
     step_ids = {e["args"]["span"] for e in steps}
