@@ -103,15 +103,20 @@ pool, re-cut for the XLA compilation contract):
   the tokens. models/brumby.py::ServingPasses: NO attention layer — the
   state, of the model's own type, is the whole cache; no K/V pool is
   allocated, the pools ride the programs' signatures as None, and a class's
-  `cap` only limits positions). The GPT family's is `GPTPasses` below,
+  `cap` only limits positions. models/mistral4.py::ServingPasses: ONE
+  pool, `kv_pools` 1 — a latent row a position that every head shares,
+  `[rows, L, cap, latent_width]`; no V pool is allocated and None rides in
+  its place; the model reads it with a kernel of its own, whose plan it
+  gives as `read_plan`). The GPT family's is `GPTPasses` below,
   over models/gpt.py's two halves. The worker loop, admission,
   launch-ahead, sampling, streaming and metrics are one code for every
   model.
 
 - **Streaming.** Tokens are emitted per step onto each request's
-  stream queue (GenerateHandle iterates them; server.py chunks them
-  over HTTP) with TTFT/tokens-per-sec metrics on the bus and per-token
-  spans riding the PR-6 tracer.
+  stream queue (GenerateHandle iterates them), or onto the sink a
+  caller gave `submit(stream=...)` (server.py's one writer, which chunks
+  every stream's tokens over HTTP), with TTFT/tokens-per-sec metrics on
+  the bus and per-token spans riding the PR-6 tracer.
 
 Replica lifecycle is the SHARED state machine (lifecycle.py): workers
 are warming -> active -> draining -> retired with a generation counter,
@@ -375,6 +380,10 @@ class GPTPasses:
     program_prefix = "gpt"
     refuses: dict = {}
     state_dtype = None
+    # a K and a V pool, read by the engine's own kernel (`kernel_plan`);
+    # a model that reads its pool its own way gives `read_plan(cap)`
+    kv_pools = 2
+    read_plan = None
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -958,15 +967,16 @@ class _Launched:
     rows are known to be real. `cause` is fixed at the launch, from what
     the device's queue held in front of the step (STEP_GAP_CAUSES)."""
 
-    __slots__ = ("step", "rows", "prog_key", "kv_reads", "ahead", "nxt",
-                 "nkeys", "aux", "cause")
+    __slots__ = ("step", "rows", "prog_key", "kv_reads", "attended",
+                 "ahead", "nxt", "nkeys", "aux", "cause")
 
-    def __init__(self, step, rows, prog_key, kv_reads, ahead, nxt, nkeys,
-                 aux, cause):
+    def __init__(self, step, rows, prog_key, kv_reads, attended, ahead, nxt,
+                 nkeys, aux, cause):
         self.step = step              # the worker's number for it, at launch
         self.rows = rows
         self.prog_key = prog_key      # (device, "decode", cap, bucket)
         self.kv_reads = kv_reads      # per row: pool positions its read copies
+        self.attended = attended      # per row: pool positions it attends
         self.ahead = ahead            # an earlier step was still unread
         self.nxt = nxt
         self.nkeys = nkeys
@@ -1156,6 +1166,7 @@ _REGISTRY = _sm.EngineRegistry("generative", aggregate_snapshot)
                "handoffs_in_total", "migrations_out_total",
                "handoff_bytes_total", "kv_positions_read_total",
                "kv_positions_capacity_total", "state_bytes_moved_total",
+               "attended_positions_total",
                "step_gaps_total", "step_gap_seconds_total",
                "step_gap_tokens_total", "moe_assignments_total",
                "moe_expert_tokens", "moe_distinct_experts_total",
@@ -1188,6 +1199,7 @@ class GenerativeMetrics:
         self.kv_positions_read_total = 0      # positions the steps read
         self.kv_positions_capacity_total = 0  # real rows x class cap
         self.state_bytes_moved_total = 0  # fixed-size state, read + written
+        self.attended_positions_total = 0     # positions real rows attend
         # the gap between two reads of a class's steps, by its cause: every
         # live row of a step gets its token(s) at the read, so weighted by
         # tokens this is the engine's own inter-token gap
@@ -1252,7 +1264,7 @@ class GenerativeMetrics:
     def on_step(self, rows: int, bucket: int, kv_read: int = 0,
                 kv_capacity: int = 0, ahead: bool = False,
                 state_bytes: int = 0, cause: Optional[str] = None,
-                gap_s: float = 0.0, tokens: int = 0):
+                gap_s: float = 0.0, tokens: int = 0, attended: int = 0):
         """One decode step of `rows` real rows in a batch bucket;
         `kv_read` of the rows' `kv_capacity` (rows x class cap) pool
         positions were read by the step's attention, and `state_bytes` of
@@ -1261,7 +1273,8 @@ class GenerativeMetrics:
         of STEP_GAP_CAUSES; None for the first read of a class that held
         no row, which follows no read): what the worker did in the `gap_s`
         seconds since the class's previous step was read, and `tokens`
-        the rows got at this one."""
+        the rows got at this one. `attended`: the pool positions the real
+        rows attend, summed over them, whatever read them."""
         with self._lock:
             if cause is not None:
                 self.step_gaps_total[cause] += 1
@@ -1274,6 +1287,7 @@ class GenerativeMetrics:
             self.kv_positions_read_total += int(kv_read)
             self.kv_positions_capacity_total += int(kv_capacity)
             self.state_bytes_moved_total += int(state_bytes)
+            self.attended_positions_total += int(attended)
             self.occupancy_hist[rows] = \
                 self.occupancy_hist.get(rows, 0) + 1
 
@@ -1418,6 +1432,7 @@ class GenerativeMetrics:
                     self.kv_positions_read_total,
                     self.kv_positions_capacity_total),
                 "state_bytes_moved_total": self.state_bytes_moved_total,
+                "attended_positions_total": self.attended_positions_total,
                 # flat scalars: aggregate_snapshot sums those, and a
                 # reader subtracts two snapshots key by key
                 **{f"step_gaps_{c}_total": n
@@ -1533,6 +1548,9 @@ class GenerativeMetrics:
         metric("paddle_generate_state_bytes_moved_total", "counter",
                s["state_bytes_moved_total"],
                "bytes of fixed-size state the decode steps read and wrote")
+        metric("paddle_generate_attended_positions_total", "counter",
+               s["attended_positions_total"],
+               "cache positions the decode steps' real rows attend")
         for name, help_ in (
                 ("step_gaps",
                  "reads of a decode step that followed another read of its "
@@ -1700,6 +1718,10 @@ class GenerativeEngine:
         self._H = self._model.kv_heads
         self._Dh = self._model.head_dim
         self._L = self._model.kv_layers
+        # a K and a V pool, or ONE pool (a latent row a position, whose
+        # keys and values the model derives itself): `kv_pools` 1, and no
+        # V pool rides the programs (None, as for a model with no pool)
+        self._pools = self._model.kv_pools
         self._vocab = self._model.vocab_size
 
         self._slots = int(slots if slots is not None
@@ -1983,6 +2005,7 @@ class GenerativeEngine:
         zk = zv = None      # a model with no attention layer has no pool
         if self._L:
             zk = _kvq.alloc(self._pool_shape(cap), device, self._kv_dtype)
+        if self._pools == 2:
             zv = _kvq.alloc(self._pool_shape(cap), device, self._kv_dtype)
         dk = dv = None
         if self._spec:
@@ -2025,6 +2048,10 @@ class GenerativeEngine:
         Mirrors pool_attention's choice."""
         if kind == "decode":
             H, Dh, Hq = self._H, self._Dh, self._model.query_heads
+            own = self._model.read_plan
+            if own is not None:     # a model that reads its pool its own way
+                return own(cap) if _lowers_for_tpu(self._device_pool[0]) \
+                    else None
         elif kind == "dpropose":
             H, Dh, Hq = self._dH, self._dDh, self._dH
         else:
@@ -2035,17 +2062,18 @@ class GenerativeEngine:
 
     def kv_pool_bytes(self) -> int:
         """Bytes ONE worker's cache allocates, of either kind: the K/V
-        pools (all capacity classes, K+V, target + draft geometry, scratch
-        and prefix-cache rows included) and the model's fixed-size state
-        beside them — or the state alone, for a model with no attention
-        layer. The density denominator serve_bench's quantized gate
-        divides by; int8 halves-and-halves-again the f32 figure (int8
-        data + the small per-(row, layer) scale tensor)."""
+        pools (all capacity classes, K+V — or the one pool of a model whose
+        cache is one —, target + draft geometry, scratch and prefix-cache
+        rows included) and the model's fixed-size state beside them — or
+        the state alone, for a model with no attention layer. The density
+        denominator serve_bench's quantized gate divides by; int8
+        halves-and-halves-again the f32 figure (int8 data + the small
+        per-(row, layer) scale tensor)."""
         total = 0
         for cap in self._caps:
             if self._L:
-                total += 2 * _kvq.pool_nbytes(self._pool_shape(cap),
-                                              self._kv_dtype)
+                total += self._pools * _kvq.pool_nbytes(
+                    self._pool_shape(cap), self._kv_dtype)
             if self._state_shape() is not None:
                 total += _kvq.pool_nbytes(self._state_shape(),
                                           self._model.state_dtype)
@@ -2078,11 +2106,16 @@ class GenerativeEngine:
             "kv_classes": list(self._caps),
             "kv_dtype": self._kv_dtype,
             # the cache by layer type: a class's K/V pool (K and V each;
-            # none for a model with no attention layer) and the fixed-size
-            # state (None: the model has none), in `state_dtype`
+            # none for a model with no attention layer) or its ONE latent
+            # pool, and the fixed-size state (None: the model has none), in
+            # `state_dtype`
             "cache": {"model": self._model.name,
                       "kv_pool": {cap: list(self._pool_shape(cap))
-                                  for cap in self._caps if self._L},
+                                  for cap in self._caps
+                                  if self._pools == 2},
+                      **({"latent_pool": {cap: list(self._pool_shape(cap))
+                                          for cap in self._caps}}
+                         if self._pools == 1 else {}),
                       "state": None if self._state_shape() is None
                       else list(self._state_shape())},
             "state_dtype": self._model.state_dtype,
@@ -2116,12 +2149,12 @@ class GenerativeEngine:
 
     def _cache_avals(self, cap: int) -> tuple:
         """(K pool, V pool, state) of class `cap` as shapes; None what
-        the model has none of."""
+        the model has none of (a one-pool model's in the K pool's place)."""
         pool = _kvq.aval(self._pool_shape(cap), self._kv_dtype) \
             if self._L else None
         rec = self._state_shape()
-        return pool, pool, None if rec is None else _kvq.aval(
-            rec, self._model.state_dtype)
+        return pool, pool if self._pools == 2 else None, \
+            None if rec is None else _kvq.aval(rec, self._model.state_dtype)
 
     # ----------------------------------------------------------- workers --
     def _new_worker(self, device=None) -> ReplicaSlot:
@@ -2720,7 +2753,7 @@ class GenerativeEngine:
                top_p: Optional[float] = None,
                seed: Optional[int] = None,
                prefill_only: bool = False,
-               resume_from: int = 0) -> GenerateHandle:
+               resume_from: int = 0, stream=None) -> GenerateHandle:
         """Enqueue one generation; returns its streaming handle. Raises
         ServingError for decode rejects (400) and load shedding (503).
 
@@ -2730,7 +2763,13 @@ class GenerativeEngine:
         ``resume_from=n`` is the replay-resume path — the client
         already holds n tokens from a lost host, so regeneration (the
         key-chain law makes it bitwise) suppresses re-delivery of the
-        first n."""
+        first n.
+
+        ``stream``: where the request's events go in place of the
+        handle's own queue — any object whose ``put((kind, value))``
+        takes them from the engine's threads (the HTTP front's one writer
+        for every streamed reply); the handle's ``events()`` then has
+        nothing to read, and ``result()`` is as before."""
         if prefill_only:
             self._refuse_handoff("prefill_only")
         bound = self._queue_bound()
@@ -2752,6 +2791,8 @@ class GenerativeEngine:
                                        eos_token_id, deadline_ms,
                                        temperature, top_k, top_p, seed)
             req.prefill_only = bool(prefill_only)
+            if stream is not None:
+                req.stream = stream
             if resume_from:
                 try:
                     rf = int(resume_from)
@@ -3221,6 +3262,9 @@ class GenerativeEngine:
         kv_plan = self._kv_plan("decode", cs.cap)
         kv_reads = [0 if not self._L else cs.cap if kv_plan is None else
                     kv_plan.positions_read(x, cs.cap) for x in positions]
+        # and the positions each attends, whatever reads them
+        attended = [min(x, cs.cap - 1) + 1 if self._L else 0
+                    for x in positions]
         if not self._busy(w, gen, prog_keys):
             return
         w.launched += 1
@@ -3235,6 +3279,7 @@ class GenerativeEngine:
             args = {"replica": w.rid, "step": w.launched, "rows": n,
                     "bucket": bucket,
                     "cap": cs.cap, "kv_read": sum(kv_reads),
+                    "attended": sum(attended),
                     "state_bytes": self._model.state_step_bytes(n, bucket),
                     "spec_k": 0,
                     "ahead": int(behind > 0),
@@ -3283,8 +3328,8 @@ class GenerativeEngine:
                     for out in jax.tree.leaves((nxt, nkeys, aux)):
                         out.copy_to_host_async()
                     dev.unread.append(_Launched(
-                        w.launched, rows, prog_key, kv_reads, behind > 0,
-                        nxt, nkeys, aux, cause))
+                        w.launched, rows, prog_key, kv_reads, attended,
+                        behind > 0, nxt, nkeys, aux, cause))
                     del stale
                 with _tr.span("generate.decode_step.wait", "serving",
                               read_args):
@@ -3405,7 +3450,7 @@ class GenerativeEngine:
         return self._emit_step(
             w, gen, cs, step.rows, [step.prog_key], step.prog_key[3],
             step.kv_reads, [[int(t)] for t in nxt[:len(step.rows)]],
-            nkeys, span, step.cause, step.ahead, experts)
+            nkeys, span, step.cause, step.ahead, experts, step.attended)
 
     def _read_oldest(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                      phase: Optional[dict]) -> bool:
@@ -3548,7 +3593,8 @@ class GenerativeEngine:
     def _emit_step(self, w: ReplicaSlot, gen: int, cs: _ClassState,
                    rows: list, prog_keys: list, bucket: int,
                    kv_reads: list, toks: list, keys, span, cause: str,
-                   ahead: bool = False, experts=None) -> bool:
+                   ahead: bool = False, experts=None,
+                   attended: Optional[list] = None) -> bool:
         """What follows a decode step's read on the worker thread: row i
         takes the tokens toks[i] (one, or a speculative burst's accepted
         prefix) and the key keys[i] that follows them — the rows'
@@ -3564,6 +3610,8 @@ class GenerativeEngine:
         previous step was read, with the `cause` the step's launch named
         (STEP_GAP_CAUSES) — to the counters with the step, and with
         the live rows onto `span`, the `generate.emit` this runs under.
+        `attended` (per row, the pool positions its step attends; None: not
+        counted) goes to the counters for the live rows alone.
         A step that emits to no row hands nothing over and closes no gap;
         neither does the first read of a class that had held no row. The
         read's time is the live span's own opening stamp, so a trace's
@@ -3596,7 +3644,9 @@ class GenerativeEngine:
                                  self._model.state_step_bytes(len(rows),
                                                               bucket),
                                  cause=cause, gap_s=gap,
-                                 tokens=sum(len(toks[i]) for i in live))
+                                 tokens=sum(len(toks[i]) for i in live),
+                                 attended=0 if attended is None else
+                                 sum(attended[i] for i in live))
             if _tr.enabled():
                 span.set(rows=len(live))
                 if cause is not None:
@@ -3689,7 +3739,7 @@ class GenerativeEngine:
                 409, f"{self._model.name}: this model is not served with "
                      f"handoff ({call}) — {why}")
 
-    def import_handoff(self, raw: bytes) -> GenerateHandle:
+    def import_handoff(self, raw: bytes, stream=None) -> GenerateHandle:
         """Admit one exported KV slot (the /admin/kv plane's POST).
         Geometry and kv_dtype must match this engine exactly — 409
         otherwise (the fabric router treats that as "this host refuses
@@ -3698,7 +3748,8 @@ class GenerativeEngine:
         worker scatters the row into a free slot with the warmed kvput
         program and decode continues bitwise (the key-chain cursor
         rides the payload). Tokens up to meta["streamed"] are
-        suppressed on re-emission — zero duplicates downstream."""
+        suppressed on re-emission — zero duplicates downstream.
+        ``stream`` as ``submit``'s."""
         from ..fabric import handoff as _ho
 
         self._refuse_handoff("import_handoff")
@@ -3791,6 +3842,8 @@ class GenerativeEngine:
             eos, None, temperature=temp, top_k=tk, top_p=tp, seed=sd)
         req.requeues = int(meta.get("requeues", 0))
         req.streamed = streamed
+        if stream is not None:
+            req.stream = stream
         req.handoff = (meta, arrays)
         bound = self._queue_bound()
         with _tr.span("generate.import", "serving") as sp:

@@ -66,6 +66,7 @@ from __future__ import annotations
 import base64
 import io
 import json
+import queue
 import struct
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -256,8 +257,8 @@ class _Handler(BaseHTTPRequestHandler):
                 # raw wire payload in, the continued stream out: the
                 # importer's handle streams exactly like /generate —
                 # the relaying router splices the lines verbatim
-                handle = self.generator.import_handoff(body)
-                self._stream_reply(handle)
+                self._stream_reply(lambda sink: self.generator
+                                   .import_handoff(body, stream=sink))
                 return
             if not self.path.startswith("/admin/scale"):
                 self.close_connection = True
@@ -329,8 +330,8 @@ class _Handler(BaseHTTPRequestHandler):
         except Exception as e:  # noqa: BLE001
             raise ServingError(400, f"bad request body: {e!r}"[:2000]) \
                 from None
-        handle = self.generator.submit(input_ids, **kw)
         if not stream or kw["prefill_only"]:
+            handle = self.generator.submit(input_ids, **kw)
             # prefill_only never streams: its "result" IS the handoff
             # payload the caller re-homes — no tokens belong here
             timeout = 300.0
@@ -339,45 +340,38 @@ class _Handler(BaseHTTPRequestHandler):
                 timeout = float(kw["deadline_ms"]) / 1e3 + 60.0
             self._send_json(200, handle.result(timeout))
             return
-        self._stream_reply(handle)
+        self._stream_reply(
+            lambda sink: self.generator.submit(input_ids, stream=sink, **kw))
 
-    def _stream_reply(self, handle):
+    def _stream_reply(self, start):
         # chunked ndjson: the decode loop feeds the wire token by
-        # token. Headers go out before the first token, so a failure
+        # token, through the server's one writer (_StreamPump); `start`
+        # (sink) -> the handle submits the request with its events going
+        # to the sink, and may refuse it before any header is out.
+        # Headers go out before the first token, so a failure
         # mid-generation is surfaced as a terminal {"error": ...} line
         # (the HTTP status is already committed — the error can only
         # ride the stream). Shared by /generate and /admin/kv/import —
         # a relaying router splices either stream into its client's.
-        self.send_response(200)
-        self.send_header("Content-Type", "application/x-ndjson")
-        self.send_header("Transfer-Encoding", "chunked")
-        self.end_headers()
-
-        def chunk(obj) -> None:
-            data = (json.dumps(obj) + "\n").encode()
-            self.wfile.write(f"{len(data):X}\r\n".encode() +
-                             data + b"\r\n")
-            self.wfile.flush()
-
+        pump = self.owner.pump
+        sink = pump.sink(self.connection)
+        start(sink)
         try:
+            self.send_response(200)
+            self.send_header("Content-Type", "application/x-ndjson")
+            self.send_header("Transfer-Encoding", "chunked")
+            self.end_headers()
+            blocking = self.connection.gettimeout()
+            self.connection.setblocking(False)
             try:
-                for kind, val in handle.events():
-                    if kind == "tok":
-                        chunk({"token": int(val)})
-                    elif kind == "handoff":
-                        # migrate-on-drain terminal: NOT done — the
-                        # stream is moving hosts; the line carries the
-                        # payload the router imports on a survivor
-                        chunk(dict(val))
-                    else:
-                        chunk(dict(val, done=True))
-            except OSError:
-                raise
-            except ServingError as e:
-                chunk({"error": e.message, "status": e.status})
-            except Exception as e:  # noqa: BLE001
-                chunk({"error": repr(e)[:2000], "status": 500})
-            self.wfile.write(b"0\r\n\r\n")
+                pump.open(sink)
+                sink.finished.wait()
+            finally:
+                self.connection.settimeout(blocking)
+            if sink.failed:
+                raise OSError("the client went away mid-stream")
+            # what the socket had no room for, and the last chunk
+            self.wfile.write(sink.rest + b"0\r\n\r\n")
         except OSError:
             # the client went away mid-stream: the 200 is already
             # committed, so there is nobody left to tell and nothing
@@ -449,6 +443,174 @@ class _Handler(BaseHTTPRequestHandler):
                                    timeout=timeout)
 
 
+_TERMINAL = ("done", "handoff", "err")
+# how soon the pump tries again a socket that had no room for a chunk
+_RETRY_S = 0.005
+
+
+def _chunk(kind: str, val) -> bytes:
+    """One stream event as the chunk that carries its ndjson line."""
+    if kind == "tok":
+        data = b'{"token": %d}\n' % int(val)
+    else:
+        if kind == "handoff":
+            # migrate-on-drain terminal: NOT done — the stream is moving
+            # hosts; the line carries the payload the router imports on
+            # a survivor
+            obj = dict(val)
+        elif kind == "err":
+            obj = {"error": val.message, "status": val.status} \
+                if isinstance(val, ServingError) else \
+                {"error": repr(val)[:2000], "status": 500}
+        else:
+            obj = dict(val, done=True)
+        data = (json.dumps(obj) + "\n").encode()
+    return b"%X\r\n%s\r\n" % (len(data), data)
+
+
+class _Sink:
+    """One streamed reply's end of the pump. The engine's `put` lands on
+    the pump's queue; only the pump's thread turns the events into chunks
+    (`out`) and sends them, once the reply's headers are out (`open`).
+    At the stream's last event it hands what the socket had no room for
+    (`rest`) back to the reply's thread and sets `finished`; `failed`:
+    the client went away."""
+
+    __slots__ = ("_q", "sock", "out", "open", "ending", "rest", "failed",
+                 "finished")
+
+    def __init__(self, q: "queue.SimpleQueue", sock):
+        self._q = q
+        self.sock = sock
+        self.out = bytearray()
+        self.open = False
+        self.ending = False
+        self.rest = b""
+        self.failed = False
+        self.finished = threading.Event()
+
+    def put(self, event) -> None:
+        self._q.put((self, event))
+
+
+class _StreamPump:
+    """One thread writes the lines of every streamed reply.
+
+    A reply thread a stream woken for every token is, at 128 streaming
+    rows, 128 wake-ups and GIL hand-offs a decode step: they hold up the
+    engine's worker, which launches the next step, and they run in
+    another order every step, so the gaps a client reads between its
+    tokens spread by the time they all take. Here the engine puts every
+    stream's events on one queue; the pump takes what is there, appends
+    each stream's chunks and sends them with one non-blocking send a
+    stream, in the order the worker emitted them. A socket that has no
+    room keeps its bytes and is tried again; the reply's thread sleeps
+    until its stream ends, then writes the rest and the last chunk."""
+
+    def __init__(self):
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+        self._live: set = set()         # sinks open and not finished
+
+    def sink(self, sock) -> _Sink:
+        with self._lock:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="serving-stream", daemon=True)
+                self._thread.start()
+        return _Sink(self._q, sock)
+
+    def open(self, sink: _Sink) -> None:
+        """The reply's headers are out and its socket does not block: the
+        pump may send its chunks."""
+        with self._lock:
+            if self._thread is None:        # closed: nobody would send
+                sink.failed = True
+                sink.finished.set()
+                return
+            if not sink.finished.is_set():
+                self._live.add(sink)
+            sink.open = True
+        sink.put(None)
+
+    def close(self) -> None:
+        """Send what is queued, then end the thread: a stream still open
+        ends as a client gone (its reply's thread closes the connection)."""
+        with self._lock:
+            thread, self._thread = self._thread, None
+        if thread is not None:
+            self._q.put((None, None))
+            thread.join(5.0)
+
+    def _run(self) -> None:
+        q, backlog, stop = self._q, [], False
+        while not stop:
+            try:
+                batch = [q.get(timeout=_RETRY_S) if backlog else q.get()]
+            except queue.Empty:
+                batch = []
+            while True:
+                try:
+                    batch.append(q.get_nowait())
+                except queue.Empty:
+                    break
+            touched = dict.fromkeys(backlog)
+            for sink, event in batch:
+                if sink is None:
+                    stop = True
+                    continue
+                if sink.finished.is_set() or sink.ending:
+                    continue
+                if event is not None:
+                    try:
+                        sink.out += _chunk(*event)
+                    except Exception as e:  # noqa: BLE001 — a bad event
+                        # must not take the other streams down
+                        sink.out += _chunk("err", e)
+                        sink.ending = True
+                    sink.ending = sink.ending or event[0] in _TERMINAL
+                touched[sink] = None
+            backlog = [sink for sink in touched if not self._send(sink)]
+        with self._lock:
+            live, self._live = self._live, set()
+        for sink in live:
+            if not sink.finished.is_set():
+                sink.failed = True
+                sink.finished.set()
+
+    def _send(self, sink: _Sink) -> bool:
+        """Send what the socket takes of the sink's chunks -> True where
+        nothing is left for the pump to do."""
+        if sink.open and sink.out:
+            try:
+                n = sink.sock.send(sink.out)
+                del sink.out[:n]
+            except BlockingIOError:
+                pass
+            except OSError:
+                sink.failed = True
+                self._finish(sink)
+                return True
+        if sink.ending:
+            sink.rest = bytes(sink.out)
+            self._finish(sink)
+            return True
+        return not (sink.open and sink.out)
+
+    def _finish(self, sink: _Sink) -> None:
+        with self._lock:
+            self._live.discard(sink)
+            sink.finished.set()
+
+
+class _Listener(ThreadingHTTPServer):
+    # the kernel's accept queue: socketserver's default of 5 resets
+    # connections when a closed loop of a few hundred streaming clients
+    # connects at once (the kernel caps it at net.core.somaxconn)
+    request_queue_size = 1024
+
+
 class ServingHTTPServer:
     """ThreadingHTTPServer bound to one engine and/or one generative
     engine; start()/stop() for embedding (tests, serve_bench),
@@ -469,8 +631,9 @@ class ServingHTTPServer:
         self.generator = generator
         self.admin = bool(admin)
         self._drainer: Optional[threading.Thread] = None
-        self.httpd = ThreadingHTTPServer((host, port), handler)
+        self.httpd = _Listener((host, port), handler)
         self.httpd.daemon_threads = True
+        self.pump = _StreamPump()
         self.host, self.port = self.httpd.server_address[:2]
         self._thread: Optional[threading.Thread] = None
 
@@ -544,6 +707,7 @@ class ServingHTTPServer:
             self.generator.shutdown(drain=drain, migrate=migrate)
         self.httpd.shutdown()
         self.httpd.server_close()
+        self.pump.close()
         if self._thread is not None:
             self._thread.join(10.0)
             self._thread = None

@@ -231,6 +231,8 @@ class ServingPasses:
     # no K/V layer: the engine allocates no pool, and `kv_dtype` names none
     kv_dtype = "f32"
     kv_layers = 0
+    kv_pools = 0
+    read_plan = None
     state_dtype = "f32"
     expert_layers = 0
 

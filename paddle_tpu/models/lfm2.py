@@ -446,6 +446,9 @@ class ServingPasses:
     # what joins a device operation to the scope it was traced under is
     # keyed by the program's name (observability/trace.py)
     program_prefix = "lfm2"
+    # a K and a V pool for its attention layers, read by the engine's kernel
+    kv_pools = 2
+    read_plan = None
 
     def __init__(self, cfg: LFM2Config):
         self.cfg = cfg

@@ -315,6 +315,98 @@ def test_brumby_programs_compile_for_a_v5e_at_the_cells_size(
           mem.argument_size_in_bytes, "alias", mem.alias_size_in_bytes)
 
 
+@pytest.mark.parametrize("kind, bucket", [("decode", 128),
+                                          ("prefill", 2048)])
+def test_mistral4_programs_compile_for_a_v5e_at_the_cells_size(
+        one_chip, no_persistent_cache, kind, bucket):
+    """`mistral-small-4-119b` as the benchmark's cell serves it (128 slots,
+    one class of 8,192 positions, ONE latent pool and no V pool): the
+    decode program of all slots and the prefill of the traffic's longest
+    prompt bucket, from shapes alone. The decode program takes the
+    `mla_decode` kernel, one a layer, over the bfloat16 latent pool where it
+    lies: the pool (6.49 GB) aliases its input and the temporaries hold
+    nothing of its size — under 200 MB beside 7.57 GB of weights. The
+    prefill takes the flash kernel over the expanded heads."""
+    from paddle_tpu.inference.serving import GenerativeEngine
+    from paddle_tpu.models import mistral4
+
+    cfg = mistral4.PRESETS["mistral-small-4-119b"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {n: sds(shape, jnp.bfloat16)
+              for n, (shape, _) in mistral4.param_shapes(cfg).items()}
+    eng = GenerativeEngine(params=({}, cfg), slots=128, warmup=False,
+                           auto_start=False, donate=True)
+    try:
+        cap = eng._caps[-1]
+        pool = sds(eng._pool_shape(cap), jnp.bfloat16)
+        assert pool.shape == (129, 8, 8192, 384)
+        assert eng._cache_avals(cap)[1:] == (None, None)
+        if kind == "decode":
+            b = bucket
+            rows = (sds((b,), jnp.int32), sds((b,), jnp.int32),
+                    sds((b,), jnp.int32), sds((b,), jnp.float32),
+                    sds((b,), jnp.int32), sds((b,), jnp.float32),
+                    sds((b, 2), jnp.uint32))
+        else:
+            # the ids, the packed row, the first-token table
+            rows = (sds((1, bucket), jnp.int32), sds((7,), jnp.int32),
+                    sds((129, 3), jnp.int32))
+        compiled = eng._program(kind, cap, bucket).lower(
+            params, pool, None, *rows, None).compile()
+        pool_bytes = eng.kv_pool_bytes()
+    finally:
+        eng.shutdown(drain=False)
+    mem = compiled.memory_analysis()
+    assert pool_bytes == 2 * int(np.prod(pool.shape)) == 6_492_782_592
+    assert mem.alias_size_in_bytes >= pool_bytes
+    n_bytes = 2 * mistral4.n_params(cfg)
+    assert mem.argument_size_in_bytes > n_bytes + pool_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 0.9 * 16.9e9
+    text = compiled.as_text()
+    assert f"jit_mistral4_{kind}_c8192_b{bucket}" in text
+    assert "mla.attention" in text and "moe.experts" in text \
+        and "moe.shared" in text and "moe.route" in text
+    if kind == "decode":
+        assert mem.temp_size_in_bytes < 200e6
+        assert "tpu_custom_call" in text and "mla_decode" in text
+    else:
+        assert mem.temp_size_in_bytes < 2**30
+        assert "flash_fwd" in text
+    print(kind, bucket, "temp", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes, "alias", mem.alias_size_in_bytes)
+
+
+def test_mla_decode_compiles_for_a_v5e_at_the_cells_shapes(
+        one_chip, no_persistent_cache):
+    """The kernel alone over the cell's latent pool: 128 rows of 32 heads,
+    a plan of 1,024-position blocks, the pool aliased by nothing and
+    copied by nothing."""
+    from paddle_tpu.ops.pallas import mla_decode as mla
+
+    plan = mla.latent_block_plan(8192, 32, 384, 256, 2)
+    assert plan is not None and plan.block == 1024
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda q, pool, layer, slots, lens: mla.mla_decode(
+        q, pool, layer, slots, lens, value_width=256,
+        sm_scale=0.195, scratch=128))
+    compiled = fn.lower(sds((128, 32, 384), jnp.bfloat16),
+                        sds((129, 8, 8192, 384), jnp.bfloat16),
+                        sds((), jnp.int32), sds((128,), jnp.int32),
+                        sds((128,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert "mla_decode" in text and "tpu_custom_call" in text
+    assert mem.temp_size_in_bytes < 50e6
+    print("mla_decode temp", mem.temp_size_in_bytes)
+
+
 # --------------------------------------------- the train step's remat plan --
 # a v5e's `bytes_limit` as the chip's runtime reports it (memory_stats on
 # the cells' own runs): what TrainStep's plan reads there as the device's

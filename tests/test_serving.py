@@ -12,6 +12,7 @@ import struct
 import subprocess
 import sys
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -374,6 +375,35 @@ class TestHTTPServer:
             got, model(paddle.to_tensor(X)).numpy(), rtol=1e-5,
             atol=1e-6)
 
+    def test_a_burst_of_connections_is_accepted(self, server):
+        """Three hundred clients connect at once, as a closed loop of a
+        few hundred streaming callers does at its start: every connection
+        is accepted and answered (the listen backlog is no longer
+        socketserver's 5, which reset some of them)."""
+        import socket
+        import threading
+
+        srv, _ = server
+        socks = [socket.create_connection(("127.0.0.1", srv.port),
+                                          timeout=60) for _ in range(300)]
+        answers = [None] * len(socks)
+
+        def ask(i):
+            socks[i].sendall(b"GET /healthz HTTP/1.1\r\nHost: x\r\n"
+                             b"Connection: close\r\n\r\n")
+            answers[i] = socks[i].recv(64)
+            socks[i].close()
+
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(len(socks))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert srv.httpd.request_queue_size >= 300
+        assert all(a and a.startswith(b"HTTP/1.") and b" 200 " in a
+                   for a in answers), answers[:3]
+
     def test_predict_json_nested_lists(self, server):
         srv, model = server
         X = np.random.RandomState(1).randn(2, 8).astype("float32")
@@ -548,3 +578,179 @@ def test_warm_restart_serves_with_zero_fresh_compiles(tmp_path, runs):
     assert warm["warmup"]["persistent_hits"] > 0
     assert warm["stats"]["misses"] == 0
     assert warm["stats"]["hits"] >= warm["warmup"]["persistent_hits"]
+
+
+# ---------------------------------------------- streamed replies, one writer --
+class _ScriptedGenerator:
+    """A generative front with no model: `submit` hands the events of
+    `script(i, input_ids)` (the i-th request's) to the sink the server
+    passes, from a thread of its own, one step of every live request at a
+    time as the engine's worker emits them — or before `submit` returns
+    (`early`), ahead of the reply's headers."""
+
+    def __init__(self, script, early=False, step_s=0.001):
+        self.script, self.early, self.step_s = script, early, step_s
+        self.submitted = 0
+        self._lock = threading.Lock()
+
+    def submit(self, input_ids, stream=None, **kw):
+        with self._lock:
+            i = self.submitted
+            self.submitted += 1
+        events = self.script(i, list(input_ids))
+        if isinstance(events, Exception):
+            raise events
+        if self.early:
+            for ev in events:
+                stream.put(ev)
+            return None
+
+        def play():
+            for ev in events:
+                stream.put(ev)
+                time.sleep(self.step_s)
+        threading.Thread(target=play, daemon=True).start()
+        return None
+
+    def shutdown(self, drain=True, migrate=False):
+        pass
+
+
+def _stream_lines(port, ids, stop_after=None):
+    """POST a streamed /generate -> (status, the ndjson lines read)."""
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+    try:
+        conn.request("POST", "/generate", json.dumps(
+            {"input_ids": ids, "stream": True}).encode(),
+            {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        if resp.status != 200:
+            return resp.status, [json.loads(resp.read())]
+        lines = []
+        while True:
+            line = resp.readline()
+            if not line:
+                break
+            lines.append(json.loads(line))
+            if stop_after is not None and len(lines) >= stop_after:
+                break
+        return resp.status, lines
+    finally:
+        conn.close()
+
+
+def _tokens_then_done(n):
+    def script(i, ids):
+        toks = [ids[0] * 1000 + j for j in range(n)]
+        return [("tok", t) for t in toks] + [
+            ("done", {"tokens": toks, "n_tokens": n})]
+    return script
+
+
+@pytest.mark.parametrize("early", [False, True])
+def test_streams_are_written_by_one_thread(early):
+    """Sixteen streams at once, their events from another thread (or put
+    before the reply's headers are out): each client reads its own tokens
+    in order, then the done line, in the lines json.dumps writes — and no
+    reply thread was woken a token, one pump thread wrote them all."""
+    from paddle_tpu.inference.serving import ServingHTTPServer
+
+    n = 40
+    srv = ServingHTTPServer(None, generator=_ScriptedGenerator(
+        _tokens_then_done(n), early=early)).start()
+    try:
+        got = [None] * 16
+
+        def ask(r):
+            got[r] = _stream_lines(srv.port, [r + 1])
+
+        threads = [threading.Thread(target=ask, args=(r,))
+                   for r in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        for r, (status, lines) in enumerate(got):
+            assert status == 200
+            want = [(r + 1) * 1000 + j for j in range(n)]
+            assert [ln["token"] for ln in lines[:-1]] == want
+            assert lines[-1] == {"tokens": want, "n_tokens": n,
+                                 "done": True}
+        pumps = [t for t in threading.enumerate()
+                 if t.name == "serving-stream"]
+        assert len(pumps) == 1
+    finally:
+        srv.stop()
+    assert not any(t.name == "serving-stream" and t.is_alive()
+                   for t in threading.enumerate())
+
+
+def test_a_stream_chunk_is_the_line_json_writes():
+    from paddle_tpu.inference.serving.server import _chunk
+    from paddle_tpu.inference.serving import ServingError
+
+    for tok in (0, 7, 131071):
+        data = (json.dumps({"token": tok}) + "\n").encode()
+        assert _chunk("tok", tok) == b"%X\r\n%s\r\n" % (len(data), data)
+    assert b'"done": true' in _chunk("done", {"n_tokens": 1})
+    assert b'{"error": "gone", "status": 503}' in _chunk(
+        "err", ServingError(503, "gone"))
+    assert b'"status": 500' in _chunk("err", RuntimeError("boom"))
+    assert b'"done"' not in _chunk("handoff", {"handoff": "x"})
+
+
+def test_a_refused_stream_answers_with_its_status():
+    """A request the engine refuses before it is queued gets its status
+    and JSON body, never a chunked 200."""
+    from paddle_tpu.inference.serving import ServingError, ServingHTTPServer
+
+    srv = ServingHTTPServer(None, generator=_ScriptedGenerator(
+        lambda i, ids: ServingError(503, "queue full"))).start()
+    try:
+        status, body = _stream_lines(srv.port, [1])
+        assert status == 503 and body == [{"error": "queue full"}]
+    finally:
+        srv.stop()
+
+
+def test_an_error_ends_its_stream_and_no_other():
+    """One stream ends in an error line after two tokens; a client that
+    leaves after its first token is dropped; the streams beside them run
+    to their done lines through the same pump."""
+    from paddle_tpu.inference.serving import ServingError, ServingHTTPServer
+
+    def script(i, ids):
+        if ids[0] == 1:
+            return [("tok", 5), ("tok", 6),
+                    ("err", ServingError(500, "device lost"))]
+        return _tokens_then_done(200)(i, ids)
+
+    srv = ServingHTTPServer(None, generator=_ScriptedGenerator(
+        script)).start()
+    try:
+        out = {}
+
+        def ask(first, stop_after=None):
+            out[first] = _stream_lines(srv.port, [first], stop_after)
+
+        threads = [threading.Thread(target=ask, args=(1,)),
+                   threading.Thread(target=ask, args=(2, 1))] + \
+            [threading.Thread(target=ask, args=(r,)) for r in (3, 4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert out[1] == (200, [{"token": 5}, {"token": 6},
+                                {"error": "device lost", "status": 500}])
+        assert out[2] == (200, [{"token": 2000}])
+        for r in (3, 4):
+            status, lines = out[r]
+            assert status == 200 and lines[-1]["done"] and \
+                len(lines) == 201
+        # the server still streams after a client left mid-stream
+        status, lines = _stream_lines(srv.port, [5])
+        assert status == 200 and len(lines) == 201
+    finally:
+        srv.stop()
