@@ -16,19 +16,30 @@ The pool is `[rows, L, cap, W]`: a row of it is a SLOT (the engine's
 `_pool_shape`), a position `[c ‖ k_r ‖ 0]`, `W` the latent and the rotary
 key padded to whole 128-lane tiles (a TPU lays a narrower minor dimension
 out padded to them in HBM anyway; the padding lanes hold zeros, so the
-query's zeros there add nothing). `slots`, each row's last live position
-and the layer are scalar-prefetched, and the `BlockSpec` index map picks
-block `(slots[i], layer, j)` of the pool in HBM: a block wholly past the
-row's last position is neither computed (`pl.when`) nor copied (the index
-stays on the row's last live block, so the pipeline issues no DMA). Inside
-the last live block positions past `last` are masked in the scores and
-zeroed in the values — whatever the pool holds there, a NaN included,
-never reaches the output. A padding row (its slot the scratch slot)
-computes nothing and moves nothing: its index map holds the block of the
-last real row before it, and its output is nought.
+query's zeros there add nothing).
+
+**The copy loop.** The pool stays in HBM (`memory_space=pl.ANY`) and the
+kernel copies what it reads itself, by `make_async_copy` into
+`_IN_FLIGHT + 1` VMEM buffers with a DMA semaphore each. The grid is one
+step a row, never one a block. A row whose new token sits at `last`
+reads `pool[slot, layer, 0 : (last // chunk + 1)·chunk]` and nothing past
+it: each whole block before its last in one copy, its last block in one
+copy a chunk, up to the chunk that holds `last`. The real rows' blocks
+form one chain, and a cursor in SMEM walks it `_IN_FLIGHT` blocks ahead
+of the products: while a block is computed the next ones are copied,
+and while a row's last block is computed the next REAL row's first is
+already in flight, so the copies run on from row to row without a
+pause. Each row's slot and last position, the blocks of the rows before
+it (which name its buffers) and the next real row are scalar-prefetched
+with the layer. Only the last block is masked: positions past `last` are
+masked in the scores and zeroed in the values — whatever the buffer holds
+there (the pool's own positions to the chunk's end, then what the buffer
+held before), a NaN included, never reaches the output. A padding row (its slot the scratch slot) is left
+out of the chain: it copies and computes nothing, and its output is
+nought.
 
 Softmax is online, in float32, across a row's blocks; the products take
-the pool's type and accumulate in float32. The block is
+the pool's type and accumulate in float32. The block and the chunk are
 `latent_block_plan`'s, from the shape alone. Elsewhere than a TPU the
 `jax.numpy` twin computes the same (`mla_decode_twin`).
 """
@@ -44,34 +55,52 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import NN, NT, _NEG_INF, _dot, _im
 
-# One latent block a DMA, as decode_attention.py sizes its K blocks.
+# A block is what one pass of the products takes of a row, a chunk what one
+# copy reads of a row's last block: a row copies less than a chunk past its
+# position, and the products pay their fixed cost a block. Chosen on a v5e
+# at the latent cell's shapes (`tools/mla_chunk_bench.py`; the table in
+# PERF.md): smaller blocks pay that cost more often, and a third copy in
+# flight gained nothing measurable.
 _BLOCK_BYTES = 2**20
+_CHUNK_BYTES = 2**17
+_IN_FLIGHT = 2
 _VMEM_BUDGET = 12 * 2**20
 _VMEM_LIMIT = 32 * 2**20
 
 
 @dataclasses.dataclass(frozen=True)
 class LatentPlan:
-    """block: latent positions a grid step reads of one row.
-    vmem_bytes: the step's pipelined blocks (double-buffered) and scratch."""
+    """block: latent positions of one row the products take at once;
+    chunk: positions one copy of a row's last block reads (a whole block
+    before it is one copy). vmem_bytes: the copy buffers, the pipelined
+    query and output rows, and the statistics."""
     block: int
+    chunk: int
     vmem_bytes: int
 
     def positions_read(self, length: int, cap: int) -> int:
         """Pool positions the kernel copies for a row whose new token sits
-        at `length`: whole blocks up to the one that holds it."""
-        return (min(int(length), cap - 1) // self.block + 1) * self.block
+        at `length`: whole chunks up to the one that holds it."""
+        return (min(int(length), cap - 1) // self.chunk + 1) * self.chunk
 
 
 def _step_vmem_bytes(block, heads, width, value_width, itemsize):
-    """The latent block double-buffered; the query and the output rows
-    (double-buffered, heads padded to 8 sublanes); the float32 accumulator
-    [H, C] and the two [H, 1] statistics, which pad to 128 lanes."""
+    """The copy buffers; the query and the output rows (double-buffered,
+    heads padded to 8 sublanes); the two [H, 1] float32 statistics, which
+    pad to 128 lanes."""
     rows = -(-heads // 8) * 8
-    kv = 2 * block * width * itemsize
+    kv = (_IN_FLIGHT + 1) * block * width * itemsize
     io = 2 * rows * (width * itemsize + value_width * 4)
-    acc = rows * value_width * 4 + 2 * rows * 128 * 4
-    return kv + io + acc
+    return kv + io + 2 * rows * 128 * 4
+
+
+def _pow2_under(cap: int, nbytes: int, row_bytes: int) -> int:
+    """The largest power-of-two divisor of `cap` whose rows fill at most
+    `nbytes`, and at least 8 (one sublane tile)."""
+    n = 8
+    while cap % (n * 2) == 0 and n * 2 * row_bytes <= nbytes:
+        n *= 2
+    return n
 
 
 def latent_block_plan(cap: int, heads: int, width: int, value_width: int,
@@ -81,52 +110,112 @@ def latent_block_plan(cap: int, heads: int, width: int, value_width: int,
     heads, or None where the kernel does not serve the shape: a row or a
     latent that does not fill whole 128-lane tiles, a capacity that is no
     multiple of 8 positions, or a step over VMEM. The block is the largest
-    power-of-two divisor of `cap` of at most `_BLOCK_BYTES`, and at least
-    8 positions (one sublane tile)."""
+    power-of-two divisor of `cap` of at most `_BLOCK_BYTES`, the chunk that
+    of the block of at most `_CHUNK_BYTES`; each at least 8 positions."""
     if width % 128 or value_width % 128 or value_width > width \
             or cap % 8 or cap < 8:
         return None
-    block = 8
-    while cap % (block * 2) == 0 and \
-            block * 2 * width * itemsize <= _BLOCK_BYTES:
-        block *= 2
+    block = _pow2_under(cap, _BLOCK_BYTES, width * itemsize)
+    chunk = _pow2_under(block, _CHUNK_BYTES, width * itemsize)
     need = _step_vmem_bytes(block, heads, width, value_width, itemsize)
     if need > _VMEM_BUDGET:
         return None
-    return LatentPlan(block, need)
+    return LatentPlan(block, chunk, need)
 
 
-def _kernel(slots_ref, last_ref, real_ref, layer_ref, q_ref, kv_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, block, value_width, sm_scale):
-    """Grid (row i, block j). q [H, W]; kv [block, W] of pool row
-    slots[i] at the layer; o [H, C]; m, l [H, 1] and acc [H, C] in
-    scratch."""
-    del slots_ref, layer_ref          # the index maps read them
-    i, j = pl.program_id(0), pl.program_id(1)
-    last = last_ref[i]
-    real = real_ref[i] != 0
-    # the block that holds `last` (lax.div: under the package's x64 a `//`
-    # traces a convert that Mosaic's lowering recurses on)
-    live = jax.lax.div(last, jnp.int32(block))
+def _kernel(slots_ref, last_ref, before_ref, next_ref, layer_ref, q_ref,
+            pool_ref, o_ref, buf_ref, sem_ref, m_ref, l_ref, cur_ref, *,
+            block, chunk, value_width, sm_scale):
+    """Grid (row i,). q [H, W]; pool [rows, L, cap, W] in HBM; o [H, C],
+    the float32 sum until the row's end; buf [d + 1, block, W] and a DMA
+    semaphore each, d = `_IN_FLIGHT`; m, l [H, 1]; cur the (row, block)
+    the next copy reads. Block g of the chain (the real rows' blocks one
+    after another) lies in buffer g % (d + 1): while it is computed the d
+    after it are copied. A row's last block is copied by the chunks up to
+    the one that holds `last`, the rest of its buffer left as it was."""
+    i = pl.program_id(0)
+    last = last_ref[i]                      # -1: a padding row
+    rows = pl.num_programs(0)
+    layer = layer_ref[0]
+    B, S = jnp.int32(block), jnp.int32(chunk)
+    nbuf = jnp.int32(_IN_FLIGHT + 1)
 
-    @pl.when(j == 0)
+    def copy(row, at, size, g):
+        """Positions [at, at + size) of `row` into the buffer of block g,
+        at the same offset in the block."""
+        b = jax.lax.rem(g, nbuf)
+        return pltpu.make_async_copy(
+            pool_ref.at[slots_ref[row], layer,
+                        pl.ds(pl.multiple_of(at, chunk), size)],
+            buf_ref.at[b, pl.ds(pl.multiple_of(jax.lax.rem(at, B), chunk),
+                                size)],
+            sem_ref.at[b])
+
+    def copies(row, n, g, act):
+        """Start (or wait for) the copies of block n of `row`: one of the
+        whole block, or, the row's last, one a chunk up to its position
+        (lax.div: under the package's x64 a `//` traces a convert that
+        Mosaic's lowering recurses on)."""
+        end = last_ref[row]
+        whole = n < jax.lax.div(end, B)
+
+        @pl.when(whole)
+        def _():
+            act(copy(row, n * B, block, g))
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            def one(k, carry):
+                act(copy(row, n * B + k * S, chunk, g))
+                return carry
+            jax.lax.fori_loop(jnp.int32(0),
+                              jax.lax.div(jax.lax.rem(end, B), S) + 1, one,
+                              jnp.int32(0))
+
+    def issue(g):
+        """Start the copies of chain block g, the one `cur` names, and move
+        `cur` on: to the row's next block, or past its last to the next
+        real row's first."""
+        row, n = cur_ref[0], cur_ref[1]
+
+        @pl.when(row < rows)
+        def _():
+            copies(row, n, g, lambda c: c.start())
+            more = n < jax.lax.div(last_ref[row], B)
+            cur_ref[0] = jnp.where(more, row, next_ref[row])
+            cur_ref[1] = jnp.where(more, n + 1, jnp.int32(0))
+
+    @pl.when(last >= 0)
     def _():
+        blocks = jax.lax.div(last, B) + 1
+        g0 = before_ref[i]
+
+        @pl.when(g0 == 0)                   # the first real row starts
+        def _():
+            cur_ref[0] = i
+            cur_ref[1] = jnp.int32(0)
+            for g in range(_IN_FLIGHT):
+                issue(jnp.int32(g))
+
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    def step(masked):
-        def run():
-            q = q_ref[...]
-            kv = kv_ref[...]
-            s = _dot(q, kv, NT, None) * jnp.float32(sm_scale)  # [H, block]
-            c = kv[:, :value_width]
+        def products(n, masked):
+            g = g0 + n
+            issue(g + _IN_FLIGHT)           # before this block's products
+            copies(i, n, g, lambda c: c.wait())
+            kv = buf_ref[jax.lax.rem(g, nbuf)]
+            s = _dot(q_ref[...], kv, NT, None) * jnp.float32(sm_scale)
+            c = kv[:, :value_width]                         # s [H, block]
             if masked:
-                kpos = j * block + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
+                # past `last`: the pool's positions to the chunk's end,
+                # then whatever the buffer held before — any a NaN
+                kpos = n * B + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                        1)
                 s = jnp.where(kpos <= last, s, jnp.float32(_NEG_INF))
-                cpos = j * block + jax.lax.broadcasted_iota(
-                    jnp.int32, c.shape, 0)
+                cpos = n * B + jax.lax.broadcasted_iota(jnp.int32, c.shape,
+                                                        0)
                 c = jnp.where(cpos <= last, c, jnp.zeros((), c.dtype))
             m_prev = m_ref[...]
             m = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -134,50 +223,48 @@ def _kernel(slots_ref, last_ref, real_ref, layer_ref, q_ref, kv_ref, o_ref,
             alpha = jnp.exp(m_prev - m)
             l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
                                                       keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + _dot(
+            o_ref[...] = o_ref[...] * alpha + _dot(
                 p if c.dtype == jnp.float32 else p.astype(c.dtype), c, NN,
                 None)                                       # [H, C]
             m_ref[...] = m
-        return run
 
-    # whole blocks take no mask; blocks past `live` are neither computed
-    # nor (the index map) copied; a padding row computes nothing
-    pl.when(real & (j < live))(step(False))
-    pl.when(real & (j == live))(step(True))
+        # whole blocks take no mask; the last one does
+        jax.lax.fori_loop(jnp.int32(0), blocks - 1,
+                          lambda n, carry: (products(n, False), carry)[1],
+                          jnp.int32(0))
+        products(blocks - 1, True)
+        o_ref[...] = o_ref[...] / l_ref[...]
 
-    end = j == pl.num_programs(1) - 1
-
-    @pl.when(end & real)
-    def _():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-
-    @pl.when(end & jnp.logical_not(real))
+    @pl.when(last < 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _row_plan(slots, last, scratch: int):
-    """(block slot, last position, real) of each row: a real row reads its
-    own slot up to its position; a padding row shows the index map the
-    last real row's last block (no copy) — or, before any real row, block
-    0 of its own slot."""
+def _chain(slots, lengths, cap: int, block: int, scratch: int):
+    """(last position, blocks before, next real row) of each row: a
+    padding row's last position is -1 and it holds no block; a row's
+    blocks follow those of every real row before it; after the last real
+    row the next is `b`, none."""
+    b = slots.shape[0]
     real = slots != scratch
-    idx = jnp.arange(slots.shape[0], dtype=jnp.int32)
-    prev = jax.lax.cummax(jnp.where(real, idx, -1), axis=0)
-    src = jnp.where(real | (prev < 0), idx, prev)
-    return slots[src], jnp.where(real | (prev < 0), last, last[src]), \
-        real.astype(jnp.int32)
+    last = jnp.where(real, jnp.minimum(lengths.astype(jnp.int32), cap - 1),
+                     -1)
+    blocks = jnp.where(real, last // block + 1, 0)
+    before = jnp.cumsum(blocks) - blocks
+    after = jax.lax.cummin(
+        jnp.where(real, jnp.arange(b, dtype=jnp.int32), b), axis=0,
+        reverse=True)
+    nxt = jnp.concatenate([after[1:], jnp.full((1,), b, jnp.int32)])
+    return last, before.astype(jnp.int32), nxt
 
 
 @functools.partial(jax.jit, static_argnames=("value_width", "sm_scale",
-                                             "scratch", "interpret", "block"))
+                                             "scratch", "interpret"))
 def mla_decode(q, pool, layer, slots, lengths, *, value_width: int,
-               sm_scale: float, scratch: int, interpret: bool = False,
-               block: int | None = None):
+               sm_scale: float, scratch: int, interpret: bool = False):
     """One query a row and head over the latent pool, in place. (Jitted,
     the layer an argument: a program of L layers traces and lowers the
-    kernel once.) `block` overrides the plan's, for the tests' short
-    pools.
+    kernel once.)
 
     q [b, H, W] in the pool's type: each head's absorbed query and rotary
     query, `[q̂ ‖ q_r ‖ 0]`; pool [rows, L, cap, W]; layer a scalar; slots
@@ -195,38 +282,32 @@ def mla_decode(q, pool, layer, slots, lengths, *, value_width: int,
             f"mla_decode does not serve a pool {pool.shape} ({pool.dtype}) "
             f"under {heads} heads of {width} lanes, latent {value_width}: "
             f"see latent_block_plan")
-    block = block or plan.block
-    last = jnp.minimum(lengths.astype(jnp.int32), cap - 1)
-    bslot, blast, real = _row_plan(slots.astype(jnp.int32), last, scratch)
-
-    def kv_index(i, j, slot, last, real, layer):
-        at = jax.lax.div(last[i], jnp.int32(block))
-        return (slot[i], layer[0],
-                jnp.where(real[i] != 0, jnp.minimum(j, at), at), 0)
-
-    kv_spec = pl.BlockSpec((None, None, block, width), _im(kv_index))
+    slots = slots.astype(jnp.int32)
+    last, before, nxt = _chain(slots, lengths, cap, plan.block, scratch)
+    row = pl.BlockSpec((None, heads, width), _im(lambda i, *_: (i, 0, 0)))
     return pl.pallas_call(
-        functools.partial(_kernel, block=block, value_width=value_width,
-                          sm_scale=sm_scale),
+        functools.partial(_kernel, block=plan.block, chunk=plan.chunk,
+                          value_width=value_width, sm_scale=sm_scale),
         name="mla_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(b, cap // block),
-            in_specs=[pl.BlockSpec((None, heads, width),
-                                   _im(lambda i, j, *_: (i, 0, 0))),
-                      kv_spec],
+            num_scalar_prefetch=5,
+            grid=(b,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, heads, value_width),
-                                   _im(lambda i, j, *_: (i, 0, 0))),
+                                   _im(lambda i, *_: (i, 0, 0))),
             scratch_shapes=[
+                pltpu.VMEM((_IN_FLIGHT + 1, plan.block, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((_IN_FLIGHT + 1,)),
                 pltpu.VMEM((heads, 1), jnp.float32),
                 pltpu.VMEM((heads, 1), jnp.float32),
-                pltpu.VMEM((heads, value_width), jnp.float32)]),
+                pltpu.SMEM((2,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((b, heads, value_width), jnp.float32),
         interpret=interpret,
+        # the copies run on across rows: the grid is walked in order
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
-    )(bslot, blast, real, jnp.asarray(layer, jnp.int32).reshape(1),
+    )(slots, last, before, nxt, jnp.asarray(layer, jnp.int32).reshape(1),
       q, pool)
 
 

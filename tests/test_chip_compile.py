@@ -383,12 +383,14 @@ def test_mistral4_programs_compile_for_a_v5e_at_the_cells_size(
 def test_mla_decode_compiles_for_a_v5e_at_the_cells_shapes(
         one_chip, no_persistent_cache):
     """The kernel alone over the cell's latent pool: 128 rows of 32 heads,
-    a plan of 1,024-position blocks, the pool aliased by nothing and
-    copied by nothing."""
+    products a block of 1,024 positions, a row's last block copied by
+    chunks of 128; a grid of one step a row and none a block (the pool
+    stays in HBM, its blocks copied in the kernel's own loop), the pool
+    aliased by nothing and copied by nothing."""
     from paddle_tpu.ops.pallas import mla_decode as mla
 
     plan = mla.latent_block_plan(8192, 32, 384, 256, 2)
-    assert plan is not None and plan.block == 1024
+    assert plan is not None and (plan.block, plan.chunk) == (1024, 128)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -396,10 +398,21 @@ def test_mla_decode_compiles_for_a_v5e_at_the_cells_shapes(
     fn = jax.jit(lambda q, pool, layer, slots, lens: mla.mla_decode(
         q, pool, layer, slots, lens, value_width=256,
         sm_scale=0.195, scratch=128))
-    compiled = fn.lower(sds((128, 32, 384), jnp.bfloat16),
-                        sds((129, 8, 8192, 384), jnp.bfloat16),
-                        sds((), jnp.int32), sds((128,), jnp.int32),
-                        sds((128,), jnp.int32)).compile()
+    args = (sds((128, 32, 384), jnp.bfloat16),
+            sds((129, 8, 8192, 384), jnp.bfloat16),
+            sds((), jnp.int32), sds((128,), jnp.int32),
+            sds((128,), jnp.int32))
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+    calls = list(pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert [c.params["grid_mapping"].grid for c in calls] == [(128,)]
+    compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     assert "mla_decode" in text and "tpu_custom_call" in text

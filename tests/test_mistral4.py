@@ -335,34 +335,69 @@ def test_rotation_is_pairwise_and_keeps_dot_products_by_offset():
 
 
 # ------------------------------------------------------------------ the kernel
+@pytest.fixture
+def small_plan(monkeypatch):
+    """Cut the kernel's plan to a short pool: `cut(width, itemsize, block,
+    chunk)` gives blocks of `block` positions, a row's last block copied by
+    chunks of `chunk`. The kernel is jitted: its traces under another plan
+    are dropped before and after."""
+    def cut(width, itemsize, block=32, chunk=8):
+        monkeypatch.setattr(mla, "_BLOCK_BYTES", block * width * itemsize)
+        monkeypatch.setattr(mla, "_CHUNK_BYTES", chunk * width * itemsize)
+        mla.mla_decode.clear_cache()
+    yield cut
+    mla.mla_decode.clear_cache()
+
+
+# cap 64 in blocks of 32 and chunks of 8; the scratch slot is 6
+KERNEL_CASES = {
+    # rows at their first position, at a block's edge, at the cap's end
+    "padding_inside": ((1, 3, 0, 6), (0, 15, 63, 40)),
+    "padding_first": ((6, 2, 6, 0), (0, 15, 63, 40)),
+    "all_padding": ((6, 6, 6, 6), (0, 15, 63, 40)),
+    # a chunk's last and first position, a block's last and first, the cap
+    # less one, a row over two blocks
+    "chunk_edges": ((0, 1, 2, 3, 4, 5), (7, 8, 31, 32, 63, 41)),
+    # two real rows back to back after a padding row, a padding row
+    # between rows of one chunk, a row past the cap
+    "rows_after_padding": ((2, 6, 0, 1, 6, 3, 6, 4, 5),
+                           (39, 5, 47, 1, 0, 0, 12, 70, 16)),
+}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["4-byte", "2-byte"])
-@pytest.mark.parametrize("slots", [(1, 3, 0, 4), (4, 2, 4, 0), (4, 4, 4, 4)],
-                         ids=["padding_inside", "padding_first",
-                              "all_padding"])
-def test_mla_decode_in_interpret_mode_against_its_twin(slots, dtype):
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_mla_decode_in_interpret_mode_against_its_twin(case, dtype,
+                                                       small_plan):
     """Interpret mode: 8 heads over a latent of 128 and a rotary key padded
-    to 256 lanes, blocks of 16 positions, rows of ragged lengths (a row at
-    its first position, one at a block's edge, one at the cap's end), NaN
-    in every position past a row's length (it must not reach the output);
-    a padding row (the scratch slot, 4) computes nothing and reads nought."""
-    rows, L, cap, W, C, H, scratch = 5, 2, 64, 256, 128, 8, 4
+    to 256 lanes, blocks of 32 positions whose last a row copies by chunks
+    of 8, rows of ragged lengths on and across those edges, NaN in every
+    position past a row's length (it must not reach the output); a padding
+    row (the scratch slot, 6) computes nothing and reads nought, and the
+    copies run on past it to the next real row."""
+    slots, lengths = KERNEL_CASES[case]
+    rows, L, cap, W, C, H, scratch = 7, 2, 64, 256, 128, 8, 6
+    small_plan(W, jnp.dtype(dtype).itemsize)
+    plan = mla.latent_block_plan(cap, H, W, C, jnp.dtype(dtype).itemsize)
+    assert (plan.block, plan.chunk) == (32, 8)
     rng = np.random.RandomState(0)
     pool = rng.standard_normal((rows, L, cap, W)).astype(np.float32)
     pool[..., C + 64:] = 0
-    lengths = np.asarray([0, 15, 63, 40], np.int32)
+    lengths = np.asarray(lengths, np.int32)
     for s, n in zip(slots, lengths):
         if s != scratch:
             pool[s, 1, n + 1:] = np.nan
     pool = jnp.asarray(pool, dtype)
-    q = jnp.asarray(rng.standard_normal((4, H, W)), dtype)
+    b = len(slots)
+    q = jnp.asarray(rng.standard_normal((b, H, W)), dtype)
     q = q.at[..., C + 64:].set(0)
     at = jnp.asarray(slots, jnp.int32)
     kw = dict(value_width=C, sm_scale=0.2, scratch=scratch)
     kern = mla.mla_decode(q, pool, 1, at, jnp.asarray(lengths),
-                          interpret=True, block=16, **kw)
+                          interpret=True, **kw)
     twin = mla.mla_decode_twin(q, pool, 1, at, jnp.asarray(lengths), **kw)
-    assert kern.shape == (4, H, C) and kern.dtype == jnp.float32
+    assert kern.shape == (b, H, C) and kern.dtype == jnp.float32
     assert not np.isnan(np.asarray(kern)).any()
     assert not np.isnan(np.asarray(twin)).any()
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -371,17 +406,35 @@ def test_mla_decode_in_interpret_mode_against_its_twin(slots, dtype):
         if s == scratch:
             assert not np.asarray(kern[i]).any()
     # by hand, a row at its first position: its own latent, every head
-    if slots[0] != scratch:
+    if slots[0] != scratch and lengths[0] == 0:
         np.testing.assert_allclose(
             kern[0], np.broadcast_to(np.asarray(pool[slots[0], 1, 0, :C],
                                                 np.float32), (H, C)),
             atol=tol, rtol=tol)
 
 
+def test_the_chain_of_a_steps_rows():
+    """Each row's last position (-1: padding), the blocks of the real rows
+    before it, and the next real row (`b` after the last)."""
+    slots = jnp.asarray([2, 6, 0, 1, 6, 3, 6], jnp.int32)
+    lengths = jnp.asarray([39, 5, 47, 1, 0, 70, 12], jnp.int32)
+    last, before, nxt = mla._chain(slots, lengths, 64, 32, 6)
+    assert last.tolist() == [39, -1, 47, 1, -1, 63, -1]
+    assert before.tolist() == [0, 2, 2, 4, 5, 5, 7]
+    assert nxt.tolist() == [2, 2, 3, 5, 5, 7, 7]
+
+
 def test_latent_block_plan_serves_the_cells_pool():
     plan = mla.latent_block_plan(8192, 32, 384, 256, 2)
-    assert plan.block == 1024 and plan.positions_read(0, 8192) == 1024
+    # products a block of 1,024 positions; copies of a row's last block a
+    # chunk of 128: whole chunks up to the one that holds the position
+    assert (plan.block, plan.chunk) == (1024, 128)
+    assert plan.positions_read(0, 8192) == 128
+    assert plan.positions_read(127, 8192) == 128
+    assert plan.positions_read(128, 8192) == 256
+    assert plan.positions_read(1023, 8192) == 1024
     assert plan.positions_read(3000, 8192) == 3072
+    assert plan.positions_read(8191, 8192) == 8192
     assert plan.positions_read(9000, 8192) == 8192
     # a row or a latent that is no whole number of lane tiles
     assert mla.latent_block_plan(8192, 32, 320, 256, 2) is None
@@ -471,6 +524,32 @@ def test_the_counters_are_the_held_experts_and_the_attended_positions():
         snap["step_rows_total"] * k * layers
     assert snap["moe_distinct_experts_total"] <= \
         min(4, k) * snap["moe_layer_steps_total"]
+
+
+def test_the_read_counter_counts_the_kernels_whole_chunks(monkeypatch,
+                                                         small_plan):
+    """Where the decode program is lowered for a TPU (steered here: the
+    CPU still runs the twin), `kv_positions_read_total` grows a step by
+    `positions_read` of each real row — whole chunks up to its position,
+    in the plan of the model's own `read_plan` — and
+    `attended_positions_total` by the positions themselves. A prompt of 14
+    leaves the pending token at 14; six steps read up to 14 … 19, a
+    chunk of 16 twice and two chunks four times."""
+    from paddle_tpu.inference.serving import generate as gen
+
+    cfg = tiny("float32", kv_lora_rank=128)
+    assert cfg.latent_width == 256
+    small_plan(cfg.latent_width, 4, block=32, chunk=16)
+    monkeypatch.setattr(gen, "_lowers_for_tpu", lambda device: True)
+    plan = cfg.serving_passes().read_plan(CAP)
+    assert (plan.block, plan.chunk) == (32, 16)
+    prompt = np.random.RandomState(4).randint(0, 512, 14)
+    _, snap = _serve(cfg, params_of(cfg), [prompt], 7, slots=1)
+    assert snap["steps_total"] == 6
+    steps = range(14, 20)
+    assert snap["kv_positions_read_total"] == sum(
+        plan.positions_read(x, CAP) for x in steps) == 2 * 16 + 4 * 32
+    assert snap["attended_positions_total"] == sum(x + 1 for x in steps)
 
 
 def test_a_steps_counts_are_its_held_share_of_the_routing():
