@@ -167,22 +167,22 @@ def run_worker(prefix: str, runner=None, predictor=None) -> int:
 def generate_presets() -> dict:
     """name -> the module whose PRESETS holds it: every model `--generate`
     serves, each kind from its own module's table (models.gpt through the
-    engine's own `GPTPasses`; models.lfm2, models.brumby and
-    models.mistral4 through the step their configuration supplies,
-    `cfg.serving_passes()`). A module of
+    engine's own `GPTPasses`; models.lfm2, models.brumby,
+    models.mistral4 and models.ling through the step their configuration
+    supplies, `cfg.serving_passes()`). A module of
     another kind joins by the same rule: a `PRESETS` table and, beside it,
     `init_params(cfg)`."""
-    from ..models import brumby, gpt, lfm2, mistral4
+    from ..models import brumby, gpt, lfm2, ling, mistral4
 
-    return {name: module for module in (gpt, lfm2, brumby, mistral4)
+    return {name: module for module in (gpt, lfm2, brumby, mistral4, ling)
             for name in module.PRESETS}
 
 
 def build_generator(preset: str, state_dict: str | None = None,
                     draft: str | None = None, **engine_kw):
     """The GenerativeEngine `--generate PRESET` serves: a preset of
-    models.gpt, models.lfm2, models.brumby or models.mistral4 with seeded
-    demo weights (or,
+    models.gpt, models.lfm2, models.brumby, models.mistral4 or models.ling
+    with seeded demo weights (or,
     for a GPT, `state_dict` loaded into it), optionally a `draft` preset
     for speculative decode; `engine_kw` goes to the engine. Returned warmed
     and started."""
@@ -241,8 +241,9 @@ def main(argv=None) -> int:
     ap.add_argument("--generate", metavar="PRESET", default=None,
                     help="also serve streaming generation (/generate) "
                          "from a PRESET of models.gpt, models.lfm2, "
-                         "models.brumby or models.mistral4 (e.g. gpt3-tiny, "
-                         "lfm2-tiny, brumby-tiny, mistral4-tiny; "
+                         "models.brumby, models.mistral4 or models.ling "
+                         "(e.g. gpt3-tiny, lfm2-tiny, brumby-tiny, "
+                         "mistral4-tiny, ling-tiny; "
                          "seeded demo weights, or --state-dict to load "
                          "trained ones); requires --http")
     ap.add_argument("--state-dict", default=None,

@@ -28,7 +28,12 @@ beyond the framework):
                     stream=true  -> chunked application/x-ndjson: one
                                     {"token": id} line per generated
                                     token AS IT DECODES, then a final
-                                    {"done": true, ...result} line
+                                    {"done": true, ...result} line;
+                                    before the first token, a
+                                    {"waiting": true} line every
+                                    WAITING_S of silence (a request
+                                    queued behind full slots), which a
+                                    reader skips
   GET  /healthz   engine health JSON (503 while draining)
   GET  /metrics   Prometheus text format (predict + generate families)
 
@@ -69,6 +74,7 @@ import json
 import queue
 import struct
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
@@ -446,6 +452,12 @@ class _Handler(BaseHTTPRequestHandler):
 _TERMINAL = ("done", "handoff", "err")
 # how soon the pump tries again a socket that had no room for a chunk
 _RETRY_S = 0.005
+# a stream still without its first token this long after it opened (or
+# after its last such line) gets a {"waiting": true} line: a request
+# queued behind full slots for minutes would
+# otherwise hold its reader in silence until the reader's (or a proxy's)
+# idle timeout gives up on a reply that is only waiting its turn
+WAITING_S = 15.0
 
 
 def _chunk(kind: str, val) -> bytes:
@@ -465,7 +477,14 @@ def _chunk(kind: str, val) -> bytes:
         else:
             obj = dict(val, done=True)
         data = (json.dumps(obj) + "\n").encode()
+    return _frame(data)
+
+
+def _frame(data: bytes) -> bytes:
     return b"%X\r\n%s\r\n" % (len(data), data)
+
+
+_WAITING = _frame(b'{"waiting": true}\n')
 
 
 class _Sink:
@@ -474,10 +493,11 @@ class _Sink:
     (`out`) and sends them, once the reply's headers are out (`open`).
     At the stream's last event it hands what the socket had no room for
     (`rest`) back to the reply's thread and sets `finished`; `failed`:
-    the client went away."""
+    the client went away; `quiet_since`: until its first token (then
+    None), when the stream opened or last got a waiting line."""
 
     __slots__ = ("_q", "sock", "out", "open", "ending", "rest", "failed",
-                 "finished")
+                 "finished", "quiet_since")
 
     def __init__(self, q: "queue.SimpleQueue", sock):
         self._q = q
@@ -485,6 +505,7 @@ class _Sink:
         self.out = bytearray()
         self.open = False
         self.ending = False
+        self.quiet_since: Optional[float] = time.monotonic()
         self.rest = b""
         self.failed = False
         self.finished = threading.Event()
@@ -505,7 +526,9 @@ class _StreamPump:
     each stream's chunks and sends them with one non-blocking send a
     stream, in the order the worker emitted them. A socket that has no
     room keeps its bytes and is tried again; the reply's thread sleeps
-    until its stream ends, then writes the rest and the last chunk."""
+    until its stream ends, then writes the rest and the last chunk.
+    A stream still without its first token WAITING_S after its last line
+    gets a {"waiting": true} line."""
 
     def __init__(self):
         self._q: queue.SimpleQueue = queue.SimpleQueue()
@@ -545,9 +568,11 @@ class _StreamPump:
 
     def _run(self) -> None:
         q, backlog, stop = self._q, [], False
+        look = time.monotonic() + WAITING_S / 4
         while not stop:
             try:
-                batch = [q.get(timeout=_RETRY_S) if backlog else q.get()]
+                batch = [q.get(timeout=_RETRY_S if backlog
+                               else WAITING_S / 4)]
             except queue.Empty:
                 batch = []
             while True:
@@ -570,7 +595,20 @@ class _StreamPump:
                         sink.out += _chunk("err", e)
                         sink.ending = True
                     sink.ending = sink.ending or event[0] in _TERMINAL
+                    if event[0] == "tok":
+                        sink.quiet_since = None
                 touched[sink] = None
+            now = time.monotonic()
+            if now >= look:
+                look = now + WAITING_S / 4
+                with self._lock:
+                    live = list(self._live)
+                for sink in live:
+                    if sink.quiet_since is not None and not sink.ending \
+                            and now - sink.quiet_since >= WAITING_S:
+                        sink.out += _WAITING
+                        sink.quiet_since = now
+                        touched[sink] = None
             backlog = [sink for sink in touched if not self._send(sink)]
         with self._lock:
             live, self._live = self._live, set()

@@ -380,6 +380,78 @@ def test_mistral4_programs_compile_for_a_v5e_at_the_cells_size(
           mem.argument_size_in_bytes, "alias", mem.alias_size_in_bytes)
 
 
+
+@pytest.mark.parametrize("kind, bucket", [("decode", 256),
+                                          ("prefill", 1024)])
+def test_ling_programs_compile_for_a_v5e_at_the_cells_size(
+        one_chip, no_persistent_cache, kind, bucket):
+    """`ling-3.0-flash` as the benchmark's cell serves it (256 slots, one
+    class of 8,192 positions, ONE latent pool for the MLA layer and a
+    float32 state for the six KDA layers): the decode program of all
+    slots and the prefill of the traffic's prompt bucket, from shapes
+    alone. The decode program takes the `kda_step` kernel, one a KDA
+    layer, and `mla_decode`; the pool (2.69 GB) and the state (3.54 GB)
+    alias their inputs, and the temporaries hold nothing of their size —
+    under 300 MB beside 5.6 GB of weights (a gather of a slice of the
+    state's minor tiles would relay the whole state out around each
+    kernel: 3.6 GB of temporaries). The prefill runs the chunked
+    delta rule (scope `kda.prefill`) and the blocked causal attention
+    (q and k of 192 over v of 128: no flash kernel)."""
+    from paddle_tpu.inference.serving import GenerativeEngine
+    from paddle_tpu.models import ling
+
+    cfg = ling.PRESETS["ling-3.0-flash"]
+    f32 = ("a_log", "dt_bias", "bias")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {n: sds(shape, jnp.float32 if what in f32 else jnp.bfloat16)
+              for n, (shape, what) in ling.param_shapes(cfg).items()}
+    eng = GenerativeEngine(params=({}, cfg), slots=256, warmup=False,
+                           auto_start=False, donate=True)
+    try:
+        cap = eng._caps[-1]
+        pool = sds(eng._pool_shape(cap), jnp.bfloat16)
+        rec = sds(eng._state_shape(), jnp.float32)
+        assert pool.shape == (257, 1, 8192, 640)
+        assert rec.shape == (257, 6, 35, 128, 128)
+        if kind == "decode":
+            b = bucket
+            rows = (sds((b,), jnp.int32), sds((b,), jnp.int32),
+                    sds((b,), jnp.int32), sds((b,), jnp.float32),
+                    sds((b,), jnp.int32), sds((b,), jnp.float32),
+                    sds((b, 2), jnp.uint32))
+        else:
+            rows = (sds((1, bucket), jnp.int32), sds((7,), jnp.int32),
+                    sds((257, 3), jnp.int32))
+        compiled = eng._program(kind, cap, bucket).lower(
+            params, pool, None, *rows, rec).compile()
+        cache_bytes = eng.kv_pool_bytes()
+    finally:
+        eng.shutdown(drain=False)
+    mem = compiled.memory_analysis()
+    assert cache_bytes == 2 * int(np.prod(pool.shape)) \
+        + 4 * int(np.prod(rec.shape)) == 6_231_818_240
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.argument_size_in_bytes > 2 * ling.n_params(cfg) + cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 0.85 * 16.9e9
+    text = compiled.as_text()
+    assert f"jit_ling_{kind}_c8192_b{bucket}" in text
+    assert "mla.attention" in text and "moe.experts" in text \
+        and "moe.shared" in text and "moe.route" in text \
+        and "kda.conv" in text
+    if kind == "decode":
+        assert mem.temp_size_in_bytes < 300e6
+        assert "kda_step" in text and "mla_decode" in text \
+            and "kda.state_step" in text
+    else:
+        assert mem.temp_size_in_bytes < 2**30
+        assert "kda.prefill" in text
+    print(kind, bucket, "temp", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes, "alias", mem.alias_size_in_bytes)
+
 def test_mla_decode_compiles_for_a_v5e_at_the_cells_shapes(
         one_chip, no_persistent_cache):
     """The kernel alone over the cell's latent pool: 128 rows of 32 heads,

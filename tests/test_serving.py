@@ -701,6 +701,49 @@ def test_a_stream_chunk_is_the_line_json_writes():
     assert b'"done"' not in _chunk("handoff", {"handoff": "x"})
 
 
+class _QueuedGenerator:
+    """Its one request waits `wait_s` for its slot, then streams two tokens
+    `wait_s` apart and its done line."""
+
+    def __init__(self, wait_s):
+        self.wait_s = wait_s
+
+    def submit(self, input_ids, stream=None, **kw):
+        def play():
+            time.sleep(self.wait_s)
+            stream.put(("tok", 7))
+            time.sleep(self.wait_s)
+            stream.put(("tok", 8))
+            stream.put(("done", {"tokens": [7, 8], "n_tokens": 2}))
+        threading.Thread(target=play, daemon=True).start()
+
+    def shutdown(self, drain=True, migrate=False):
+        pass
+
+
+def test_a_stream_waiting_for_its_first_token_says_so(monkeypatch):
+    """A request queued behind full slots gets a {"waiting": true} line
+    each WAITING_S of silence before its first token, so its reader's idle
+    timeout does not end a reply that only waits its turn; once its tokens
+    flow, a pause as long gets none."""
+    from paddle_tpu.inference.serving import ServingHTTPServer
+    from paddle_tpu.inference.serving import server
+
+    monkeypatch.setattr(server, "WAITING_S", 0.1)
+    srv = ServingHTTPServer(None, generator=_QueuedGenerator(0.5)).start()
+    try:
+        status, lines = _stream_lines(srv.port, [1])
+    finally:
+        srv.stop()
+    assert status == 200
+    waited = [ln for ln in lines if ln == {"waiting": True}]
+    assert len(waited) >= 2
+    assert lines[:len(waited)] == waited
+    assert lines[len(waited):] == [
+        {"token": 7}, {"token": 8},
+        {"tokens": [7, 8], "n_tokens": 2, "done": True}]
+
+
 def test_a_refused_stream_answers_with_its_status():
     """A request the engine refuses before it is queued gets its status
     and JSON body, never a chunked 200."""
